@@ -175,8 +175,10 @@ class ServerConfig:
 
 def _mesh_from_device_spec(spec: str):
     """A 1-D data mesh over the named jax device indices ("0" or
-    "0,2,3"): each `pio deploy --workers` worker pins its prepared
-    serving state to its own device or mesh slice."""
+    "0,2,3") of the devices THIS process can see: each `pio deploy
+    --workers` worker pins its prepared serving state to its own device
+    or mesh slice (on a TPU the supervisor narrows what a worker sees
+    to its own chips before it starts, tools/cli.py)."""
     import jax
 
     from predictionio_tpu.parallel.mesh import make_mesh
@@ -434,9 +436,9 @@ class _BatchingExecutor:
     contract (CreateServer.scala:473-624), safe for engines with mutable
     predict-time state. Depth 2 (opt-in, see ServerConfig.pipeline_depth)
     double-buffers: while batch k's result fetch is crossing
-    host<->device (or, on a relay rig, the network), batch k+1 already
-    dispatched and batch k+2 accumulates behind the semaphore — the
-    device never idles waiting on a fetch.
+    host<->device, batch k+1 already dispatched and batch k+2
+    accumulates behind the semaphore — the device never idles waiting
+    on a fetch.
     """
 
     _STOP = object()  # collector-thread shutdown sentinel
@@ -471,7 +473,7 @@ class _BatchingExecutor:
             key[0]: child.snapshot()
             for key, child in self._m_batch_fill.children()
         }
-        # watchdog: a serve_batch wedged in a stuck device/relay call
+        # watchdog: a serve_batch wedged in a stuck device call
         # degrades /readyz once it overruns the deadline (executors of
         # one process share the heartbeat — either stalling is a
         # process-level routing signal); idle executors never stall
@@ -558,7 +560,7 @@ class _BatchingExecutor:
             self._queue.put(self._STOP)
         if worker is not None and worker.is_alive():
             worker.join(timeout=10.0)
-        # wait=False so a wedged serve_batch (a stuck device/relay call)
+        # wait=False so a wedged serve_batch (a stuck device call)
         # cannot hang THIS call forever, mirroring the bounded collector
         # join above. The guarantee is only that close() returns: a truly
         # wedged batch still blocks its request threads (their slots

@@ -158,8 +158,8 @@ def _message(status: int, message: str) -> Tuple[int, dict]:
 
 
 # every live EventAPI, so the admin delete path can revoke a key from
-# all in-process servers' auth caches immediately (ADVICE.md: the TTL
-# alone left a same-process revocation authenticating for up to 5 s)
+# all in-process servers' auth caches immediately (the TTL alone left a
+# same-process revocation authenticating for up to 5 s)
 _LIVE_APIS: "weakref.WeakSet" = weakref.WeakSet()
 
 

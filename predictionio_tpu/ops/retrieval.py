@@ -35,9 +35,10 @@ The single-device fallback is the SAME kernel fused into one jit
 (score + mask + top_k, one dispatch) — 1-device serving no longer
 materializes the full score row per query on host, and the parity tests
 cover both shapes. The final packed buffer rides the
-``_topn_packed``-style score+index-bits layout (and the row-sharded
-output pinning lesson of ``_topn_packed_sharded``): one fetch per batch,
-indices as raw int32 bits so ids >= 2^24 survive.
+``_topn_packed`` layout (``ops/als.py _pack_topn``: score bits beside
+int32 ids in one int32 buffer — and the row-sharded output pinning
+lesson of ``_topn_packed_sharded``): one fetch per batch, ids never
+pass through a float.
 
 Metrics (utils/metrics.py conventions, visible in ``pio top``):
 ``pio_retrieval_shard_topk_seconds`` / ``pio_retrieval_merge_seconds``
@@ -101,9 +102,9 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from predictionio_tpu.ops.als import _pack_topn, unpack_topn
 from predictionio_tpu.ops.similarity import pad_rows_pow2, pow2_at_least
 from predictionio_tpu.parallel.mesh import pad_to_multiple
 from predictionio_tpu.utils import compilation_cache as _cc
@@ -177,24 +178,6 @@ def _mask_scores(scores, allow0, excl, incl, has_incl, positive_only):
     if positive_only:
         allow = allow & (scores > 0)
     return jnp.where(allow, scores, -jnp.inf)
-
-
-def _pack(scores, idx):
-    # scores + raw int32 index bits in ONE buffer: one device->host fetch
-    # per batch, no float cast of ids (2^24 mantissa cliff on large
-    # catalogs) — the _topn_packed layout from ops/als.py
-    return jnp.concatenate(
-        [scores, jax.lax.bitcast_convert_type(idx, jnp.float32)], axis=1
-    )
-
-
-def unpack_topn(packed: np.ndarray, n: int) -> Tuple[np.ndarray, np.ndarray]:
-    """(scores [B, n], global item idx [B, n]) from the packed buffer."""
-    packed = np.asarray(packed)
-    return (
-        packed[:, :n],
-        np.ascontiguousarray(packed[:, n:]).view(np.int32),
-    )
 
 
 def pow2_topk_width(
@@ -284,12 +267,21 @@ def _fused_topn_single(
     scaling + mask scatter + top_k, no [B, N] score materialization on
     host and no host post-filter (the pre-round-12 ecommerce predict
     computed the full score row in numpy and masked it in Python)."""
-    scores = jnp.dot(q, Y.T, preferred_element_type=jnp.float32)
+    scores = _exact_scores(q, Y)
     if normalize:
         scores = scores * rn[None, :]
     scores = _mask_scores(scores, allow0, excl, incl, has_incl, positive_only)
     s, i = jax.lax.top_k(scores, n)
-    return _pack(s, i)
+    return _pack_topn(s, i)
+
+
+def _exact_scores(q, Y):
+    """The float32 tier's score block. ``precision="highest"`` because a
+    TPU's default matmul precision rounds float32 operands to bfloat16:
+    the exact tier must order items as a float32 host reference does."""
+    return jnp.dot(
+        q, Y.T, preferred_element_type=jnp.float32, precision="highest"
+    )
 
 
 def _approx_scores(q, Yq, scale, precision):
@@ -326,7 +318,7 @@ def _rescore_exact(
     rows = jnp.take(Yq, i1, axis=0).astype(jnp.float32)
     if precision == "int8":
         rows = rows * jnp.take(scale, i1)[:, :, None]
-    rescored = jnp.einsum("bk,bck->bc", q, rows)
+    rescored = jnp.einsum("bk,bck->bc", q, rows, precision="highest")
     if normalize:
         rescored = rescored * jnp.take(rn, i1)
     if positive_only:
@@ -360,7 +352,7 @@ def _fused_topn_single_2s(
         q, Yq, scale, s1, i1, rn, positive_only, normalize, precision
     )
     s, j = jax.lax.top_k(rescored, n)
-    return _pack(s, jnp.take_along_axis(i1, j, axis=1))
+    return _pack_topn(s, jnp.take_along_axis(i1, j, axis=1))
 
 
 def _shard_topk_kernel_2s(
@@ -391,7 +383,7 @@ def _shard_topk_kernel_2s(
         q, Yq, scale, s1, i1, rn, positive_only, normalize, precision
     )
     s, j = jax.lax.top_k(rescored, n_local)
-    return _pack(s, jnp.take_along_axis(i1, j, axis=1) + off)
+    return _pack_topn(s, jnp.take_along_axis(i1, j, axis=1) + off)
 
 
 def _shard_topk_kernel(
@@ -410,7 +402,7 @@ def _shard_topk_kernel(
         # which .at[] would WRAP NumPy-style back into this shard
         return jnp.where((g >= off) & (g < off + rows_l), g - off, rows_l)
 
-    scores = jnp.dot(q, Y.T, preferred_element_type=jnp.float32)
+    scores = _exact_scores(q, Y)
     if normalize:
         scores = scores * rn[None, :]
     scores = _mask_scores(
@@ -418,7 +410,7 @@ def _shard_topk_kernel(
         positive_only,
     )
     s, i = jax.lax.top_k(scores, n_local)
-    return _pack(s, i + off)
+    return _pack_topn(s, i + off)
 
 
 @functools.partial(jax.jit, static_argnames=("n", "n_local", "rep_s"))
@@ -435,12 +427,12 @@ def _merge_candidates(packed, n, n_local, rep_s):
     B = x.shape[0]
     S = x.shape[1] // (2 * n_local)
     x = x.reshape(B, S, 2, n_local)
-    s_cand = x[:, :, 0, :].reshape(B, S * n_local)
-    i_cand = jax.lax.bitcast_convert_type(
-        x[:, :, 1, :], jnp.int32
+    s_cand = jax.lax.bitcast_convert_type(
+        x[:, :, 0, :], jnp.float32
     ).reshape(B, S * n_local)
+    i_cand = x[:, :, 1, :].reshape(B, S * n_local)
     s, j = jax.lax.top_k(s_cand, n)
-    return _pack(s, jnp.take_along_axis(i_cand, j, axis=1))
+    return _pack_topn(s, jnp.take_along_axis(i_cand, j, axis=1))
 
 
 # --- metric families (get-or-create per call: dict lookups at batch
@@ -1046,7 +1038,8 @@ class ItemRetriever:
         if S <= 1 or not len(cand):
             return
         arr = cand.reshape(cand.shape[0], S, 2, n_local)
-        live = (arr[:, :, 0, :] > -np.inf).sum(axis=(0, 2)).astype(float)
+        cand_scores = np.ascontiguousarray(arr[:, :, 0, :]).view(np.float32)
+        live = (cand_scores > -np.inf).sum(axis=(0, 2)).astype(float)
         g = _m_shard_candidates()
         for s in range(S):
             g.labels(shard=str(s)).set(float(live[s]))
@@ -1054,8 +1047,7 @@ class ItemRetriever:
             _m_shard_skew().labels(kind="candidates").set(
                 float(live.max() / live.mean())
             )
-        idx = np.ascontiguousarray(host[:, n:]).view(np.int32)
-        scores = host[:, :n]
+        scores, idx = unpack_topn(host, n)
         owners = idx[scores > -np.inf] // (self._n_pad // S)
         counts = np.bincount(owners, minlength=S).astype(float)
         if counts.mean() > 0:
@@ -1131,14 +1123,14 @@ class ItemRetriever:
                     P(None,),       # has_incl
                 )
             fn = jax.jit(
-                shard_map(
+                jax.shard_map(
                     kernel,
                     mesh=self.mesh,
                     in_specs=in_specs,
                     # per-shard candidate blocks concatenate along the
                     # candidate dim: the stage-1 output STAYS sharded
                     out_specs=P(None, axis),
-                    check_rep=False,
+                    check_vma=False,
                 )
             )
             self._stage1_cache[key] = fn
@@ -1241,10 +1233,7 @@ def naive_topn_reference(
     it is what serving did before round 12."""
     Y = np.asarray(item_factors, np.float32)
     q = np.atleast_2d(np.asarray(query_rows, np.float32))
-    scores = np.asarray(
-        jnp.dot(jnp.asarray(q), jnp.asarray(Y).T,
-                preferred_element_type=jnp.float32)
-    ).copy()
+    scores = np.asarray(_exact_scores(jnp.asarray(q), jnp.asarray(Y))).copy()
     if normalize:
         scores *= _reciprocal_norms(Y)[None, :]
     b, N = scores.shape

@@ -1372,6 +1372,8 @@ def _attribute_phases(timer, timings: dict) -> None:
         ("stream:delta-fold", "fold_exposed_s", False),
         ("stream:pack-exposed", "pack_exposed_s", False),
         ("stream:device-put-exposed", "device_put_exposed_s", False),
+        ("stream:device-pack-dispatch", "device_pack_dispatch_s", False),
+        ("stream:device-pack-exposed", "device_pack_exposed_s", False),
         ("stream:compile", "compile_s", True),
         ("stream:compile-exposed", "compile_exposed_s", False),
         ("stream:device-loop", "device_loop_s", False),
@@ -1642,12 +1644,11 @@ def train_als_streaming(
             + (factor_state[0].nbytes if warm_arrays is not None else 0)
             + sum(int(a.nbytes) for a in factor_state[2:])
         )
+        import jax
+
         t0 = time.perf_counter()
-        # aux was enqueued last: fetching it (small) fences the serialized
-        # transfer queue behind the COO chunks; the 1-element fence then
-        # waits out the concat/unpack tail
-        _als._sync_fetch(device_wire[2])
-        _als._fence((device_wire[0], device_wire[1]))
+        # waits out the transfers and the concat/unpack tail behind them
+        jax.block_until_ready(device_wire)
         timings["device_put_exposed_s"] = time.perf_counter() - t0
 
     try:

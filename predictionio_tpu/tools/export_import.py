@@ -382,7 +382,7 @@ def _columnar_import_qualify(table):
                 return bool(p == v) or bool(np.isnan(p) and np.isnan(v))
 
             def sidecar_sample_agrees(pv_np: "np.ndarray") -> bool:
-                # Vectorized sample validation (ADVICE.md): regex-parse
+                # Vectorized sample validation: regex-parse
                 # a bounded strided SAMPLE of the properties JSON —
                 # always including the rows holding the sidecar's min
                 # and max, so the cheap aggregates (non-null count was

@@ -2,17 +2,20 @@
 
 No reference analog — the reference's JVM/Spark substrate has no
 compilation step, while every first train/eval/serve here pays an XLA
-compile (20-40 s for the fused ALS loop on a real TPU). Persisting
-compiled executables across processes removes that cost from every run
-after the first: `pio train` today, redeploys, repeated tuning sweeps,
+compile. Persisting compiled executables across processes removes that
+cost from every run after the first: `pio train` today, redeploys, repeated tuning sweeps,
 and engine-server restarts all reuse yesterday's executables as long as
 shapes (bucketed — ops/als.py pack_segments) and the jax/XLA version
 match. JAX keys cache entries by program + compile options, so reuse is
 always sound.
 
-Layout: ``$PIO_COMPILATION_CACHE_DIR``, default
-``$PIO_FS_BASEDIR/compilation_cache`` (beside the localfs/sqlite
-storage universe). Set ``PIO_COMPILATION_CACHE_DIR=off`` to disable.
+Layout — one rule: if ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own
+handling of it stands and this module sets no directory; otherwise the
+cache is ``<checkout>/.jax_cache`` (beside the package, gitignored). The
+directory is part of every cache key, so it never derives from a temp
+name, a pid, the time, or per-run storage settings — a path that moves
+never hits. ``JAX_ENABLE_COMPILATION_CACHE=false`` (JAX's own switch)
+disables it.
 
 **Executable-cache accounting (the device-observability round).** The
 framework's in-memory executable caches — the ALS geometry-bucket
@@ -236,47 +239,43 @@ def persistent_cache_stats() -> Dict[str, int]:
     return {"entries": entries, "bytes": total}
 
 
+def default_cache_dir() -> str:
+    """``<checkout>/.jax_cache``: a fixed path derived from where the
+    package lives, so every process of one checkout shares one cache."""
+    package_dir = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    return os.path.join(os.path.dirname(package_dir), ".jax_cache")
+
+
 def ensure_compilation_cache() -> Optional[str]:
-    """Point JAX at the persistent cache directory (idempotent; best
-    effort — failures log and fall back to in-memory-only caching).
-    Returns the directory in use, or None when disabled/failed."""
+    """Turn on the persistent cache (idempotent) and return the directory
+    in use, or None when JAX's cache is disabled. With
+    ``JAX_COMPILATION_CACHE_DIR`` set the directory is JAX's to place;
+    otherwise it is :func:`default_cache_dir`."""
     global _configured
-    if _configured:
-        import jax
+    import jax
 
-        return jax.config.jax_compilation_cache_dir or None
-    _configured = True
-    path = os.environ.get("PIO_COMPILATION_CACHE_DIR")
-    if path is not None and path.lower() in ("off", "none", "0", ""):
+    if not _configured:
+        _configured = True
+        # What the environment sets (JAX reads JAX_<NAME> itself) stands;
+        # the rest are this framework's defaults.
+        defaults = {
+            # Cache every program the framework compiles — the default
+            # 1 s floor would skip the small serving/predict executables
+            # whose cold compiles are exactly the deploy-time tail
+            # latency the warm-up hook exists to hide.
+            "jax_persistent_cache_min_compile_time_secs": 0.0,
+            # bound on-disk growth (LRU eviction): tuning sweeps and
+            # jax/XLA version bumps would otherwise accumulate forever
+            "jax_compilation_cache_max_size": 4 * 1024**3,
+        }
+        for name, value in defaults.items():
+            if not os.environ.get(name.upper()):
+                jax.config.update(name, value)
+        if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+            jax.config.update("jax_compilation_cache_dir", default_cache_dir())
+        logger.info(
+            "XLA compilation cache at %s", jax.config.jax_compilation_cache_dir
+        )
+    if not jax.config.jax_enable_compilation_cache:
         return None
-    if path is None:
-        from predictionio_tpu.utils.fs import fs_basedir
-
-        path = os.path.join(fs_basedir(), "compilation_cache")
-    try:
-        import jax
-
-        os.makedirs(path, exist_ok=True)
-        # thresholds first: if any knob is missing on this jax version we
-        # bail out BEFORE activating the on-disk cache, so a None return
-        # is never half-configured.
-        # Cache every program the framework compiles — the default 1 s
-        # floor would skip the small serving/predict executables whose
-        # cold compiles are exactly the deploy-time tail latency the
-        # warm-up hook exists to hide.
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        # bound on-disk growth (LRU eviction): tuning sweeps and jax/XLA
-        # version bumps would otherwise accumulate entries forever
-        jax.config.update("jax_compilation_cache_max_size", 4 * 1024**3)
-        jax.config.update("jax_compilation_cache_dir", path)
-        logger.info("XLA compilation cache at %s", path)
-        return path
-    except Exception as e:  # unwritable dir, old jax — never fatal
-        try:
-            import jax
-
-            jax.config.update("jax_compilation_cache_dir", "")
-        except Exception:
-            pass
-        logger.warning("compilation cache disabled: %s", e)
-        return None
+    return jax.config.jax_compilation_cache_dir or None

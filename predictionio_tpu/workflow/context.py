@@ -65,8 +65,11 @@ class WorkflowContext:
             # JVM substrate has no compilation step)
             ensure_compilation_cache()
             self._mesh = default_mesh()
+            first = self._mesh.devices.flat[0]
             logger.info(
-                "%s: created %s", self.app_name, dict(self._mesh.shape)
+                "%s: created %s on platform=%s device_kind=%r",
+                self.app_name, dict(self._mesh.shape),
+                first.platform, first.device_kind,
             )
         return self._mesh
 

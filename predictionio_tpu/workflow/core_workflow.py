@@ -152,7 +152,12 @@ class CoreWorkflow:
             # rounds (a leaking round shows here before it OOMs)
             from predictionio_tpu.utils import health as _health
 
-            _health.record_memory_gauges()
+            # logged as well: a one-shot `pio train` takes its registry
+            # with it when it exits
+            logger.info(
+                "memory after training: %s",
+                json.dumps(_health.record_memory_gauges(), sort_keys=True),
+            )
             if ctx.timer.records:
                 logger.info("training phases:\n%s", ctx.timer.summary())
                 hidden = ctx.timer.overlapped_total()

@@ -564,3 +564,51 @@ class TestSubspaceSolver:
         cfg = ALSConfig(rank=4, iterations=2, solver="subspace", block_size=2)
         with pytest.raises(ValueError, match="solver='exact'"):
             train_als_grid(u, i, r, 60, 40, cfg, [0.01, 0.1])
+
+
+class TestWarmupCompile:
+    def test_refused_warmup_compile_is_logged_at_error_level(
+        self, monkeypatch, caplog
+    ):
+        """A background warm-up compile the device refuses must surface:
+        a one-shot `pio train` takes its metrics registry (and the
+        `error` outcome counted there) with it when it exits, so the log
+        line is what an operator — and chip_smoke.py — can see."""
+        import logging
+
+        from predictionio_tpu.ops import als
+
+        def refuse(*args, **kwargs):
+            raise RuntimeError("RESOURCE_EXHAUSTED: program does not fit")
+
+        monkeypatch.setattr(als, "_run_iterations", refuse)
+        counts = np.array([3, 1, 2], np.int32)
+        geo = als._segment_geometry(counts, 3, 8, 1, 4096)
+        with caplog.at_level(logging.ERROR, logger=als.logger.name):
+            rec = als.start_compile_async(
+                3, 3, geo, geo, 8, 8, als.ALSConfig(rank=7)
+            )()
+        assert "RESOURCE_EXHAUSTED" in rec["error"]
+        errors = [r for r in caplog.records if r.levelno == logging.ERROR]
+        assert len(errors) == 1
+        assert "ALS warm-up compile failed" in errors[0].getMessage()
+        assert errors[0].exc_info is not None
+
+
+class TestPackedTopN:
+    def test_packed_buffer_is_integer_typed(self):
+        """The one buffer that carries scores AND indices must be int32:
+        a TPU flushes float32 subnormals to zero, and a small index's
+        bits read as a float32 ARE a subnormal — in a float buffer every
+        served index came back 0 on the chip (the CPU never flushes, so
+        only the dtype can be held here; chip_smoke.py holds the lists)."""
+        from predictionio_tpu.ops.als import _pack_topn, unpack_topn
+
+        scores = jnp.asarray([[3.5, -jnp.inf, 1e-42]], jnp.float32)
+        idx = jnp.asarray([[7, 26_743, 2**24 + 1]], jnp.int32)
+        packed = _pack_topn(scores, idx)
+        assert packed.dtype == jnp.int32
+        s, i = unpack_topn(packed, 3)
+        assert s.dtype == np.float32 and i.dtype == np.int32
+        np.testing.assert_array_equal(s, np.asarray(scores))
+        np.testing.assert_array_equal(i, np.asarray(idx))

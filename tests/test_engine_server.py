@@ -203,7 +203,7 @@ class TestBatchingPipeline:
                     self.running += 1
                     self.max_running = max(self.max_running, self.running)
                 try:
-                    time.sleep(0.05)  # a relay-bound result fetch
+                    time.sleep(0.05)  # a slow result fetch
                 finally:
                     with self._lock:
                         self.running -= 1
@@ -282,7 +282,7 @@ class TestBatchingPipeline:
         ex.close()  # idempotent
 
     def test_close_returns_despite_wedged_serve(self):
-        """A serve_batch stuck on a dead device/relay call must not hang
+        """A serve_batch stuck on a dead device call must not hang
         close() (round-4 advisor): the pool shutdown is non-blocking; the
         in-flight slot stays pending but the server shuts down."""
         import time

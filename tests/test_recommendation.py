@@ -79,7 +79,7 @@ def engine_params(app_name="testapp", eval_k=None, **algo_kw):
     )
 
 
-class TestStoreLayer:
+class TestStorageLayer:
     def test_find_columns(self, mem_storage):
         populate(mem_storage)
         store = PEventStore(mem_storage)

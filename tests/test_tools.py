@@ -622,7 +622,7 @@ class TestColumnarParquetImport:
         qualify WITHOUT regex-reparsing the FULL property JSON it
         rendered — the typed propKey/propValue sidecar carries the
         values. The sidecar's own validation regex-parses a BOUNDED
-        sample (ADVICE.md round 5), so the trap below only fires on
+        sample (round 5), so the trap below only fires on
         event-sized inputs: a silently-dead sidecar path falling through
         to the full regex reparse FAILS here instead of passing."""
         import numpy as np
@@ -969,3 +969,99 @@ class TestFleetSupervisor:
         assert row["restarts"] == 3
         out = render([row])
         assert "RESTART" in out.splitlines()[0]
+
+
+class TestDeployFleetDevices:
+    """One process per chip: the `pio deploy --workers` supervisor stays
+    off JAX (a parent that touched the runtime would hold the chips its
+    workers need) and hands every worker its devices before it starts."""
+
+    def test_supervisor_parent_never_imports_jax(self, tmp_path):
+        """The whole supervisor path — argument parsing, storage
+        validation, device assignment, fleet hand-off — in a child
+        interpreter, asserting on ITS ``sys.modules`` (this process
+        imported jax long ago)."""
+        import os
+        import subprocess
+        import sys
+
+        (tmp_path / "engine.json").write_text(
+            json.dumps({"engineFactory": "x.Factory"})
+        )
+        script = tmp_path / "supervise.py"
+        script.write_text(
+            "import json, sys\n"
+            "from predictionio_tpu.tools import cli, fleet\n"
+            "seen = {}\n"
+            "def fake_fleet(spawn, workers, **kw):\n"
+            "    seen['workers'] = workers\n"
+            "    return 0\n"
+            "fleet.run_worker_fleet = fake_fleet\n"
+            "cli._probe_devices = lambda: ('cpu', 8)\n"
+            "rc = cli.main(['deploy', '-v', sys.argv[1], '--port', '8123',"
+            " '--workers', '2'])\n"
+            "print(json.dumps({'rc': rc, 'workers': seen.get('workers'),"
+            " 'jax': sorted(m for m in sys.modules"
+            " if m == 'jax' or m.startswith('jax.'))}))\n"
+        )
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env = {
+            **os.environ,
+            "PYTHONPATH": root,
+            "PIO_FS_BASEDIR": str(tmp_path),
+            "PIO_STORAGE_SOURCES_SQLITE_TYPE": "sqlite",
+            "PIO_STORAGE_SOURCES_SQLITE_PATH": str(tmp_path / "s.db"),
+        }
+        for repo in ("METADATA", "EVENTDATA", "MODELDATA"):
+            env[f"PIO_STORAGE_REPOSITORIES_{repo}_NAME"] = repo.lower()
+            env[f"PIO_STORAGE_REPOSITORIES_{repo}_SOURCE"] = "SQLITE"
+        out = subprocess.run(
+            [sys.executable, str(script), str(tmp_path / "engine.json")],
+            capture_output=True, text=True, timeout=120, env=env,
+        )
+        assert out.returncode == 0, out.stderr
+        report = json.loads(out.stdout.strip().splitlines()[-1])
+        assert report == {"rc": 0, "workers": 2, "jax": []}
+
+    def test_cpu_workers_index_the_shared_virtual_devices(self):
+        from predictionio_tpu.tools.cli import _assign_worker_devices
+
+        assert _assign_worker_devices("cpu", 8, None, 2) == [
+            ("0,2,4,6", {}), ("1,3,5,7", {}),
+        ]
+        assert _assign_worker_devices("cpu", 8, "3", 2) == [
+            ("3", {}), ("3", {}),
+        ]
+        assert _assign_worker_devices("cpu", 1, None, 2) == [
+            (None, {}), (None, {}),
+        ]
+
+    def test_tpu_workers_get_disjoint_chips_before_jax_starts(self):
+        from predictionio_tpu.tools.cli import _assign_worker_devices
+
+        four = _assign_worker_devices("tpu", 4, None, 4)
+        assert [e["TPU_VISIBLE_CHIPS"] for _, e in four] == list("0123")
+        # --serving-device indexes what the worker can see: its one chip
+        assert {d for d, _ in four} == {"0"}
+        assert {e["TPU_CHIPS_PER_PROCESS_BOUNDS"] for _, e in four} == {
+            "1,1,1"
+        }
+        assert len({e["TPU_PROCESS_PORT"] for _, e in four}) == 4
+        two = _assign_worker_devices("tpu", 4, "0,1,2,3", 2)
+        assert [(d, e["TPU_VISIBLE_CHIPS"]) for d, e in two] == [
+            ("0,1", "0,1"), ("0,1", "2,3"),
+        ]
+
+    @pytest.mark.parametrize(
+        "n_dev,serving_device,workers",
+        [(1, None, 2), (4, None, 3), (4, "0", 2)],
+    )
+    def test_tpu_fleet_that_would_share_a_chip_is_refused(
+        self, n_dev, serving_device, workers
+    ):
+        """Fails at once with the reason — never a hang or a crash loop
+        under the supervisor's restart logic."""
+        from predictionio_tpu.tools.cli import _assign_worker_devices
+
+        with pytest.raises(CommandError, match="one process at a time"):
+            _assign_worker_devices("tpu", n_dev, serving_device, workers)

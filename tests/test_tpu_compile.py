@@ -1,0 +1,211 @@
+"""The main path's device programs, compiled for a described TPU v5e at
+ML-20M width — no chip attached, nothing runs.
+
+The TPU's compiler is installed beside JAX and compiles for a topology
+that is described, not attached. What it refuses here (a program that
+does not fit 16 GB of HBM, a sharding it cannot partition) the chip would
+refuse too, so these guard every later PR at no chip time. A compile
+that passes is not a chip run: ``chip_smoke.py`` is that.
+
+Shapes are the recommendation template's at the ML-20M size the smoke
+trains (138,493 users x 26,744 items, rank 32, 20M observations:
+segment width 128, 8 + 6 chunks of 32,768 segments).
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process at a time may load the TPU's library, and under
+pytest-xdist every worker imports this file, so touching ``topologies``
+at import (or in a ``skipif`` / ``parametrize``) would break collection
+for the whole suite. The persistent compile cache is off around these
+compiles — an entry compiled for a described device is written but can
+never be read back without the chip.
+"""
+
+import functools
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import SingleDeviceSharding
+
+from predictionio_tpu.ops import als, retrieval
+
+N_USERS, N_ITEMS, RANK = 138_493, 26_744, 32
+CHUNKS_U, CHUNKS_I, SC, L = 8, 6, 32_768, 128
+BATCH = 128
+HBM_BYTES = 16 * 2**30  # one v5e chip
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(
+                platform="tpu", topology_name="v5e:2x2"
+            )
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    return Mesh(np.array(topo.devices).reshape(4), ("data",))
+
+
+def _shape(shape, dtype, sharding):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _loop_args(n_shards, row, seg2, seg3, scalar):
+    """Arguments of ``_run_iterations`` as ``train_als`` /
+    ``train_from_wire`` place them: factors, lam and has_obs row-sharded,
+    the packs sharded on the segment dim."""
+    r_u = als._padded_rows(N_USERS, n_shards)
+    r_i = als._padded_rows(N_ITEMS, n_shards)
+
+    def pack(chunks):
+        return (
+            _shape((chunks, SC), jnp.int32, seg2),
+            _shape((chunks, SC, L), jnp.int32, seg3),
+            _shape((chunks, SC, L), jnp.float32, seg3),
+            _shape((chunks, SC), jnp.int32, seg2),
+        )
+
+    return (
+        _shape((r_u, RANK), jnp.float32, row),
+        _shape((r_i, RANK), jnp.float32, row),
+        pack(CHUNKS_U), pack(CHUNKS_I),
+        _shape((r_u,), jnp.float32, row),
+        _shape((r_i,), jnp.float32, row),
+        _shape((r_u,), jnp.bool_, row),
+        _shape((r_i,), jnp.bool_, row),
+        1.0,
+        _shape((), jnp.int32, scalar),
+    )
+
+
+def _device_bytes(compiled) -> int:
+    """Bytes one device must hold to run the program."""
+    m = compiled.memory_analysis()
+    return (
+        m.argument_size_in_bytes + m.output_size_in_bytes
+        + m.temp_size_in_bytes - m.alias_size_in_bytes
+    )
+
+
+@pytest.mark.parametrize(
+    "solver,block_size", [("exact", 0), ("subspace", 8)]
+)
+def test_fused_loop_float32_fits_one_chip(one_chip, solver, block_size):
+    """What ``pio train`` runs on one chip (no engine passes
+    ``compute_dtype``, so float32), for both solvers."""
+    args = _loop_args(1, one_chip, one_chip, one_chip, one_chip)
+    compiled = als._run_iterations.lower(
+        *args, implicit=False, compute_dtype="float32",
+        rep_sharding=None, row_sharding=None, telemetry=True,
+        solver=solver, block_size=block_size,
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_fused_loop_shards_over_four_chips(mesh4):
+    """The sharded ``train_als`` program: per-device memory must be a
+    fraction of the one-chip program's (nothing store-sized replicated
+    or left whole on device 0), with the factor handoff compiled to
+    collectives."""
+    row = NamedSharding(mesh4, P("data"))
+    rep = NamedSharding(mesh4, P())
+    args = _loop_args(
+        4, row,
+        NamedSharding(mesh4, P(None, "data")),
+        NamedSharding(mesh4, P(None, "data", None)),
+        rep,
+    )
+    compiled = als._run_iterations.lower(
+        *args, implicit=False, compute_dtype="float32",
+        rep_sharding=rep, row_sharding=row, telemetry=True,
+        solver="exact", block_size=0,
+    ).compile()
+    # the one-chip program needs ~8.8 GB; a quarter of it plus the
+    # replicated counter-side factors stays well under 4 GiB
+    assert _device_bytes(compiled) < 4 * 2**30
+    assert re.search(r"\ball-gather", compiled.as_text())
+
+
+def test_serving_topn_compiles(one_chip):
+    compiled = als._topn_packed.lower(
+        _shape((BATCH, RANK), jnp.float32, one_chip),
+        _shape((N_ITEMS, RANK), jnp.float32, one_chip),
+        n=16,
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def _stage1_shapes(replicated, rows, rows_k):
+    return (
+        _shape((BATCH, RANK), jnp.float32, replicated),
+        _shape((N_ITEMS, RANK), jnp.int8, rows_k),
+        _shape((N_ITEMS,), jnp.float32, rows),  # per-row scales
+        _shape((N_ITEMS,), jnp.float32, rows),  # reciprocal norms
+        _shape((N_ITEMS,), jnp.bool_, rows),  # candidacy mask
+        _shape((BATCH, 1), jnp.int32, replicated),
+        _shape((BATCH, 1), jnp.int32, replicated),
+        _shape((BATCH,), jnp.bool_, replicated),
+    )
+
+
+def test_int8_stage1_single_device_compiles(one_chip):
+    compiled = retrieval._fused_topn_single_2s.lower(
+        *_stage1_shapes(one_chip, one_chip, one_chip),
+        n=64, shortlist=64, positive_only=False, normalize=False,
+        precision="int8",
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_int8_stage1_shards_over_four_chips(mesh4):
+    """The ``shard_map`` stage-1 program ``ItemRetriever`` builds on a
+    mesh: each device holds a quarter of the quantized catalog."""
+    kernel = functools.partial(
+        retrieval._shard_topk_kernel_2s, axis="data", n_local=64,
+        shortlist=64, positive_only=False, normalize=False,
+        precision="int8",
+    )
+    fn = jax.jit(
+        jax.shard_map(
+            kernel, mesh=mesh4,
+            in_specs=(
+                P(None, None), P("data", None), P("data"), P("data"),
+                P("data"), P(None, None), P(None, None), P(None),
+            ),
+            out_specs=P(None, "data"),
+            check_vma=False,
+        )
+    )
+    assert N_ITEMS % 4 == 0
+    compiled = fn.lower(
+        *_stage1_shapes(
+            NamedSharding(mesh4, P()),
+            NamedSharding(mesh4, P("data")),
+            NamedSharding(mesh4, P("data", None)),
+        )
+    ).compile()
+    catalog_bytes = N_ITEMS * (RANK + 4 + 4 + 1)  # rows + scale + rn + mask
+    assert compiled.memory_analysis().argument_size_in_bytes < catalog_bytes / 2

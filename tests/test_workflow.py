@@ -273,73 +273,82 @@ class TestFastEvalEngine:
         assert [r[1] for r in res_plain] == [r[1] for r in res_fast]
 
 
+_CACHE_PROBE = (
+    "import os\n"
+    "import jax\n"
+    "jax.config.update('jax_platforms', 'cpu')\n"
+    "events = []\n"
+    "jax.monitoring.register_event_listener("
+    "lambda name, **kw: events.append(name))\n"
+    "from predictionio_tpu.utils.compilation_cache import ("
+    "ensure_compilation_cache)\n"
+    "d1 = ensure_compilation_cache()\n"
+    "d2 = ensure_compilation_cache()  # idempotent\n"
+    "assert d1 == d2, (d1, d2)\n"
+    "import jax.numpy as jnp\n"
+    "f = jax.jit(lambda x: jax.lax.fori_loop("
+    "0, 50, lambda i, a: jnp.tanh(a @ a) + i, x))\n"
+    "f(jnp.ones((128, 128))).block_until_ready()\n"
+    "print('DIR', d1, flush=True)\n"
+    "print('HITS', events.count("
+    "'/jax/compilation_cache/cache_hits'), flush=True)\n"
+)
+
+
+def _run_cache_probe(tmp_path, **env_overrides):
+    """One child interpreter per call: jax's compilation-cache config is
+    process-global, and a cache hit only means something across
+    processes."""
+    import os
+    import subprocess
+    import sys
+
+    script = tmp_path / "probe.py"
+    script.write_text(_CACHE_PROBE)
+    env = {**os.environ, "PYTHONPATH": _repo_root()}
+    for name in ("XLA_FLAGS", "JAX_COMPILATION_CACHE_DIR", "PIO_FS_BASEDIR"):
+        env.pop(name, None)
+    env.update(env_overrides)
+    out = subprocess.run(
+        [sys.executable, str(script)],
+        capture_output=True, text=True, timeout=120, env=env,
+    )
+    assert out.returncode == 0, out.stderr
+    fields = dict(
+        line.split(" ", 1) for line in out.stdout.splitlines() if " " in line
+    )
+    return fields["DIR"], int(fields["HITS"])
+
+
 class TestCompilationCache:
-    def test_cache_populates_and_is_idempotent(self, tmp_path, monkeypatch):
-        """First accelerator touch persists compiled executables under
-        PIO_COMPILATION_CACHE_DIR so later processes skip XLA compiles
-        (no reference analog — the JVM substrate has no compile step).
-        Run in a subprocess: jax compilation-cache config is global."""
-        import subprocess
-        import sys
+    """The one placement rule (utils/compilation_cache.py): JAX's own
+    ``JAX_COMPILATION_CACHE_DIR`` when it is set, otherwise
+    ``<checkout>/.jax_cache`` — never a path built from per-run storage
+    settings, because a directory that moves never hits."""
 
+    def test_env_dir_is_filled_then_hit_by_a_second_process(self, tmp_path):
         cache_dir = tmp_path / "cc"
-        script = tmp_path / "probe.py"
-        script.write_text(
-            "import jax\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
-            "from predictionio_tpu.utils.compilation_cache import ("
-            "ensure_compilation_cache)\n"
-            "d1 = ensure_compilation_cache()\n"
-            "d2 = ensure_compilation_cache()  # idempotent\n"
-            "assert d1 == d2, (d1, d2)\n"
-            "import jax.numpy as jnp\n"
-            "f = jax.jit(lambda x: jax.lax.fori_loop("
-            "0, 50, lambda i, a: jnp.tanh(a @ a) + i, x))\n"
-            "f(jnp.ones((128, 128))).block_until_ready()\n"
-            "print('DIR', d1, flush=True)\n"
-        )
+        env = {"JAX_COMPILATION_CACHE_DIR": str(cache_dir)}
+        d, hits = _run_cache_probe(tmp_path, **env)
+        assert d == str(cache_dir)
+        assert hits == 0
+        entries = sorted(p.name for p in cache_dir.iterdir())
+        assert entries, "no cache entries written"
+        d, hits = _run_cache_probe(tmp_path, **env)
+        assert d == str(cache_dir)
+        assert hits >= 1, "second process recompiled instead of hitting"
+        assert sorted(p.name for p in cache_dir.iterdir()) == entries
+
+    def test_default_is_checkout_dir_whatever_fs_basedir_is(self, tmp_path):
         import os
 
-        env = {
-            **os.environ,
-            "PYTHONPATH": _repo_root(),
-            "PIO_COMPILATION_CACHE_DIR": str(cache_dir),
-        }
-        env.pop("XLA_FLAGS", None)
-        out = subprocess.run(
-            [sys.executable, str(script)],
-            capture_output=True, text=True, timeout=120, env=env,
-        )
-        assert out.returncode == 0, out.stderr
-        assert str(cache_dir) in out.stdout
-        assert list(cache_dir.iterdir()), "no cache entries written"
-
-    def test_off_disables(self, tmp_path):
-        import subprocess
-        import sys
-        import os
-
-        script = tmp_path / "probe.py"
-        script.write_text(
-            "import jax\n"
-            "jax.config.update('jax_platforms', 'cpu')\n"
-            "from predictionio_tpu.utils.compilation_cache import ("
-            "ensure_compilation_cache)\n"
-            "assert ensure_compilation_cache() is None\n"
-            "print('DISABLED OK', flush=True)\n"
-        )
-        env = {
-            **os.environ,
-            "PYTHONPATH": _repo_root(),
-            "PIO_COMPILATION_CACHE_DIR": "off",
-        }
-        env.pop("XLA_FLAGS", None)
-        out = subprocess.run(
-            [sys.executable, str(script)],
-            capture_output=True, text=True, timeout=120, env=env,
-        )
-        assert out.returncode == 0, out.stderr
-        assert "DISABLED OK" in out.stdout
+        expected = os.path.join(_repo_root(), ".jax_cache")
+        dirs = [
+            _run_cache_probe(tmp_path, PIO_FS_BASEDIR=str(tmp_path / name))[0]
+            for name in ("run-a", "run-b")
+        ]
+        assert dirs == [expected, expected]
+        assert not (tmp_path / "run-a").exists()
 
 
 def _repo_root():
