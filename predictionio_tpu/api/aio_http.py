@@ -40,9 +40,10 @@ import json
 import logging
 import socket
 import threading
+import time
 import urllib.parse
 from http.client import responses as _REASONS
-from typing import Optional, Tuple
+from typing import Optional, Sequence, Tuple
 
 from predictionio_tpu.api.http import (
     MAX_BODY_BYTES,
@@ -102,10 +103,24 @@ class AsyncJsonHTTPServer:
         port: int,
         name: str,
         reuse_port: bool = False,
+        timed_routes: Sequence[Tuple[str, str]] = (),
     ):
         self.name = name
         self.ip = ip
         self.handle_fn = handle_fn
+        # (method, path) pairs the owning server names as timed: request
+        # parsed → its response handed to the socket, the transport's
+        # whole share of a request. Less the handler's own histogram it
+        # is the event loop's: the wake-up after the handler's future
+        # resolves, json.dumps, the write.
+        self._timed_routes = frozenset(timed_routes)
+        self._m_request = _metrics.get_registry().histogram(
+            "pio_http_request_seconds",
+            "Request parsed until its response was written, for the "
+            "routes the server names as timed",
+            labels=("server",),
+            buckets=_metrics.LATENCY_BUCKETS_S,
+        ).labels(server=name) if self._timed_routes else None
         self._pass_headers = accepts_headers(handle_fn)
         # bind synchronously so construction fails loudly (port conflict,
         # missing SO_REUSEPORT) and .port is known before the loop spins
@@ -292,10 +307,14 @@ class AsyncJsonHTTPServer:
                     _, status, message = req
                     await pending.put(
                         ((status, {"message": message}), False,
-                         "(framing)", None)
+                         "(framing)", None, None)
                     )
                     break
                 _, method, path, query, body, form, headers, keep_alive = req
+                parsed_at = (
+                    time.perf_counter()
+                    if (method, path) in self._timed_routes else None
+                )
                 trace_id = request_trace_id(headers)
                 try:
                     if self._pass_headers:
@@ -312,7 +331,9 @@ class AsyncJsonHTTPServer:
                         ),
                     )
                     result = (500, {"message": str(e)})
-                await pending.put((result, keep_alive, path, trace_id))
+                await pending.put(
+                    (result, keep_alive, path, trace_id, parsed_at)
+                )
                 if not keep_alive:
                     break
         except (ConnectionError, asyncio.IncompleteReadError):
@@ -419,7 +440,7 @@ class AsyncJsonHTTPServer:
             item = await pending.get()
             if item is _CLOSE:
                 return
-            result, keep_alive, route, trace_id = item
+            result, keep_alive, route, trace_id, parsed_at = item
             if discarding:
                 if isinstance(result, concurrent.futures.Future):
                     # best effort: an uncollected query still queued in
@@ -458,6 +479,8 @@ class AsyncJsonHTTPServer:
             record_http_error(self.name, route, status, trace_id)
             try:
                 writer.write(head + data)
+                if parsed_at is not None:
+                    self._m_request.observe(time.perf_counter() - parsed_at)
                 await writer.drain()
             except (ConnectionError, OSError):
                 discarding = True  # peer went away; drain to _CLOSE
@@ -503,14 +526,19 @@ def make_http_server(
     name: str,
     reuse_port: bool = False,
     transport: str = "async",
+    timed_routes: Sequence[Tuple[str, str]] = (),
 ):
     """Transport selector shared by the REST servers: ``async`` is the
     event-loop frontend above, ``threaded`` the stdlib thread-per-
     connection fallback. The caller supplies a transport-appropriate
-    ``handle_fn`` (the threaded frontend cannot await a Future)."""
+    ``handle_fn`` (the threaded frontend cannot await a Future).
+    ``timed_routes`` is the event-loop frontend's
+    (``pio_http_request_seconds``); the threaded fallback times
+    nothing."""
     if transport == "async":
         return AsyncJsonHTTPServer(
-            handle_fn, ip, port, name, reuse_port=reuse_port
+            handle_fn, ip, port, name, reuse_port=reuse_port,
+            timed_routes=timed_routes,
         )
     if transport == "threaded":
         return JsonHTTPServer(

@@ -328,16 +328,18 @@ class DeployedEngine:
         with self._inflight_cond:
             self._inflight += 1
         try:
-            supplemented = [self.serving.supplement(q) for q in queries]
-            indexed = list(enumerate(supplemented))
+            with _tracing.stage(_tracing.HOST_PREP):
+                supplemented = [self.serving.supplement(q) for q in queries]
+                indexed = list(enumerate(supplemented))
             per_algo: List[Dict[int, Any]] = [
                 dict(algo.batch_predict(model, indexed))
                 for algo, model in zip(self.algorithms, self.models)
             ]
-            return [
-                self.serving.serve(q, [pa[i] for pa in per_algo])
-                for i, q in enumerate(queries)
-            ]
+            with _tracing.stage(_tracing.BUILD):
+                return [
+                    self.serving.serve(q, [pa[i] for pa in per_algo])
+                    for i, q in enumerate(queries)
+                ]
         finally:
             with self._inflight_cond:
                 self._inflight -= 1
@@ -419,6 +421,70 @@ class DeployedEngine:
         return self._ledger_scope.bytes()
 
 
+def _version_children(cache: Dict[str, Dict[str, Any]], families, version):
+    """{name: the family's child for ``version``} out of ``cache``,
+    resolved once a version: on the serve thread a ``labels()`` call at
+    every observe is time that every queued request waits for."""
+    children = cache.get(version)
+    if children is None:
+        children = cache[version] = {
+            name: family.labels(version=version)
+            for name, family in families.items()
+        }
+    return children
+
+
+class _StageTimes:
+    """One request's stage boundaries, all on ``time.perf_counter``: the
+    queue entry carries it for every request, and the executor fills it
+    in where the work happens. ``enqueued`` → ``closed`` is the queue
+    wait (the collector closed the batch that holds the request),
+    → ``started`` the slot wait (the in-flight semaphore and the pool
+    hand-off), → ``served`` the batch's predict, charged to each request
+    in it; whoever finishes the request supplies the end of ``finish``.
+    ``trace`` is the request's trace context when it sent
+    ``X-PIO-Trace-Id``; ``attrs`` what the predict span says of the
+    batch."""
+
+    __slots__ = ("trace", "enqueued", "closed", "started", "served", "attrs")
+
+    def __init__(self, trace: Optional["_tracing.TraceContext"] = None):
+        self.trace = trace
+        self.enqueued = self.closed = self.started = self.served = 0.0
+        self.attrs: Optional[Dict[str, Any]] = None
+
+    def stages(self, end: float) -> "tuple[tuple[str, float, float], ...]":
+        """(name, start, seconds) of the four per-request stages, the
+        last one ending at ``end``; they tile ``enqueued`` → ``end``."""
+        return (
+            ("queue_wait", self.enqueued, self.closed - self.enqueued),
+            ("slot_wait", self.closed, self.started - self.closed),
+            ("predict", self.started, self.served - self.started),
+            ("finish", self.served, end - self.served),
+        )
+
+    def record_spans(self, end: float) -> None:
+        """The executor's share of a traced request's chain: ``batch``
+        under the http span, the four stages under ``batch``. Durations
+        are perf_counter differences; only ``startMs`` is wall time."""
+        if self.trace is None or not self.served:
+            return
+        wall_offset = time.time() - time.perf_counter()
+        batch_id = _tracing.new_span_id()
+        for name, start, seconds in self.stages(end):
+            _tracing.record_span(
+                name, self.trace.trace_id, parent_id=batch_id,
+                start_s=start + wall_offset, duration_s=seconds,
+                attrs=self.attrs if name == "predict" else None,
+            )
+        _tracing.record_span(
+            "batch", self.trace.trace_id, span_id=batch_id,
+            parent_id=self.trace.span_id,
+            start_s=self.enqueued + wall_offset,
+            duration_s=end - self.enqueued,
+        )
+
+
 class _BatchingExecutor:
     """Coalesces concurrent requests into device-sized batches.
 
@@ -469,6 +535,42 @@ class _BatchingExecutor:
             labels=("version",),
             buckets=_metrics.BATCH_SIZE_BUCKETS,
         )
+        # where a request's time goes inside the executor, and what one
+        # batch's predict is made of for engines that bracket their
+        # serving path with utils/tracing.stage. One family per stage:
+        # a /metrics reader that sums a family over its label sets
+        # cannot pick a stage out of a label. The executor observes
+        # what it times itself, where that is cheapest: the queue wait
+        # once a request on the collector thread, the slot wait and
+        # predict once a BATCH for its n requests (they share both
+        # durations), the batch stages once a batch; QueryAPI adds
+        # ``finish``. The four request stages tile enqueue → response
+        # built (_StageTimes.stages).
+        self._m_stages = {
+            name: _metrics.get_registry().histogram(
+                f"pio_serving_{name}_seconds",
+                f"{help_}, by model version",
+                labels=("version",),
+                buckets=_metrics.LATENCY_BUCKETS_S,
+            )
+            for name, help_ in (
+                ("queue_wait",
+                 "Enqueue until the collector closed the micro-batch "
+                 "that holds the request"),
+                ("slot_wait",
+                 "Batch closed until a serve thread started it (the "
+                 "in-flight semaphore and the pool hand-off), charged "
+                 "to each request in it"),
+                ("predict",
+                 "The micro-batch's serve_batch call, charged to each "
+                 "request in it"),
+                *((f"batch_{stage}",
+                   f"Seconds of one served micro-batch spent in its "
+                   f"{stage} stage")
+                  for stage in _tracing.BATCH_STAGES),
+            )
+        }
+        self._m_stage_children: Dict[str, Dict[str, Any]] = {}
         self._m_batch_bases = {
             key[0]: child.snapshot()
             for key, child in self._m_batch_fill.children()
@@ -483,19 +585,17 @@ class _BatchingExecutor:
         self,
         deployed: DeployedEngine,
         query: Any,
-        trace: Optional["_tracing.TraceContext"] = None,
+        times: Optional[_StageTimes] = None,
     ) -> "concurrent.futures.Future":
         """Enqueue one query; the returned future resolves to its
         prediction (or raises its per-query error) once the micro-batch
-        it rides is served. ``trace`` (the request's trace id + the http
-        span id) rides the queue entry so the executor can record the
-        batch/predict spans under the request's trace."""
+        it rides is served. ``times`` rides the queue entry: the
+        executor writes the request's stage boundaries into it, and the
+        caller reads them when the future resolves."""
         fut: "concurrent.futures.Future" = concurrent.futures.Future()
-        tinfo = None
-        if trace is not None:
-            # the batch span id is minted NOW so the predict span can
-            # parent on it even though both are recorded at serve time
-            tinfo = (trace, _tracing.new_span_id(), time.time())
+        if times is None:
+            times = _StageTimes()
+        times.enqueued = time.perf_counter()
         # the closed-check and the enqueue share the lock with close()'s
         # sentinel post, so a request can never land behind _STOP in the
         # queue (its future would never resolve)
@@ -505,7 +605,7 @@ class _BatchingExecutor:
             if self._worker is None or not self._worker.is_alive():
                 self._worker = threading.Thread(target=self._run, daemon=True)
                 self._worker.start()
-            self._queue.put((deployed, query, fut, tinfo))
+            self._queue.put((deployed, query, fut, times))
         return fut
 
     def submit(self, deployed: DeployedEngine, query: Any) -> Any:
@@ -571,23 +671,28 @@ class _BatchingExecutor:
 
     def _run(self) -> None:
         while True:
-            first = self._queue.get()
+            with _tracing.annotation("wait_request"):
+                first = self._queue.get()
             if first is self._STOP:
                 return
             batch = [first]
             deadline = time.monotonic() + self.window_ms / 1000.0
-            while len(batch) < self.max_batch:
-                timeout = deadline - time.monotonic()
-                if timeout <= 0:
-                    break
-                try:
-                    item = self._queue.get(timeout=timeout)
-                except queue.Empty:
-                    break
-                if item is self._STOP:
-                    self._queue.put(item)  # re-post for the outer loop
-                    break
-                batch.append(item)
+            with _tracing.annotation("collect"):
+                while len(batch) < self.max_batch:
+                    timeout = deadline - time.monotonic()
+                    if timeout <= 0:
+                        break
+                    try:
+                        item = self._queue.get(timeout=timeout)
+                    except queue.Empty:
+                        break
+                    if item is self._STOP:
+                        self._queue.put(item)  # re-post for the outer loop
+                        break
+                    batch.append(item)
+            closed = time.perf_counter()
+            for item in batch:
+                item[3].closed = closed
             # group by deployed engine (a reload may be in flight)
             groups: Dict[int, List[tuple]] = {}
             for item in batch:
@@ -602,12 +707,22 @@ class _BatchingExecutor:
                 ]
                 if not items:
                     continue
-                self._m_batch_fill.labels(
-                    version=_version_of(items[0][0])
-                ).observe(len(items))
+                version = _version_of(items[0][0])
+                self._m_batch_fill.labels(version=version).observe(
+                    len(items)
+                )
+                # on this thread, not the serve thread: by the time a
+                # response is out its queue wait is on /metrics, and the
+                # serve thread's time is every queued request's
+                observe_wait = _version_children(
+                    self._m_stage_children, self._m_stages, version
+                )["queue_wait"].observe
+                for it in items:
+                    observe_wait(closed - it[3].enqueued)
                 # blocks while pipeline_depth batches are in flight — the
                 # next batch keeps accumulating in self._queue meanwhile
-                self._inflight.acquire()
+                with _tracing.annotation("slot_wait"):
+                    self._inflight.acquire()
                 try:
                     self._serve_pool.submit(
                         self._serve_and_release, items[0][0], items
@@ -623,55 +738,65 @@ class _BatchingExecutor:
                         )
 
     def _serve_and_release(self, dep: DeployedEngine, items) -> None:
-        t0 = time.time()
+        started = time.perf_counter()
         outcomes: List[tuple] = []
         # the batch runs under a serving compile_site (any executable
         # compile inside is a COLD compile: counted per site, span-
-        # recorded, and drained below onto the predict span) and under
+        # recorded, and drained below onto the predict span), under
         # the first traced item's ambient trace, so a compile span
-        # chains into the request's trace tree
+        # chains into the request's trace tree, and under a fresh
+        # accumulator of the engine's stage() durations
         batch_trace = next(
-            (t[0] for _, _, _, t in items if t is not None), None
+            (t.trace for _, _, _, t in items if t.trace is not None), None
         )
         compile_events: List[dict] = []
+        stage_s: Dict[str, float] = {}
+        served = started
         try:
             with self._hb.busy(), _cc.compile_site("serving"), \
-                    _tracing.use(batch_trace):
+                    _tracing.use(batch_trace), \
+                    _tracing.stage_totals() as stage_s, \
+                    _tracing.annotation("predict"):
                 try:
                     self._serve_isolating(dep, items, outcomes)
                 finally:
+                    served = time.perf_counter()
                     compile_events = _cc.drain_compile_events()
         finally:
             self._inflight.release()
-            t1 = time.time()
-            for _, _, _, tinfo in items:
-                if tinfo is None:
-                    continue
-                trace, batch_span_id, enqueued = tinfo
-                # predict: the device serve_batch call (incl. bisect
-                # retries); batch: queue wait + serve, the executor's
-                # whole share of the request
-                predict_attrs: Dict[str, Any] = {"batch_size": len(items)}
+            observe = _version_children(
+                self._m_stage_children, self._m_stages, _version_of(dep)
+            )
+            n = len(items)
+            observe["slot_wait"].observe(started - items[0][3].closed, n)
+            observe["predict"].observe(served - started, n)
+            for name, seconds in stage_s.items():
+                observe["batch_" + name].observe(seconds)
+            predict_attrs: Optional[Dict[str, Any]] = None
+            if batch_trace is not None:
+                # what a traced request's predict span says of the
+                # batch: its size, the engine's stages, cold compiles
+                predict_attrs = {"batch_size": n}
+                if stage_s:
+                    predict_attrs["stages_ms"] = {
+                        name: round(seconds * 1000.0, 3)
+                        for name, seconds in stage_s.items()
+                    }
                 if compile_events:
                     predict_attrs["cold_compiles"] = compile_events
-                _tracing.record_span(
-                    "predict", trace.trace_id, parent_id=batch_span_id,
-                    start_s=t0, duration_s=t1 - t0,
-                    attrs=predict_attrs,
-                )
-                _tracing.record_span(
-                    "batch", trace.trace_id, span_id=batch_span_id,
-                    parent_id=trace.span_id, start_s=enqueued,
-                    duration_s=t1 - enqueued,
-                )
-            # futures resolve strictly AFTER the batch/predict spans are
-            # recorded: a client that got its response may immediately
-            # read /debug/traces.json and must find the whole chain
-            for f, exc, result in outcomes:
-                if exc is not None:
-                    f.set_exception(exc)
-                else:
-                    f.set_result(result)
+            for _, _, _, times in items:
+                times.started, times.served = started, served
+                times.attrs = predict_attrs
+            # the futures' callbacks (QueryAPI._finish_query: response
+            # build, feedback, bookkeeping, the request's spans) run
+            # here, one after another, before this thread can take the
+            # next batch
+            with _tracing.annotation("finish"):
+                for f, exc, result in outcomes:
+                    if exc is not None:
+                        f.set_exception(exc)
+                    else:
+                        f.set_result(result)
 
     def _serve_isolating(
         self, dep: DeployedEngine, items, outcomes: List[tuple]
@@ -756,6 +881,20 @@ class QueryAPI:
             labels=("version",),
             buckets=_metrics.LATENCY_BUCKETS_S,
         )
+        # the last of a request's four stages (_StageTimes.stages; the
+        # executor observes the three it times itself). Their sum plus
+        # the parse before the enqueue is the request's
+        # pio_serving_latency_seconds sample.
+        self._m_finish_fam = reg.histogram(
+            "pio_serving_finish_seconds",
+            "serve_batch returned until this request's response was "
+            "built (it waits for its batchmates' callbacks on the serve "
+            "thread), by model version",
+            labels=("version",),
+            buckets=_metrics.LATENCY_BUCKETS_S,
+        )
+        # its child by version: this observe runs on the serve thread
+        self._m_finish_children: Dict[str, Any] = {}
         self._m_requests_fam = reg.counter(
             "pio_serving_requests_total",
             "Completed /queries.json requests, by model version",
@@ -1148,6 +1287,7 @@ class QueryAPI:
             try:
                 _ledger.get_ledger().reconcile()
                 _cc.persistent_cache_stats()
+                _health.record_memory_gauges()
             except Exception:
                 logger.debug(
                     "device-gauge refresh failed", exc_info=True
@@ -1415,8 +1555,9 @@ class QueryAPI:
             logger.error("query %r is invalid: %s", body, e)
             return 400, {"message": str(e)}, "application/json"
 
+        times = _StageTimes(tctx)
         prediction_fut = self._executor.submit_nowait(
-            deployed, query, trace=tctx
+            deployed, query, times
         )
         out: "concurrent.futures.Future" = concurrent.futures.Future()
 
@@ -1424,7 +1565,7 @@ class QueryAPI:
             try:
                 result = self._finish_query(
                     deployed, query, query_json, f.result(), query_time,
-                    serving_start, tctx, inbound_parent,
+                    serving_start, times, tctx, inbound_parent,
                     experiment=experiment,
                 )
             except concurrent.futures.CancelledError:
@@ -1434,6 +1575,8 @@ class QueryAPI:
                     "internal error handling POST /queries.json"
                 )
                 result = (500, {"message": str(e)}, "application/json")
+                # a failed request still shows where its time went
+                times.record_spans(time.perf_counter())
             try:
                 out.set_result(result)
             except concurrent.futures.InvalidStateError:
@@ -1452,7 +1595,8 @@ class QueryAPI:
 
     def _finish_query(
         self, deployed, query, query_json, prediction, query_time,
-        serving_start, tctx=None, inbound_parent=None, experiment=None,
+        serving_start, times: _StageTimes, tctx=None, inbound_parent=None,
+        experiment=None,
     ) -> Tuple[int, Any, str]:
         prediction_json = deployed.algorithms[0].result_to_json(prediction)
         # the capture baseline is the RAW model output (pre-stamp,
@@ -1492,12 +1636,19 @@ class QueryAPI:
             deployed.engine_instance, query_json, prediction_json
         )
 
-        elapsed = time.perf_counter() - serving_start
+        now = time.perf_counter()
+        elapsed = now - serving_start
         # registry bookkeeping: per-child locks only, no shared hot-path
         # lock. The children are the SERVING deployed's version — during
         # a /reload swap, in-flight queries still record under the old
         # version while new ones record under the new.
         self._m_latency_fam.labels(version=version).observe(elapsed)
+        finish = self._m_finish_children.get(version)
+        if finish is None:
+            finish = self._m_finish_children[version] = (
+                self._m_finish_fam.labels(version=version)
+            )
+        finish.observe(now - times.served)
         self._m_requests_fam.labels(version=version).inc()
         self._m_last_fam.labels(version=version).set(elapsed)
         if experiment is not None:
@@ -1516,6 +1667,7 @@ class QueryAPI:
                 variant=version if experiment is not None else None,
             )
         if tctx is not None:
+            times.record_spans(now)
             _tracing.record_span(
                 "http:/queries.json", tctx.trace_id, span_id=tctx.span_id,
                 parent_id=inbound_parent, duration_s=elapsed,
@@ -1742,11 +1894,15 @@ class EngineServer:
         fn = (
             handle_nowait if self.config.transport == "async" else handle
         )
+        # the transport times the query route alone: a /metrics scrape
+        # or a 2 s /debug/profile call must not sit in that mean
         self._http = make_http_server(
             fn, self.config.ip, self.config.port, "Engine Server",
             reuse_port=self.config.reuse_port,
             transport=self.config.transport,
+            timed_routes=(("POST", "/queries.json"),),
         )
+        _health.install_gc_pause_hook()
 
     @property
     def port(self) -> int:

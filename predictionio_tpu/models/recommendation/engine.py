@@ -37,6 +37,7 @@ from predictionio_tpu.ops.als import (
     validate_solver,
 )
 from predictionio_tpu.ops.retrieval import ItemRetriever
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -398,28 +399,32 @@ class ALSModel:
         return result
 
     def recommend_many(self, queries) -> List[Tuple[int, PredictedResult]]:
-        """Vectorized top-N for indexed queries (the serving batch path)."""
-        known = [
-            (qx, self.user_index[q.user], q.num)
-            for qx, q in queries
-            if q.user in self.user_index
-        ]
-        unknown = [
-            (qx, PredictedResult())
-            for qx, q in queries
-            if q.user not in self.user_index
-        ]
-        if not known:
-            return unknown
-        max_num = max(n for _, _, n in known)
-        # pad the top-k width to a power of two (min 16) so varying query
-        # `num`s share O(log) compiled executables instead of one each —
-        # the shared ladder rule, which also records the ladder's padding
-        # waste in pio_padding_waste_ratio{site="retrieval_topk"}
-        from predictionio_tpu.ops.retrieval import pow2_topk_width
+        """Vectorized top-N for indexed queries (the serving batch path).
+        Its host phases are ``utils.tracing.stage``s: the float32 path's
+        ``dispatch`` and ``device_wait`` are ``ServingFactors``' own."""
+        with _tracing.stage(_tracing.HOST_PREP):
+            known = [
+                (qx, self.user_index[q.user], q.num)
+                for qx, q in queries
+                if q.user in self.user_index
+            ]
+            unknown = [
+                (qx, PredictedResult())
+                for qx, q in queries
+                if q.user not in self.user_index
+            ]
+            if not known:
+                return unknown
+            max_num = max(n for _, _, n in known)
+            # pad the top-k width to a power of two (min 16) so varying
+            # query `num`s share O(log) compiled executables instead of
+            # one each — the shared ladder rule, which also records the
+            # ladder's padding waste in
+            # pio_padding_waste_ratio{site="retrieval_topk"}
+            from predictionio_tpu.ops.retrieval import pow2_topk_width
 
-        max_num = pow2_topk_width(max_num, len(self.item_index))
-        users = [u for _, u, _ in known]
+            max_num = pow2_topk_width(max_num, len(self.item_index))
+            users = [u for _, u, _ in known]
         if self._retriever is not None:
             # quantized residency path: the retriever holds the catalog
             # as int8/bf16 rows and rescores its shortlist exactly
@@ -429,18 +434,23 @@ class ALSModel:
             )
         else:
             scores, idx = self.serving.topn_by_user(users, max_num)
-        # the inverse index is catalog-sized — build it once, not per request
-        if self._inv_item is None:
-            self._inv_item = self.item_index.inverse()
-        inv_item = self._inv_item
-        out = list(unknown)
-        for row, (qx, _, num) in enumerate(known):
-            item_scores = tuple(
-                ItemScore(item=inv_item[int(idx[row, j])], score=float(scores[row, j]))
-                for j in range(min(num, max_num))
-            )
-            out.append((qx, PredictedResult(item_scores=item_scores)))
-        return out
+        with _tracing.stage(_tracing.BUILD):
+            # the inverse index is catalog-sized — build it once, not
+            # per request
+            if self._inv_item is None:
+                self._inv_item = self.item_index.inverse()
+            inv_item = self._inv_item
+            out = list(unknown)
+            for row, (qx, _, num) in enumerate(known):
+                item_scores = tuple(
+                    ItemScore(
+                        item=inv_item[int(idx[row, j])],
+                        score=float(scores[row, j]),
+                    )
+                    for j in range(min(num, max_num))
+                )
+                out.append((qx, PredictedResult(item_scores=item_scores)))
+            return out
 
 
 class ALSAlgorithm(BaseAlgorithm):

@@ -54,6 +54,7 @@ from predictionio_tpu.parallel.mesh import pad_to_multiple
 from predictionio_tpu.utils import compilation_cache as _cc
 from predictionio_tpu.utils import device_ledger as _dl
 from predictionio_tpu.utils import metrics as _metrics
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -2459,9 +2460,13 @@ class ServingFactors:
     def topn_by_rows(self, user_rows: np.ndarray, n: int):
         """Top-N for explicit query factor rows [B, k]."""
         b = len(user_rows)
-        return unpack_topn(
-            np.asarray(self.topn_packed_device(user_rows, n))[:b], n
-        )
+        packed_dev = self.topn_packed_device(user_rows, n)
+        # the blocking fetch: queueing, execution and the copy back, as
+        # the host sees them
+        with _tracing.stage(_tracing.DEVICE_WAIT):
+            packed = np.asarray(packed_dev)
+        with _tracing.stage(_tracing.BUILD):
+            return unpack_topn(packed[:b], n)
 
     def topn_packed_device(self, user_rows: np.ndarray, n: int) -> jax.Array:
         """Device-resident top-N: upload query rows, run the matmul+top_k,
@@ -2476,27 +2481,31 @@ class ServingFactors:
         """
         from predictionio_tpu.ops.similarity import pad_rows_pow2
 
-        q = pad_rows_pow2(user_rows, 8)
+        with _tracing.stage(_tracing.HOST_PREP):
+            q = pad_rows_pow2(user_rows, 8)
         # executable-cache accounting for the serving top-k ladder: the
         # jit cache is keyed by (padded batch, catalog shape, n); a new
         # key is a compile — cold if it lands inside a serving batch
         exec_key = (
             q.shape, self._if_dev.shape, n, self.mesh is None,
         )
-        if self.mesh is None:
-            q_dev = jax.device_put(q)
-            with _cc.track_compile("serving-topk", _TOPK_SEEN, exec_key):
-                return _topn_packed(q_dev, self._if_dev, n)
-        # shard_batch further pads so the batch divides the mesh axis
-        # (a no-op for power-of-two axes), then places row-sharded
-        from predictionio_tpu.parallel.mesh import shard_batch
+        # dispatch: the query rows' upload and the program's call
+        # returning (asynchronous: the device may still be running)
+        with _tracing.stage(_tracing.DISPATCH):
+            if self.mesh is None:
+                q_dev = jax.device_put(q)
+                with _cc.track_compile("serving-topk", _TOPK_SEEN, exec_key):
+                    return _topn_packed(q_dev, self._if_dev, n)
+            # shard_batch further pads so the batch divides the mesh axis
+            # (a no-op for power-of-two axes), then places row-sharded
+            from predictionio_tpu.parallel.mesh import shard_batch
 
-        q_dev, _ = shard_batch(self.mesh, q, self._axis)
-        with _cc.track_compile("serving-topk", _TOPK_SEEN, exec_key):
-            return _topn_packed_sharded(
-                q_dev, self._if_dev, n,
-                NamedSharding(self.mesh, P(self._axis)),
-            )
+            q_dev, _ = shard_batch(self.mesh, q, self._axis)
+            with _cc.track_compile("serving-topk", _TOPK_SEEN, exec_key):
+                return _topn_packed_sharded(
+                    q_dev, self._if_dev, n,
+                    NamedSharding(self.mesh, P(self._axis)),
+                )
 
     def warm(self, n: int = 16, max_batch: int = 128) -> None:
         """Compile every padded-batch-size executable the serving path can
@@ -2550,7 +2559,8 @@ class ServingFactors:
     def topn_by_user(self, user_ids: Sequence[int], n: int):
         """Top-N for known user indices (gathers rows host-side; the row
         count is tiny relative to the item matmul)."""
-        rows = self.user_factors[np.asarray(user_ids, np.int64)]
+        with _tracing.stage(_tracing.HOST_PREP):
+            rows = self.user_factors[np.asarray(user_ids, np.int64)]
         return self.topn_by_rows(rows, n)
 
 

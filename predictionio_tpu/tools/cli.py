@@ -1036,6 +1036,10 @@ def cmd_profile(args) -> int:
 
     collector = getattr(args, "collector", None)
     timeout = float(args.seconds) + 60.0
+    if collector and args.python:
+        print("profile: --python is not relayed by a collector; capture "
+              "from the target directly", file=sys.stderr)
+        return 1
     try:
         if collector:
             body = {"target": args.url, "seconds": args.seconds}
@@ -1049,6 +1053,8 @@ def cmd_profile(args) -> int:
             )
         else:
             params = {"seconds": str(args.seconds)}
+            if args.python:
+                params["python"] = "1"
             if args.access_key:
                 params["accessKey"] = args.access_key
             if args.secret:
@@ -2006,6 +2012,11 @@ def build_parser() -> argparse.ArgumentParser:
     pf.add_argument(
         "--out", default="profile.zip",
         help="where to write the zipped trace archive",
+    )
+    pf.add_argument(
+        "--python", action="store_true",
+        help="turn the Python tracer on (frames of every call; it "
+        "stalls the traced server for the length of the capture)",
     )
     pf.add_argument(
         "--access-key", default="",

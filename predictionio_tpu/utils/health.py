@@ -28,12 +28,14 @@ store + a counter inc), far off any hot path's noise floor.
 from __future__ import annotations
 
 import contextlib
+import gc
 import os
 import threading
 import time
 from typing import Callable, Dict, Iterator, List, Optional, Sequence, Tuple
 
 from predictionio_tpu.utils import metrics as _metrics
+from predictionio_tpu.utils import tracing as _tracing
 
 __all__ = [
     "Heartbeat",
@@ -235,8 +237,11 @@ def record_memory_gauges() -> dict:
     device's ``memory_stats()`` (backends without the API — the CPU
     client — report nothing) and ``pio_host_rss_bytes`` from
     /proc/self/status (RSS fallback; absent off-Linux). Called once per
-    training round — cheap, but not a hot-path instrument. Returns what
-    it recorded (the round report includes it)."""
+    training round and at every engine-server ``/metrics`` scrape —
+    cheap, but not a hot-path instrument. Returns what it recorded (the
+    round report includes it). ``peak_bytes_in_use`` leaves a running
+    program's temporaries out; the ``*_reserved`` stats, where the
+    backend gives them, are what the allocator took from the device."""
     reg = _metrics.get_registry()
     out: dict = {}
     try:
@@ -255,7 +260,10 @@ def record_memory_gauges() -> dict:
                 ms = None
             if not ms:
                 continue
-            for stat in ("bytes_in_use", "peak_bytes_in_use", "bytes_limit"):
+            for stat in (
+                "bytes_in_use", "peak_bytes_in_use", "bytes_limit",
+                "bytes_reserved", "peak_bytes_reserved",
+            ):
                 if stat in ms:
                     g.labels(device=str(d.id), stat=stat).set(float(ms[stat]))
                     out[f"device{d.id}.{stat}"] = int(ms[stat])
@@ -268,6 +276,82 @@ def record_memory_gauges() -> dict:
         ).set(float(rss))
         out["host_rss_bytes"] = rss
     return out
+
+
+# --- interpreter pauses: the cyclic collector ---
+
+
+class _GcPauses:
+    """A ``gc.callbacks`` entry: every collection's start → stop goes
+    to ``pio_gc_pause_seconds{generation}``, a full (generation 2) one
+    to ``pio_gc_full_pause_seconds`` as well, and a ``pio:gc`` profiler
+    annotation is open across generations 1 and 2 (generation 0 runs
+    too often to annotate). A collection stops every thread of the
+    process — it runs under the interpreter lock — so its pause is a
+    pause of every request in flight, and of every request waiting to
+    be read. The full ones are the long ones, ≈ 50 ms each on a serving
+    cell's heap (PERF.md section 5), and they are one pause in a
+    hundred: a quantile over all generations cannot see them, hence
+    their own family.
+
+    The hook never waits for a lock. A collection starts on whichever
+    thread allocated last, and that may be a ``/metrics`` scrape inside
+    the very child's ``_render``, holding the lock ``observe`` would
+    wait for: the thread would wait for itself, and the interpreter
+    would never collect again. A sample whose child is busy waits in
+    ``_pending`` for the next collection instead (the interpreter runs
+    one collection at a time, so the hook's own state has one writer)."""
+
+    def __init__(self):
+        reg = _metrics.get_registry()
+        by_generation = reg.histogram(
+            "pio_gc_pause_seconds",
+            "Cyclic garbage collections of this process, start to stop, "
+            "by generation",
+            labels=("generation",),
+            buckets=_metrics.LATENCY_BUCKETS_S,
+        )
+        full = reg.histogram(
+            "pio_gc_full_pause_seconds",
+            "Full (generation 2) garbage collections of this process, "
+            "start to stop",
+            buckets=_metrics.LATENCY_BUCKETS_S,
+        )
+        # the children a collection of each generation is observed in
+        self._children = [
+            (by_generation.labels(generation="0"),),
+            (by_generation.labels(generation="1"),),
+            (by_generation.labels(generation="2"), full.labels()),
+        ]
+        self._pending: list = []  # (child, seconds) a busy child missed
+        self._t0 = 0.0
+        self._annotation = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        generation = info["generation"]
+        if phase == "start":
+            if generation:
+                self._annotation = _tracing.annotation("gc")
+                self._annotation.__enter__()
+            self._t0 = time.perf_counter()
+        elif self._t0:
+            seconds = time.perf_counter() - self._t0
+            self._t0 = 0.0
+            if self._annotation is not None:
+                self._annotation.__exit__(None, None, None)
+                self._annotation = None
+            missed = self._pending
+            missed.extend((c, seconds) for c in self._children[generation])
+            self._pending = [
+                (c, s) for c, s in missed if not c.try_observe(s)
+            ]
+
+
+def install_gc_pause_hook() -> None:
+    """Time this process's garbage collections from now on (once a
+    process, however many servers it starts)."""
+    if not any(isinstance(cb, _GcPauses) for cb in gc.callbacks):
+        gc.callbacks.append(_GcPauses())
 
 
 def _read_rss_bytes() -> Optional[int]:

@@ -384,12 +384,31 @@ class _HistogramValue:
         self._count = 0
         self._lock = threading.Lock()
 
-    def observe(self, v: float) -> None:
+    def observe(self, v: float, n: int = 1) -> None:
+        """Record ``v``, ``n`` times over: one lock take for the ``n``
+        requests of a micro-batch that share a duration."""
         i = bisect.bisect_left(self._bounds, v)
         with self._lock:
+            self._counts[i] += n
+            self._sum += v * n
+            self._count += n
+
+    def try_observe(self, v: float) -> bool:
+        """``observe`` that gives up (False) rather than wait for the
+        lock. For a ``gc.callbacks`` hook: a collection starts on
+        whichever thread allocated last, and that may be a scrape
+        inside this child's ``_render``/``snapshot``, holding the lock
+        the hook would wait for, for ever."""
+        i = bisect.bisect_left(self._bounds, v)
+        if not self._lock.acquire(blocking=False):
+            return False
+        try:
             self._counts[i] += 1
             self._sum += v
             self._count += 1
+        finally:
+            self._lock.release()
+        return True
 
     @property
     def sum(self) -> float:
@@ -463,8 +482,8 @@ class Histogram(_Family):
     def _make_child(self) -> _HistogramValue:
         return _HistogramValue(self.bounds)
 
-    def observe(self, v: float) -> None:
-        self._default().observe(v)
+    def observe(self, v: float, n: int = 1) -> None:
+        self._default().observe(v, n)
 
     def snapshot(self) -> HistogramSnapshot:
         return self._default().snapshot()

@@ -165,17 +165,33 @@ _SESSION_LOCK = threading.Lock()
 
 
 @contextlib.contextmanager
-def _session_body(profile_dir: str) -> Iterator[None]:
+def _session_body(profile_dir: str, python: bool = False) -> Iterator[None]:
     """The jax.profiler session itself — callers MUST hold
     :data:`_SESSION_LOCK` (``_profiler_session`` blocks for it; the
     HTTP capture acquires it non-blockingly so a busy profiler answers
-    409 instead of parking a route-pool worker)."""
+    409 instead of parking a route-pool worker).
+
+    The Python tracer is OFF unless ``python`` asks for it: hooking
+    every Python call stalls the process it traces for seconds (a
+    traced server stops answering, a traced train gains ≈ 6 s). The
+    host tracer stays on, so the program's own ``pio:`` annotations
+    (utils/tracing.annotation, made only while a session of this module
+    runs) and the runtime's host events are kept, on the device planes'
+    clock."""
     import jax
+
+    from predictionio_tpu.utils import tracing as _tracing
 
     os.makedirs(profile_dir, exist_ok=True)
     logger.info("writing jax profiler trace to %s", profile_dir)
-    with jax.profiler.trace(profile_dir):
-        yield
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 1 if python else 0
+    with jax.profiler.trace(profile_dir, profiler_options=options):
+        _tracing.set_capturing(True)
+        try:
+            yield
+        finally:
+            _tracing.set_capturing(False)
 
 
 @contextlib.contextmanager
@@ -256,11 +272,15 @@ class ProfileCapture:
         with self._lock:
             return self._last
 
-    def capture(self, seconds: float) -> "tuple[int, dict]":
+    def capture(
+        self, seconds: float, python: bool = False
+    ) -> "tuple[int, dict]":
         """Run one bounded capture; returns ``(http_status, payload)``.
         409 while another capture (or a --profile-dir training session)
         holds the profiler; the payload carries the zipped trace tree
-        base64-encoded plus its file listing."""
+        base64-encoded plus its file listing. ``python`` turns the
+        Python tracer on (frames of every call, at the price of
+        stalling the server for the capture)."""
         seconds = max(0.1, min(float(seconds), self.MAX_SECONDS))
         with self._lock:
             if self._busy:
@@ -282,7 +302,7 @@ class ProfileCapture:
                 self.spool_dir, f"capture-{int(started * 1000)}"
             )
             try:
-                with _session_body(cap_dir):
+                with _session_body(cap_dir, python):
                     time.sleep(seconds)
                 payload = self._archive(cap_dir, started, seconds)
             except Exception as e:
@@ -356,8 +376,8 @@ def profile_route(
     """The shared ``/debug/profile`` request core (all three servers
     route here after their own auth gate, like http.traces_payload):
     ``POST ?seconds=N`` runs one bounded capture and returns the
-    archive; ``GET`` returns capture status (and the last archive with
-    ``?archive=1``)."""
+    archive (``&python=1`` with the Python tracer on); ``GET`` returns
+    capture status (and the last archive with ``?archive=1``)."""
     if not authorized:
         return 401, {"message": "invalid or missing credentials"}
     cap = get_capture()
@@ -367,7 +387,9 @@ def profile_route(
             seconds = float(raw)
         except (TypeError, ValueError):
             return 400, {"message": f"invalid seconds {raw!r}"}
-        return cap.capture(seconds)
+        return cap.capture(
+            seconds, python=(query or {}).get("python") == "1"
+        )
     if method == "GET":
         if (query or {}).get("archive"):
             last = cap.last()

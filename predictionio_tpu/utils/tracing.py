@@ -9,7 +9,7 @@ via a ``contextvars`` context through the group-commit committer and
 the storage-gateway RPC client — so one request's path is a chain of
 spans. Training runs mint their own trace per ``PhaseTimer``:
 
-    serving:  http → batch → predict
+    serving:  http → batch → {queue_wait, slot_wait, predict, finish}
     ingest:   http → insert → group-commit-flush
     remote:   http → rpc:<dao>.<method> (gateway process) → flush
 
@@ -20,6 +20,13 @@ distributed-tracing stack: no sampling config, no exporters, no clock
 sync — just enough to answer "where did this request's time go" across
 the subsystems this repo actually has. For device-side timelines, wrap
 the training call in ``utils.profiling.trace`` (jax.profiler).
+
+:func:`stage` and :func:`annotation` put the program's own host phases
+on the profiler's clock: while a capture of ``utils/profiling`` runs, a
+``jax.profiler.TraceAnnotation`` named ``pio:<name>`` lands on the
+capture's host planes, which share the device planes' clock, so a
+device-idle gap can be put down to the host phase that was open during
+it (``benchmarks/host_gaps.py``).
 
 Like utils/metrics.py, this module is a sanctioned home for
 module-level observability state (tests/test_lint.py polices the rest
@@ -45,6 +52,11 @@ __all__ = [
     "from_headers",
     "current",
     "use",
+    "set_capturing",
+    "annotation",
+    "stage",
+    "stage_totals",
+    "BATCH_STAGES",
     "record_span",
     "span",
     "dump",
@@ -126,6 +138,96 @@ def use(ctx: Optional[TraceContext]) -> Iterator[None]:
         yield
     finally:
         _CURRENT.reset(token)
+
+
+_NO_ANNOTATION = contextlib.nullcontext()
+# True while a profiler session of utils/profiling runs (its
+# _session_body flips it). An annotation made at any other time would
+# land nowhere, and on the serving path even a no-op one at every stage
+# is Python that the serve thread runs under the interpreter lock
+# (PERF.md section 6, PR 26: it showed in the median)
+_CAPTURING = [False]
+
+
+def set_capturing(on: bool) -> None:
+    _CAPTURING[0] = on
+
+
+def annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` named ``pio:<name>`` for the
+    block while a capture of ``utils/profiling`` runs: a host event on
+    the capture's timeline. At any other time nothing (and no import of
+    JAX)."""
+    if not _CAPTURING[0]:
+        return _NO_ANNOTATION
+    import jax
+
+    return jax.profiler.TraceAnnotation("pio:" + name)
+
+
+# the host phases of one serving batch that stage() names: the engine
+# server's executor has one ``pio_serving_batch_<stage>_seconds`` family
+# for each, and engines bracket their serving path with these constants
+HOST_PREP = "host_prep"
+DISPATCH = "dispatch"
+DEVICE_WAIT = "device_wait"
+BUILD = "build"
+BATCH_STAGES = (HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD)
+
+# the per-batch accumulator of stage() durations, bound by the engine
+# server's executor for the length of one serve_batch
+_STAGES: "contextvars.ContextVar[Optional[Dict[str, float]]]" = (
+    contextvars.ContextVar("pio_stages", default=None)
+)
+
+
+class stage_totals:
+    """Bind a fresh ``{stage name: seconds}`` accumulator for the block
+    (``with stage_totals() as totals``); every :func:`stage` entered on
+    this thread inside it adds its duration there."""
+
+    __slots__ = ("_token",)
+
+    def __enter__(self) -> Dict[str, float]:
+        totals: Dict[str, float] = {}
+        self._token = _STAGES.set(totals)
+        return totals
+
+    def __exit__(self, *exc) -> None:
+        _STAGES.reset(self._token)
+
+
+class stage:
+    """One named host phase of a serving batch (one of
+    ``BATCH_STAGES``): a ``pio:<name>`` annotation while a profiler
+    capture runs, and, inside :func:`stage_totals`, its duration added
+    under its name (a stage entered twice in a batch adds up). Outside
+    a serving batch (warm-up, eval, a single ``recommend``) it is the
+    annotation alone."""
+
+    __slots__ = ("name", "_annotation", "_t0")
+
+    def __init__(self, name: str):
+        assert name in BATCH_STAGES, name
+        self.name = name
+
+    def __enter__(self) -> "stage":
+        # a batch enters nine of these on the serve thread: with no
+        # capture running not even the shared no-op is entered
+        self._annotation = None
+        if _CAPTURING[0]:
+            self._annotation = annotation(self.name)
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        elapsed = time.perf_counter() - self._t0
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        totals = _STAGES.get()
+        if totals is not None:
+            totals[self.name] = totals.get(self.name, 0.0) + elapsed
 
 
 _SPANS: "collections.deque" = collections.deque(maxlen=MAX_SPANS)
