@@ -3879,7 +3879,7 @@ def bench_promotion_under_load(device_name):
         v1 = train_once()
         server = EngineServer(
             engine,
-            ServerConfig(port=0, batch_window_ms=1.0, capture_sample=1),
+            ServerConfig(port=0, capture_sample=1),
             storage=storage,
         ).start()
         port = server.port
@@ -4212,7 +4212,7 @@ def bench_experiment(device_name):
         # trained LAST so a fresh server deploys it as the live control
         v_deg = train_once(make_params(rank=2, num_iterations=1))
         server = EngineServer(
-            engine, ServerConfig(port=0, batch_window_ms=1.0),
+            engine, ServerConfig(port=0),
             storage=storage,
         ).start()
         assert server.api.deployed.engine_instance.id == v_deg
@@ -4868,7 +4868,7 @@ def bench_device_obs(device_name):
     server = EngineServer(
         recommendation_engine(),
         ServerConfig(
-            port=0, batch_window_ms=1.0, pipeline_depth=2,
+            port=0, pipeline_depth=2,
             access_key="bench-secret",
         ),
         storage=storage,

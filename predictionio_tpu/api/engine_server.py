@@ -21,11 +21,14 @@ requestCount / avg / last serving seconds (:586-593).
 
 TPU-first divergence (deliberate): where the reference predicts per
 request, sequentially per algorithm (:497-500, "TODO: Parallelize"),
-queries here flow through a **micro-batching executor** — concurrent
-requests are coalesced for up to ``batch_window_ms`` and served as ONE
+queries here flow through a **micro-batching executor** — a request
+that finds a serve slot free is dispatched at once, and the requests
+that arrive while the slots are taken are served together as ONE
 batched device predict (`BaseAlgorithm.batch_predict`, e.g. a single
-[B, k] x [k, n_items] MXU matmul + top_k for the recommendation engine),
-so throughput scales with batch size instead of request count.
+[B, k] x [k, n_items] MXU matmul + top_k for the recommendation engine)
+as soon as a slot frees, so the batch grows with the load by itself and
+throughput scales with batch size instead of request count. No timer
+and no window: an idle server adds no wait.
 """
 
 from __future__ import annotations
@@ -105,8 +108,10 @@ class ServerConfig:
     event_server_port: int = 7070
     access_key: Optional[str] = None
     batch: str = ""
-    # micro-batching knobs (TPU addition)
-    batch_window_ms: float = 2.0
+    # micro-batching (TPU addition): the hard cap on one served batch.
+    # When a batch closes is not configured: it closes the moment a
+    # serve slot (pipeline_depth below) is free, over whatever queued
+    # while the slots were taken (_BatchingExecutor).
     max_batch: int = 128
     # Daily self upgrade check (reference CreateServer.scala:253-260 runs
     # UpgradeCheckRunner every 1 day): best-effort, on a background
@@ -438,10 +443,12 @@ class _StageTimes:
     """One request's stage boundaries, all on ``time.perf_counter``: the
     queue entry carries it for every request, and the executor fills it
     in where the work happens. ``enqueued`` → ``closed`` is the queue
-    wait (the collector closed the batch that holds the request),
-    → ``started`` the slot wait (the in-flight semaphore and the pool
-    hand-off), → ``served`` the batch's predict, charged to each request
-    in it; whoever finishes the request supplies the end of ``finish``.
+    wait (the collector closed the batch that holds the request, which
+    it does once a serve slot is free: the wait for the in-flight
+    semaphore is in here), → ``started`` the slot wait (the pool
+    hand-off alone: a serve thread still finishing the batch before),
+    → ``served`` the batch's predict, charged to each request in it;
+    whoever finishes the request supplies the end of ``finish``.
     ``trace`` is the request's trace context when it sent
     ``X-PIO-Trace-Id``; ``attrs`` what the predict span says of the
     batch."""
@@ -488,10 +495,14 @@ class _StageTimes:
 class _BatchingExecutor:
     """Coalesces concurrent requests into device-sized batches.
 
-    Requests enqueue (query, future); one collector thread drains the
-    queue — waiting up to window_ms after the first arrival — and hands
-    each batch to a serve pool holding up to ``pipeline_depth`` batches
-    in flight. ``submit_nowait`` returns the
+    Requests enqueue (query, future); one collector thread waits for a
+    request, then for one of the ``pipeline_depth`` in-flight slots,
+    then takes whatever else is queued (up to ``max_batch``) and hands
+    that batch to the serve pool. There is no timer: on an idle server
+    a request is dispatched alone and at once, and on a busy one the
+    batch is exactly what arrived while the slots were held, so it
+    grows with the load and with the length of ``serve_batch`` by
+    itself. ``submit_nowait`` returns the
     ``concurrent.futures.Future`` directly: the event-loop frontend
     awaits it, so an in-flight query is a queue entry, not a parked OS
     thread, and the collector can actually accumulate ``max_batch``-
@@ -503,14 +514,14 @@ class _BatchingExecutor:
     predict-time state. Depth 2 (opt-in, see ServerConfig.pipeline_depth)
     double-buffers: while batch k's result fetch is crossing
     host<->device, batch k+1 already dispatched and batch k+2
-    accumulates behind the semaphore — the device never idles waiting
-    on a fetch.
+    accumulates in the queue until a slot frees — the device never
+    idles waiting on a fetch. Never more than ``pipeline_depth``
+    ``serve_batch`` calls run at once.
     """
 
     _STOP = object()  # collector-thread shutdown sentinel
 
-    def __init__(self, window_ms: float, max_batch: int, pipeline_depth: int = 1):
-        self.window_ms = window_ms
+    def __init__(self, max_batch: int, pipeline_depth: int = 1):
         self.max_batch = max_batch
         self.pipeline_depth = max(1, pipeline_depth)
         self._queue: "queue.Queue" = queue.Queue()
@@ -545,7 +556,9 @@ class _BatchingExecutor:
         # predict once a BATCH for its n requests (they share both
         # durations), the batch stages once a batch; QueryAPI adds
         # ``finish``. The four request stages tile enqueue → response
-        # built (_StageTimes.stages).
+        # built (_StageTimes.stages). ``immediate`` is no stage: it is
+        # the counter of batches that found a slot free, kept here so
+        # that its child too is resolved once a version.
         self._m_stages = {
             name: _metrics.get_registry().histogram(
                 f"pio_serving_{name}_seconds",
@@ -556,11 +569,11 @@ class _BatchingExecutor:
             for name, help_ in (
                 ("queue_wait",
                  "Enqueue until the collector closed the micro-batch "
-                 "that holds the request"),
+                 "that holds the request, which it does when a serve "
+                 "slot is free"),
                 ("slot_wait",
                  "Batch closed until a serve thread started it (the "
-                 "in-flight semaphore and the pool hand-off), charged "
-                 "to each request in it"),
+                 "pool hand-off), charged to each request in it"),
                 ("predict",
                  "The micro-batch's serve_batch call, charged to each "
                  "request in it"),
@@ -570,6 +583,14 @@ class _BatchingExecutor:
                   for stage in _tracing.BATCH_STAGES),
             )
         }
+        self._m_stages["immediate"] = _metrics.get_registry().counter(
+            "pio_serving_batch_immediate_total",
+            "Served micro-batches whose serve slot was free when their "
+            "first request was taken: the executor made them wait for "
+            "nothing. Over pio_serving_batch_fill's count, the share of "
+            "batches that started from an idle server, by model version",
+            labels=("version",),
+        )
         self._m_stage_children: Dict[str, Dict[str, Any]] = {}
         self._m_batch_bases = {
             key[0]: child.snapshot()
@@ -675,15 +696,19 @@ class _BatchingExecutor:
                 first = self._queue.get()
             if first is self._STOP:
                 return
+            # the slot before the batch closes: whatever arrives while
+            # pipeline_depth batches are in flight joins THIS batch. A
+            # slot that is free already means the executor imposes no
+            # wait at all on the request
+            with _tracing.annotation("slot_wait"):
+                immediate = self._inflight.acquire(blocking=False)
+                if not immediate:
+                    self._inflight.acquire()
             batch = [first]
-            deadline = time.monotonic() + self.window_ms / 1000.0
             with _tracing.annotation("collect"):
                 while len(batch) < self.max_batch:
-                    timeout = deadline - time.monotonic()
-                    if timeout <= 0:
-                        break
                     try:
-                        item = self._queue.get(timeout=timeout)
+                        item = self._queue.get_nowait()
                     except queue.Empty:
                         break
                     if item is self._STOP:
@@ -697,6 +722,7 @@ class _BatchingExecutor:
             groups: Dict[int, List[tuple]] = {}
             for item in batch:
                 groups.setdefault(id(item[0]), []).append(item)
+            slot_held = True
             for items in groups.values():
                 # a future the transport cancelled (client gone before
                 # its batch formed) is dropped here; marking the rest
@@ -707,6 +733,12 @@ class _BatchingExecutor:
                 ]
                 if not items:
                     continue
+                if not slot_held:
+                    # a batch that spans a reload: one slot a group
+                    with _tracing.annotation("slot_wait"):
+                        self._inflight.acquire()
+                    immediate = False
+                slot_held = False
                 version = _version_of(items[0][0])
                 self._m_batch_fill.labels(version=version).observe(
                     len(items)
@@ -714,15 +746,14 @@ class _BatchingExecutor:
                 # on this thread, not the serve thread: by the time a
                 # response is out its queue wait is on /metrics, and the
                 # serve thread's time is every queued request's
-                observe_wait = _version_children(
+                children = _version_children(
                     self._m_stage_children, self._m_stages, version
-                )["queue_wait"].observe
+                )
+                if immediate:
+                    children["immediate"].inc()
+                observe_wait = children["queue_wait"].observe
                 for it in items:
                     observe_wait(closed - it[3].enqueued)
-                # blocks while pipeline_depth batches are in flight — the
-                # next batch keeps accumulating in self._queue meanwhile
-                with _tracing.annotation("slot_wait"):
-                    self._inflight.acquire()
                 try:
                     self._serve_pool.submit(
                         self._serve_and_release, items[0][0], items
@@ -736,6 +767,9 @@ class _BatchingExecutor:
                         f.set_exception(
                             RuntimeError(f"server is shutting down: {e}")
                         )
+            if slot_held:
+                # every request of the batch was cancelled
+                self._inflight.release()
 
     def _serve_and_release(self, dep: DeployedEngine, items) -> None:
         started = time.perf_counter()
@@ -846,7 +880,6 @@ class QueryAPI:
         # hash of (salt, user_key), so workers need no shared state.
         self._experiment: Optional[_experiment.ActiveExperiment] = None
         self._executor = _BatchingExecutor(
-            self.config.batch_window_ms,
             self.config.max_batch,
             self.config.pipeline_depth,
         )

@@ -375,7 +375,6 @@ def cmd_deploy(args) -> int:
         event_server_ip=args.event_server_ip,
         event_server_port=args.event_server_port,
         access_key=args.accesskey,
-        batch_window_ms=args.batch_window_ms,
         max_batch=args.max_batch,
         pipeline_depth=args.pipeline_depth,
         transport=args.transport,
@@ -578,7 +577,6 @@ def _deploy_worker_fleet(args, workers: int) -> int:
             "--ip", args.ip, "--port", str(args.port),
             "--workers", "1", "--reuse-port",
             "--transport", args.transport,
-            "--batch-window-ms", str(args.batch_window_ms),
             "--max-batch", str(args.max_batch),
             "--pipeline-depth", str(args.pipeline_depth),
             "--event-server-ip", args.event_server_ip,
@@ -1794,10 +1792,6 @@ def build_parser() -> argparse.ArgumentParser:
     deploy.add_argument("--event-server-ip", default="localhost")
     deploy.add_argument("--event-server-port", type=int, default=7070)
     deploy.add_argument("--accesskey")
-    deploy.add_argument(
-        "--batch-window-ms", type=float, default=2.0,
-        help="micro-batching window for concurrent queries",
-    )
     deploy.add_argument(
         "--max-batch", type=int, default=128,
         help="max queries per device batch",

@@ -19,7 +19,11 @@ from predictionio_tpu.api.aio_http import (
 from predictionio_tpu.api.http import JsonHTTPServer
 
 from tests import fake_engine as fe
-from tests.test_engine_server import make_engine, train_instance
+from tests.test_engine_server import (
+    after_submits,
+    make_engine,
+    train_instance,
+)
 
 
 def _echo_handler(method, path, query, body, form=None):
@@ -291,7 +295,9 @@ class TestMicroBatchCoalescing:
         """The headline property: with in-flight queries held as queue
         entries (not parked threads), >=32 concurrent clients coalesce
         into multi-query device batches — batch_fill_mean must clear 1
-        by a wide margin."""
+        by a wide margin. What coalesces them is a serve slot that is
+        held: the first batch stays in serve_batch until half the
+        clients' first requests have queued behind it."""
         from predictionio_tpu.api.engine_server import (
             EngineServer,
             ServerConfig,
@@ -301,12 +307,17 @@ class TestMicroBatchCoalescing:
         train_instance(mem_storage)
         server = EngineServer(
             make_engine(),
-            ServerConfig(
-                port=0, batch_window_ms=25.0, max_batch=64,
-                transport="async",
-            ),
+            ServerConfig(port=0, max_batch=64, transport="async"),
             storage=mem_storage,
         ).start()
+        queued_behind = after_submits(server.api._executor, 16)
+        serve_batch = server.api.deployed.serve_batch
+
+        def held(queries):
+            assert queued_behind.wait(10.0)
+            return serve_batch(queries)
+
+        server.api.deployed.serve_batch = held
         try:
             def client(worker):
                 conn = http.client.HTTPConnection("localhost", server.port)
@@ -386,7 +397,7 @@ class TestSubmitNowait:
             def serve_batch(self, queries):
                 return [q * 2 for q in queries]
 
-        ex = _BatchingExecutor(window_ms=1.0, max_batch=4)
+        ex = _BatchingExecutor(max_batch=4)
         try:
             futs = [ex.submit_nowait(Dep(), i) for i in range(3)]
             assert [f.result(timeout=5) for f in futs] == [0, 2, 4]
@@ -403,7 +414,7 @@ class TestSubmitNowait:
                 return list(queries)
 
         dep = PoisonDep()
-        ex = _BatchingExecutor(window_ms=20.0, max_batch=8)
+        ex = _BatchingExecutor(max_batch=8)
         try:
             futs = [ex.submit_nowait(dep, i) for i in range(4)]
             assert futs[0].result(timeout=5) == 0
@@ -425,19 +436,26 @@ class TestSubmitNowait:
                 return list(queries)
 
         gate = threading.Event()
+        entered = threading.Event()
 
         class GateDep(Dep):
             def serve_batch(self, queries):
+                entered.set()
                 gate.wait(5.0)
                 return super().serve_batch(queries)
 
         dep = GateDep()
-        ex = _BatchingExecutor(window_ms=50.0, max_batch=8)
+        ex = _BatchingExecutor(max_batch=8)
         try:
+            # "hold" goes out at once and keeps the one slot, so the
+            # batch of "a" and "b" has not formed when "b" is cancelled
+            hold = ex.submit_nowait(dep, "hold")
+            assert entered.wait(5.0)
             first = ex.submit_nowait(dep, "a")
             doomed = ex.submit_nowait(dep, "b")
             assert doomed.cancel()  # client went away pre-batch
             gate.set()
+            assert hold.result(timeout=5) == "hold"
             assert first.result(timeout=5) == "a"
             deadline = time.time() + 5
             while "a" not in served and time.time() < deadline:
@@ -453,7 +471,7 @@ class TestSubmitNowait:
             def serve_batch(self, queries):
                 return [q + 1 for q in queries]
 
-        ex = _BatchingExecutor(window_ms=1.0, max_batch=4)
+        ex = _BatchingExecutor(max_batch=4)
         try:
             assert ex.submit(Dep(), 41) == 42
         finally:

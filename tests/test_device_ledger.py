@@ -287,7 +287,7 @@ def ledger_world(mem_storage):
     v1 = train_instance(mem_storage)
     server = EngineServer(
         make_engine(),
-        ServerConfig(port=0, batch_window_ms=1.0),
+        ServerConfig(port=0),
         storage=mem_storage,
     ).start()
     try:
@@ -500,7 +500,7 @@ class TestColdCompileAttribution:
         )
         server = EngineServer(
             retriever_engine(),
-            ServerConfig(port=0, batch_window_ms=1.0),
+            ServerConfig(port=0),
             storage=mem_storage,
         ).start()
         try:
@@ -586,7 +586,7 @@ class TestProfileCapture:
         server = EngineServer(
             make_engine(),
             ServerConfig(
-                port=0, batch_window_ms=1.0, access_key="sekrit"
+                port=0, access_key="sekrit"
             ),
             storage=mem_storage,
         ).start()

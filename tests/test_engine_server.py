@@ -1,6 +1,7 @@
 """Engine (query) server tests: deploy path, serving hot path with
 micro-batching, feedback loop, reload, plugins, bookkeeping."""
 
+import itertools
 import json
 import threading
 import time
@@ -106,12 +107,30 @@ class TestDeploy:
         assert results[0].qx == 3 and results[1].qx == 4
 
 
+def after_submits(executor, n) -> threading.Event:
+    """An event that is set once ``n`` requests have been handed to
+    ``executor``: what a test's gate-held serve_batch waits for, so that
+    coalescing follows from a held slot and not from anybody's pace."""
+    handed_over = threading.Event()
+    submit_nowait = executor.submit_nowait
+    count = itertools.count(1)
+
+    def submitting(*args, **kwargs):
+        fut = submit_nowait(*args, **kwargs)
+        if next(count) == n:
+            handed_over.set()
+        return fut
+
+    executor.submit_nowait = submitting
+    return handed_over
+
+
 @pytest.fixture()
 def query_api(mem_storage):
     fe.reset_counters()
     train_instance(mem_storage)
     dep = DeployedEngine.from_storage(make_engine(), mem_storage)
-    return QueryAPI(dep, ServerConfig(batch_window_ms=1.0))
+    return QueryAPI(dep, ServerConfig())
 
 
 class TestQueryAPI:
@@ -148,18 +167,24 @@ class TestQueryAPI:
         assert "Engine Server" in page
 
     def test_concurrent_queries_coalesce(self, query_api):
-        """Concurrent requests ride one micro-batch (thus share a single
-        serve_batch call) and all get correct per-query results."""
+        """Requests that arrive while the serve slot is held ride one
+        micro-batch (thus share a single serve_batch call) and all get
+        correct per-query results."""
         calls = []
         orig = query_api.deployed.serve_batch
+        entered = threading.Event()
+        all_submitted = after_submits(query_api._executor, 8)
 
         def counting(queries):
+            if not calls:
+                # the first request holds the slot (depth 1) until the
+                # other seven are in the executor's hands
+                entered.set()
+                assert all_submitted.wait(10.0)
             calls.append(len(queries))
             return orig(queries)
 
         query_api.deployed.serve_batch = counting
-        query_api.config.batch_window_ms = 50.0
-        query_api._executor.window_ms = 50.0
 
         results = {}
 
@@ -172,15 +197,366 @@ class TestQueryAPI:
         threads = [
             threading.Thread(target=do, args=(qx,)) for qx in range(8)
         ]
-        for t in threads:
+        threads[0].start()
+        assert entered.wait(10.0)
+        for t in threads[1:]:
             t.start()
         for t in threads:
             t.join()
         assert sorted(results) == list(range(8))
         for qx, body in results.items():
             assert body["qx"] == qx
-        assert max(calls) > 1  # at least one coalesced batch
-        assert sum(calls) == 8
+        # the first alone, at once; the seven that queued behind it as one
+        assert calls == [1, 7]
+
+
+class _GateDep:
+    """A deployed engine whose every serve_batch says that it entered and
+    then holds its serve slot until the test lets one call go."""
+
+    def __init__(self, let_go=0):
+        self.entered = threading.Semaphore(0)
+        self.go = threading.Semaphore(let_go)  # calls that need not wait
+        self.calls = []
+        self.running = 0
+        self.max_running = 0
+        self._lock = threading.Lock()
+
+    def serve_batch(self, queries):
+        with self._lock:
+            self.calls.append(list(queries))
+            self.running += 1
+            self.max_running = max(self.max_running, self.running)
+        self.entered.release()
+        try:
+            assert self.go.acquire(timeout=10.0)
+        finally:
+            with self._lock:
+                self.running -= 1
+        return list(queries)
+
+    def await_entry(self):
+        assert self.entered.acquire(timeout=10.0)
+
+
+class _WatchedSlots:
+    """The executor's in-flight semaphore, saying when the collector has
+    found no slot free and is about to wait for one: from then on the
+    batch it forms is not an immediate one, whatever the threads' pace."""
+
+    def __init__(self, ex):
+        self._sem = ex._inflight
+        self.collector_waits = threading.Event()
+        self.held = 0  # slots taken and not yet given back
+        self._given_back = threading.Semaphore(0)
+        self._lock = threading.Lock()
+        ex._inflight = self
+
+    def acquire(self, blocking=True):
+        if blocking:
+            self.collector_waits.set()
+        got = self._sem.acquire(blocking)
+        if got:
+            with self._lock:
+                self.held += 1
+        return got
+
+    def release(self):
+        with self._lock:
+            self.held -= 1
+        self._sem.release()
+        self._given_back.release()
+
+    def await_release(self):
+        assert self._given_back.acquire(timeout=10.0)
+
+
+def _immediate_batches() -> float:
+    """pio_serving_batch_immediate_total over its versions, as a
+    /metrics reader sums it."""
+    from predictionio_tpu.utils import metrics
+
+    return metrics.counter_sum(
+        metrics.parse_exposition(metrics.get_registry().render()),
+        "pio_serving_batch_immediate_total",
+    )
+
+
+@pytest.mark.parametrize("depth", [1, 2])
+class TestBatchClosesWhenASlotIsFree:
+    """The executor's policy: first request, then a slot, then whatever
+    is queued. No timer, so no test here sleeps or waits out a window:
+    a gate-held serve_batch decides what the queue holds when."""
+
+    def _held(self, depth, max_batch=8):
+        """An executor whose ``depth`` slots are all held by lone
+        requests h0, h1, ... that went out at once."""
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        dep = _GateDep()
+        ex = _BatchingExecutor(max_batch=max_batch, pipeline_depth=depth)
+        _WatchedSlots(ex)
+        holders = []
+        for n in range(depth):
+            holders.append(ex.submit_nowait(dep, f"h{n}"))
+            dep.await_entry()
+        return dep, ex, holders
+
+    def test_lone_request_on_an_idle_executor_goes_at_once(self, depth):
+        dep, ex, holders = self._held(depth)
+        try:
+            # each reached serve_batch with nothing else submitted and no
+            # timer to run out: _held waited for exactly that
+            assert dep.calls == [[f"h{n}"] for n in range(depth)]
+            for _ in holders:
+                dep.go.release()
+            assert [f.result(timeout=10) for f in holders] == [
+                f"h{n}" for n in range(depth)
+            ]
+        finally:
+            ex.close()
+
+    def test_requests_behind_a_held_slot_leave_as_one_batch(self, depth):
+        dep, ex, holders = self._held(depth)
+        try:
+            immediate = _immediate_batches()
+            futs = [ex.submit_nowait(dep, q) for q in "bcd"]
+            assert ex._inflight.collector_waits.wait(10.0)
+            dep.go.release()  # one holder returns: its slot frees
+            dep.await_entry()
+            assert dep.calls[depth:] == [["b", "c", "d"]]
+            # that batch waited for its slot: not an immediate one
+            assert _immediate_batches() == immediate
+            for _ in range(depth):
+                dep.go.release()
+            assert [f.result(timeout=10) for f in futs] == ["b", "c", "d"]
+            assert dep.max_running <= depth
+        finally:
+            ex.close()
+
+    def test_max_batch_caps_what_a_free_slot_takes(self, depth):
+        dep, ex, holders = self._held(depth, max_batch=4)
+        try:
+            futs = [ex.submit_nowait(dep, i) for i in range(10)]
+            for _ in range(3):
+                dep.go.release()
+                dep.await_entry()
+            assert dep.calls[depth:] == [
+                [0, 1, 2, 3], [4, 5, 6, 7], [8, 9],
+            ]
+            for _ in range(depth):
+                dep.go.release()
+            assert [f.result(timeout=10) for f in futs] == list(range(10))
+        finally:
+            ex.close()
+
+    def test_never_more_than_depth_calls_at_once(self, depth):
+        dep, ex, holders = self._held(depth, max_batch=1)
+        try:
+            # six batches of one, each let in only by a call that returns
+            futs = [ex.submit_nowait(dep, i) for i in range(6)]
+            for n in range(6):
+                assert dep.running == depth
+                dep.go.release()
+                dep.await_entry()
+            for _ in range(depth):
+                dep.go.release()
+            assert [f.result(timeout=10) for f in futs] == list(range(6))
+            assert dep.max_running == depth
+        finally:
+            ex.close()
+
+    def test_stress_each_request_served_once_within_the_caps(self, depth):
+        """More submitting threads than cores at a shortened switch
+        interval: every request is answered with its own result exactly
+        once, no batch passes max_batch, and never more than ``depth``
+        serve_batch calls run at once."""
+        import sys
+
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        dep = _GateDep(let_go=1600)
+        ex = _BatchingExecutor(max_batch=4, pipeline_depth=depth)
+        interval = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        answers = {}
+        try:
+            def client(w):
+                for j in range(50):
+                    q = w * 1000 + j
+                    answers[q] = ex.submit(dep, q)
+
+            threads = [
+                threading.Thread(target=client, args=(w,)) for w in range(32)
+            ]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=60)
+            assert not any(t.is_alive() for t in threads)
+        finally:
+            sys.setswitchinterval(interval)
+            ex.close()
+        assert len(answers) == 1600
+        assert all(v == q for q, v in answers.items())
+        assert sorted(sum(dep.calls, [])) == sorted(answers)
+        assert max(len(call) for call in dep.calls) <= 4
+        assert dep.max_running <= depth
+
+
+class TestSlotAccounting:
+    def test_counts_batches_that_found_a_slot_free(self):
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        dep = _GateDep()
+        ex = _BatchingExecutor(max_batch=8)
+        slots = _WatchedSlots(ex)
+        try:
+            before = _immediate_batches()
+            a = ex.submit_nowait(dep, "a")
+            dep.await_entry()
+            assert _immediate_batches() == before + 1
+            futs = [ex.submit_nowait(dep, q) for q in "bcd"]
+            assert slots.collector_waits.wait(10.0)
+            dep.go.release()
+            dep.await_entry()
+            dep.go.release()
+            assert [f.result(timeout=10) for f in [a] + futs] == list("abcd")
+            assert dep.calls == [["a"], ["b", "c", "d"]]
+            assert _immediate_batches() == before + 1
+            # the server is idle again: the next one counts
+            e = ex.submit_nowait(dep, "e")
+            dep.await_entry()
+            dep.go.release()
+            assert e.result(timeout=10) == "e"
+            assert _immediate_batches() == before + 2
+            assert ex.stats()["batches"] == 3
+        finally:
+            ex.close()
+
+    def test_a_batch_cancelled_whole_gives_its_slot_back(self):
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        dep = _GateDep()
+        ex = _BatchingExecutor(max_batch=8)
+        slots = _WatchedSlots(ex)
+        try:
+            hold = ex.submit_nowait(dep, "hold")
+            dep.await_entry()
+            gone = [ex.submit_nowait(dep, q) for q in "ab"]
+            assert slots.collector_waits.wait(10.0)
+            assert all(f.cancel() for f in gone)
+            dep.go.release()
+            assert hold.result(timeout=10) == "hold"
+            # hold's slot comes back, the collector takes it for "a" and
+            # "b", finds both gone and gives it back: were it kept, at
+            # depth 1 nothing would ever be served again
+            slots.await_release()
+            slots.await_release()
+            assert slots.held == 0
+            z = ex.submit_nowait(dep, "z")
+            dep.await_entry()
+            dep.go.release()
+            assert z.result(timeout=10) == "z"
+            assert dep.calls == [["hold"], ["z"]]
+        finally:
+            ex.close()
+
+    def test_a_batch_that_spans_a_reload_takes_one_slot_a_group(self):
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        old, new = _GateDep(), _GateDep()
+        ex = _BatchingExecutor(max_batch=8)
+        slots = _WatchedSlots(ex)
+        try:
+            before = _immediate_batches()
+            hold = ex.submit_nowait(old, "hold")
+            old.await_entry()
+            futs = [
+                ex.submit_nowait(dep, q)
+                for dep, q in ((old, "a"), (new, "b"), (old, "c"), (new, "d"))
+            ]
+            assert slots.collector_waits.wait(10.0)
+            old.go.release()  # hold returns; its slot serves the old group
+            old.await_entry()
+            assert old.calls == [["hold"], ["a", "c"]] and new.calls == []
+            old.go.release()  # and only that slot, freed again, the new one
+            new.await_entry()
+            new.go.release()
+            assert [f.result(timeout=10) for f in [hold] + futs] == [
+                "hold", "a", "b", "c", "d",
+            ]
+            assert new.calls == [["b", "d"]]
+            assert _immediate_batches() == before + 1  # hold's alone
+            assert ex.stats()["batches"] == 3
+            assert slots.held == 0  # three taken, three given back
+        finally:
+            ex.close()
+
+    def test_an_immediate_batch_that_spans_a_reload_counts_once(self):
+        import concurrent.futures
+
+        from predictionio_tpu.api.engine_server import (
+            _BatchingExecutor,
+            _StageTimes,
+        )
+
+        old, new = _GateDep(), _GateDep()
+        ex = _BatchingExecutor(max_batch=8)
+        slots = _WatchedSlots(ex)
+        try:
+            before = _immediate_batches()
+            # both queued before the collector thread runs, so that an
+            # idle executor's first drain finds both engines' requests
+            futs = []
+            for dep, q in ((old, "a"), (new, "b")):
+                futs.append(concurrent.futures.Future())
+                ex._queue.put((dep, q, futs[-1], _StageTimes()))
+            ex._worker = threading.Thread(target=ex._run, daemon=True)
+            ex._worker.start()
+            old.await_entry()
+            assert new.calls == []
+            old.go.release()
+            new.await_entry()
+            new.go.release()
+            assert [f.result(timeout=10) for f in futs] == ["a", "b"]
+            # the old engine's group went out on the free slot; the new
+            # one's waited for that slot to come back
+            assert _immediate_batches() == before + 1
+            assert slots.held == 0
+        finally:
+            ex.close()
+
+    def test_stop_during_a_drain_resolves_every_queued_future(self):
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        dep = _GateDep()
+        ex = _BatchingExecutor(max_batch=8)
+        a = ex.submit_nowait(dep, "a")
+        dep.await_entry()
+        b, c = ex.submit_nowait(dep, "b"), ex.submit_nowait(dep, "c")
+        stop_posted = threading.Event()
+        put = ex._queue.put
+
+        def watching(item):
+            put(item)
+            if item is ex._STOP:
+                stop_posted.set()
+
+        ex._queue.put = watching
+        closer = threading.Thread(target=ex.close)
+        closer.start()
+        assert stop_posted.wait(10.0)  # the queue: c, _STOP (b is taken)
+        for _ in range(2):
+            dep.go.release()
+        closer.join(timeout=10)
+        assert not closer.is_alive()
+        assert [f.result(timeout=10) for f in (a, b, c)] == ["a", "b", "c"]
+        # the drain stopped at the sentinel and left it for the loop
+        assert dep.calls == [["a"], ["b", "c"]]
+        assert not ex._worker.is_alive()
+        with pytest.raises(RuntimeError):
+            ex.submit_nowait(dep, "d")
 
 
 class TestBatchingPipeline:
@@ -210,7 +586,7 @@ class TestBatchingPipeline:
                 return list(queries)
 
         dep = SlowDep()
-        ex = _BatchingExecutor(window_ms=1.0, max_batch=2, pipeline_depth=2)
+        ex = _BatchingExecutor(max_batch=2, pipeline_depth=2)
         results = []
         res_lock = threading.Lock()
 
@@ -239,7 +615,7 @@ class TestBatchingPipeline:
                 return list(queries)
 
         dep = PoisonDep()
-        ex = _BatchingExecutor(window_ms=5.0, max_batch=8, pipeline_depth=2)
+        ex = _BatchingExecutor(max_batch=8, pipeline_depth=2)
         outcomes = {}
         lock = threading.Lock()
 
@@ -270,7 +646,7 @@ class TestBatchingPipeline:
                 return list(queries)
 
         dep = Dep()
-        ex = _BatchingExecutor(window_ms=1.0, max_batch=4, pipeline_depth=2)
+        ex = _BatchingExecutor(max_batch=4, pipeline_depth=2)
         assert ex.submit(dep, 7) == 7
         worker = ex._worker
         assert worker is not None and worker.is_alive()
@@ -297,7 +673,7 @@ class TestBatchingPipeline:
                 return list(queries)
 
         dep = WedgedDep()
-        ex = _BatchingExecutor(window_ms=1.0, max_batch=1, pipeline_depth=1)
+        ex = _BatchingExecutor(max_batch=1, pipeline_depth=1)
         t = threading.Thread(target=lambda: ex.submit(dep, 1), daemon=True)
         t.start()
         time.sleep(0.1)  # let the batch reach the wedged serve call

@@ -305,7 +305,7 @@ def exp_world(mem_storage):
     servers = []
 
     def make_server(**cfg):
-        defaults = dict(port=0, batch_window_ms=1.0)
+        defaults = dict(port=0)
         defaults.update(cfg)
         s = EngineServer(
             make_engine(), ServerConfig(**defaults), storage=mem_storage

@@ -151,7 +151,7 @@ def promo_world(mem_storage):
     v1 = train_instance(mem_storage)
     server = EngineServer(
         make_engine(),
-        ServerConfig(port=0, batch_window_ms=1.0),
+        ServerConfig(port=0),
         storage=mem_storage,
     ).start()
     try:

@@ -324,7 +324,7 @@ def query_api(mem_storage):
     fe.reset_counters()
     train_instance(mem_storage)
     dep = DeployedEngine.from_storage(make_engine(), mem_storage)
-    api = QueryAPI(dep, ServerConfig(batch_window_ms=1.0))
+    api = QueryAPI(dep, ServerConfig())
     yield api
     api.close()
 

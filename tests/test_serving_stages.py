@@ -71,7 +71,7 @@ def served(request, mem_storage):
     engine, body = ENGINES[request.param](mem_storage)
     server = EngineServer(
         engine,
-        ServerConfig(port=0, batch_window_ms=1.0, access_key="sekrit"),
+        ServerConfig(port=0, access_key="sekrit"),
         storage=mem_storage,
     ).start()
     try:
@@ -348,7 +348,7 @@ def test_capture_under_load_holds_the_annotations(
 
     engine, body = _reco(mem_storage)
     server = EngineServer(
-        engine, ServerConfig(port=0, batch_window_ms=1.0),
+        engine, ServerConfig(port=0),
         storage=mem_storage,
     ).start()
     stop = threading.Event()

@@ -551,11 +551,10 @@ class TestCLIServingAndEvalKnobs:
         vpath.write_text(json.dumps(variant))
         assert cli_main([
             "deploy", "-v", str(vpath), "--pipeline-depth", "1",
-            "--batch-window-ms", "5", "--max-batch", "64",
+            "--max-batch", "64",
         ]) == 0
         cfg = captured["config"]
         assert cfg.pipeline_depth == 1
-        assert cfg.batch_window_ms == 5.0
         assert cfg.max_batch == 64
 
 
