@@ -9,7 +9,9 @@ stalled serving. This module extracts that read behind a TTL cache with
 OUT-OF-BAND refresh:
 
 - ``get()`` returns the cached set and NEVER touches the store once
-  primed: past the TTL it kicks a single background refresh thread and
+  primed: past HALF the TTL it kicks a single background refresh thread
+  (so that a ``$set`` is honoured by every query sent later than the TTL
+  after its acknowledgement, as long as queries keep ticking) and
   keeps serving the cached value, so a store stall can no longer block
   a batch (only the very first call, typically at deploy, reads
   inline).
@@ -128,10 +130,16 @@ class ConstraintCache:
         inline read (deploy-time)."""
         with self._lock:
             value = self._value
+            # refresh at HALF the TTL: a set written just after a read
+            # is then served within ttl_s of its acknowledgement (half a
+            # TTL until the next refresh is kicked, the rest for that
+            # read and the listeners' re-upload), which is the guarantee
+            # the TTL names; a refresh kicked only AT the TTL would
+            # honour it a read too late
             stale = (
                 value is not None
                 and self.ttl_s > 0
-                and (time.monotonic() - self._loaded_at) > self.ttl_s
+                and (time.monotonic() - self._loaded_at) > self.ttl_s / 2
             )
             kick = stale and not self._refreshing
             if kick:

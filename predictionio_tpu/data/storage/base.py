@@ -22,7 +22,9 @@ import datetime as _dt
 import re
 import secrets
 import threading
-from typing import Dict, Iterable, Iterator, List, Optional, Sequence, Union
+from typing import (
+    Dict, Iterable, Iterator, List, Optional, Sequence, Tuple, Union,
+)
 
 
 class DAOCacheMixin:
@@ -167,6 +169,38 @@ class LEvents(abc.ABC):
         event-time order."""
 
     # --- derived operations ---
+
+    def find_by_entities(
+        self,
+        app_id: int,
+        channel_id: Optional[int] = None,
+        *,
+        entity_type: str,
+        entity_ids: Sequence[str],
+        event_names: Sequence[str],
+        target_entity_type: str,
+    ) -> Dict[str, List[Tuple[str, str, int]]]:
+        """The serving path's read of a whole micro-batch: for each of
+        ``entity_ids`` its ``(event, target entity id, event time ms)``
+        triples among ``event_names`` with a target of
+        ``target_entity_type``, newest first. One pass over the store a
+        call where the backend has one (sqlite); here one ``find`` an
+        entity. It reads what is committed when it is called, from
+        whichever process: nothing is cached between calls."""
+        out: Dict[str, List[Tuple[str, str, int]]] = {}
+        for entity_id in dict.fromkeys(entity_ids):
+            out[entity_id] = [
+                (e.event, e.target_entity_id,
+                 int(e.event_time.timestamp() * 1000))
+                for e in self.find(
+                    app_id=app_id, channel_id=channel_id,
+                    entity_type=entity_type, entity_id=entity_id,
+                    event_names=list(event_names),
+                    target_entity_type=target_entity_type, reversed=True,
+                )
+                if e.target_entity_id
+            ]
+        return out
 
     def aggregate_properties(
         self,

@@ -681,6 +681,17 @@ class StorageClient(base.DAOCacheMixin):
         with self.lock:
             self.conn.commit()
 
+def _pent_dtype():
+    """One row of the pages' entity index: the row's number in its
+    page, its target's dictionary code, its value, its time (20 bytes,
+    packed)."""
+    import numpy as np
+
+    return np.dtype(
+        [("idx", "<i4"), ("target", "<i4"), ("val", "<f4"), ("ms", "<i8")]
+    )
+
+
 _GEN_SCHEMA = (
     "CREATE TABLE IF NOT EXISTS pio_table_gen "
     "(tbl TEXT PRIMARY KEY, gen INTEGER NOT NULL)"
@@ -709,6 +720,15 @@ class SQLiteLEvents(base.LEvents):
         from collections import OrderedDict
 
         self._seg_cache: "OrderedDict[str, object]" = OrderedDict()
+        # table -> the dictionary's names by code, as far as this
+        # process has read them. Codes are given once and never change
+        # (AUTOINCREMENT, no update, no delete short of remove()), so
+        # what is cached stays true whoever writes; a code past the end
+        # reads the new tail
+        self._dict_cache: Dict[str, list] = {}
+        # what the reads by entity cost, for tests and the curious:
+        # pages whose blobs were decoded, rows of the entity index read
+        self.read_stats = {"pages_decoded": 0, "index_rows": 0}
         # test-only crash injection: called between segment-file write
         # and the manifest commit (compaction crash-consistency tests)
         self.compact_fault = None
@@ -837,6 +857,23 @@ class SQLiteLEvents(base.LEvents):
                 name TEXT UNIQUE NOT NULL
             )"""
         )
+        # the pages' entity index (build_entity_index): one row for each
+        # (entity code, page) pair that has events, holding that
+        # entity's rows of that page packed (row number, target code,
+        # value, time), so a read by entity costs the entity's events
+        # and decodes no page. _pent_pages lists the pages it covers.
+        self._c.execute(
+            f"""CREATE TABLE IF NOT EXISTS {t}_pent (
+                ecode INTEGER NOT NULL,
+                page INTEGER NOT NULL,
+                packed BLOB NOT NULL,
+                PRIMARY KEY (ecode, page)
+            ) WITHOUT ROWID"""
+        )
+        self._c.execute(
+            f"CREATE TABLE IF NOT EXISTS {t}_pent_pages "
+            f"(page INTEGER PRIMARY KEY)"
+        )
 
     def remove(self, app_id: int, channel_id: Optional[int] = None) -> bool:
         t = self._events_table(app_id, channel_id)
@@ -856,6 +893,9 @@ class SQLiteLEvents(base.LEvents):
             self._c.execute(f"DROP TABLE IF EXISTS {t}")
             self._c.execute(f"DROP TABLE IF EXISTS {t}_pages")
             self._c.execute(f"DROP TABLE IF EXISTS {t}_dict")
+            self._c.execute(f"DROP TABLE IF EXISTS {t}_pent")
+            self._c.execute(f"DROP TABLE IF EXISTS {t}_pent_pages")
+            self._dict_cache.pop(t, None)
             self._c.execute(f"DROP TABLE IF EXISTS {t}_segments")
             self._c.execute(f"DROP TABLE IF EXISTS {t}_compaction")
             # bump the table GENERATION: DROP resets the AUTOINCREMENT
@@ -1417,15 +1457,22 @@ class SQLiteLEvents(base.LEvents):
                 ((s,) for s in strs),
             )
             mapping: Dict[str, int] = {}
-            chunk = 900  # sqlite bound-parameter limit headroom
-            for s in range(0, len(strs), chunk):
-                part = strs[s : s + chunk]
-                rows = self._c.conn.execute(
-                    f"SELECT name, id FROM {t}_dict WHERE name IN "
-                    f"({','.join('?' * len(part))})",
-                    part,
-                ).fetchall()
-                mapping.update(rows)
+            if len(strs) > 50_000:
+                # a bulk import's worth of names: one scan of the
+                # dictionary, not a thousand statements of 900 names
+                mapping.update(self._c.conn.execute(
+                    f"SELECT name, id FROM {t}_dict"
+                ).fetchall())
+            else:
+                chunk = 900  # sqlite bound-parameter limit headroom
+                for s in range(0, len(strs), chunk):
+                    part = strs[s : s + chunk]
+                    rows = self._c.conn.execute(
+                        f"SELECT name, id FROM {t}_dict WHERE name IN "
+                        f"({','.join('?' * len(part))})",
+                        part,
+                    ).fetchall()
+                    mapping.update(rows)
             self._c.conn.commit()
         return np.array([mapping[s] for s in strs], np.int32)
 
@@ -1614,30 +1661,25 @@ class SQLiteLEvents(base.LEvents):
         """Decode page rows into Event objects (legacy find() view)."""
         import numpy as np
 
+        if target_entity_id is None:
+            return []
+        if entity_id is not None:
+            return self._page_events_by_entity(
+                t, start_time, until_time, entity_type, entity_id,
+                event_names, target_entity_type, target_entity_id,
+            )
         pages = self._page_rows(
             t, start_time, until_time, entity_type, event_names,
             target_entity_type,
         )
-        if not pages or target_entity_id is None:
+        if not pages:
             return []
 
-        def code_of(name: str):
-            row = self._c.execute(
-                f"SELECT id FROM {t}_dict WHERE name=?", (name,)
-            ).fetchone()
-            return row[0] if row else None
-
-        # entity filters compare int32 dict CODES, not strings: a
-        # serving-path find_by_entity over a 20M-row bulk import must
-        # stay vectorized (object-array string equality would burn the
-        # serving deadline)
-        e_code = g_code = None
-        if entity_id is not None:
-            e_code = code_of(entity_id)
-            if e_code is None:
-                return []
+        # the target filter compares int32 dict CODES, not strings (the
+        # entity filter went through the entity index above)
+        g_code = None
         if target_entity_id is not UNSET:
-            g_code = code_of(target_entity_id)
+            g_code = self._dict_code(t, target_entity_id)
             if g_code is None:
                 return []
         names = self._dict_names(t)
@@ -1647,6 +1689,7 @@ class SQLiteLEvents(base.LEvents):
         for (
             page, ev, et, tet, prop, n, min_ms, max_ms, eb, gb, vb, tb, db
         ) in pages:
+            self.read_stats["pages_decoded"] += 1
             e = np.frombuffer(eb, np.int32)
             g = np.frombuffer(gb, np.int32)
             v = np.frombuffer(vb, np.float32)
@@ -1660,8 +1703,6 @@ class SQLiteLEvents(base.LEvents):
                 keep = keep & (ts >= lo)
             if hi is not None:
                 keep = keep & (ts < hi)
-            if e_code is not None:
-                keep = keep & (e == e_code)
             if g_code is not None:
                 keep = keep & (g == g_code)
             for j in np.nonzero(keep)[0]:
@@ -1681,6 +1722,296 @@ class SQLiteLEvents(base.LEvents):
                         creation_time=when,
                     )
                 )
+        return out
+
+    # --- reads by entity: the pages' entity index ---
+
+    def build_entity_index(
+        self, app_id: int, channel_id: Optional[int] = None
+    ) -> int:
+        """Index every bulk-imported page not yet covered, by entity;
+        returns how many pages it indexed. One pass over those pages,
+        kept in the store: ``find_by_entities`` and an entity-filtered
+        ``find`` call it when they meet an uncovered page, and a loader
+        may call it once after its import so that no query pays."""
+        return self._build_entity_index(
+            self._events_table(app_id, channel_id)
+        )
+
+    def _build_entity_index(self, t: str) -> int:
+        import numpy as np
+
+        self._ensure_pages_schema(t)
+        with self._c.lock:
+            if not self._exists(f"{t}_pent"):
+                return 0
+            todo = [
+                r[0] for r in self._c.conn.execute(
+                    f"SELECT page FROM {t}_pages WHERE page NOT IN "
+                    f"(SELECT page FROM {t}_pent_pages)"
+                ).fetchall()
+            ]
+            for page in todo:
+                row = self._c.conn.execute(
+                    f"SELECT entities, targets, vals, times FROM {t}_pages "
+                    f"WHERE page=?", (page,),
+                ).fetchone()
+                if row is None:
+                    continue
+                self.read_stats["pages_decoded"] += 1
+                e = np.frombuffer(row[0], np.int32)
+                order = np.argsort(e, kind="stable")
+                packed = np.empty(len(e), _pent_dtype())
+                packed["idx"] = order
+                packed["target"] = np.frombuffer(row[1], np.int32)[order]
+                packed["val"] = np.frombuffer(row[2], np.float32)[order]
+                packed["ms"] = np.frombuffer(row[3], np.int64)[order]
+                codes = e[order]
+                starts = np.flatnonzero(
+                    np.concatenate([[True], codes[1:] != codes[:-1]])
+                )
+                ends = np.append(starts[1:], len(codes))
+                blob = packed.tobytes()
+                size = _pent_dtype().itemsize
+                self._c.conn.executemany(
+                    f"INSERT OR REPLACE INTO {t}_pent (ecode, page, packed) "
+                    f"VALUES (?,?,?)",
+                    (
+                        (int(codes[a]), page, blob[a * size:b * size])
+                        for a, b in zip(starts.tolist(), ends.tolist())
+                    ),
+                )
+                self._c.conn.execute(
+                    f"INSERT OR IGNORE INTO {t}_pent_pages (page) VALUES (?)",
+                    (page,),
+                )
+                self._c.conn.commit()
+        return len(todo)
+
+    def _dict_code(self, t: str, name: str) -> Optional[int]:
+        """The dictionary code of one name, or None (no such name, or no
+        dictionary: an app that never had a bulk import)."""
+        if not self._exists_memo(f"{t}_dict"):
+            return None
+        row = self._c.execute(
+            f"SELECT id FROM {t}_dict WHERE name=?", (name,)
+        ).fetchone()
+        return row[0] if row else None
+
+    def _dict_name_of(self, t: str, codes) -> list:
+        """Names of dictionary codes, from this process's copy of the
+        dictionary (see ``_dict_cache``), read further when a code lies
+        past its end."""
+        names = self._dict_cache.setdefault(t, [None])
+        top = max(codes, default=0)
+        if top >= len(names):
+            with self._c.lock:  # one reader extends the copy at a time
+                if top >= len(names):
+                    for code, name in self._c.read_execute(
+                        f"SELECT id, name FROM {t}_dict WHERE id >= ? "
+                        f"ORDER BY id", (len(names),),
+                    ).fetchall():
+                        names.extend([None] * (code - len(names)))
+                        names.append(name)
+        return [names[c] for c in codes]
+
+    def _entity_page_rows(
+        self, t, entity_type, entity_ids, event_names, target_entity_type,
+        start_time=None, until_time=None,
+    ):
+        """{entity id: [(page row, packed rows of the entity in it)]}
+        through the entity index, dead rows dropped: what a read by
+        entity costs is the entity's events. ``page row`` is (page,
+        event, entity_type, target_entity_type, prop)."""
+        import numpy as np
+
+        filt = self._page_filter(
+            start_time, until_time, entity_type, event_names,
+            target_entity_type,
+        )
+        if filt is None or not entity_ids:
+            return {}
+        self._ensure_pages_schema(t)
+        with self._c.lock:
+            if not self._exists(f"{t}_pent"):
+                return {}
+        clauses, params = filt
+        sql = (
+            f"SELECT page, event, entity_type, target_entity_type, prop, "
+            f"dead IS NOT NULL FROM {t}_pages"
+        )
+        if clauses:
+            sql += " WHERE " + " AND ".join(clauses)
+        pages = {r[0]: r for r in self._c.read_execute(sql, params).fetchall()}
+        if not pages:
+            return {}
+        covered = {
+            r[0] for r in self._c.read_execute(
+                f"SELECT page FROM {t}_pent_pages"
+            ).fetchall()
+        }
+        if not covered.issuperset(pages):
+            self._build_entity_index(t)
+        ids = list(dict.fromkeys(entity_ids))
+        code_of: Dict[str, int] = {}
+        found: Dict[int, list] = {}
+        chunk = 900  # sqlite's bound-parameter limit, with headroom
+        for s in range(0, len(ids), chunk):
+            part = ids[s:s + chunk]
+            marks = ",".join("?" * len(part))
+            code_of.update(self._c.read_execute(
+                f"SELECT name, id FROM {t}_dict WHERE name IN ({marks})",
+                part,
+            ).fetchall())
+        codes = list(code_of.values())
+        for s in range(0, len(codes), chunk):
+            part = codes[s:s + chunk]
+            marks = ",".join("?" * len(part))
+            for ecode, page, blob in self._c.read_execute(
+                f"SELECT ecode, page, packed FROM {t}_pent "
+                f"WHERE ecode IN ({marks})", part,
+            ).fetchall():
+                if page in pages:
+                    self.read_stats["index_rows"] += 1
+                    found.setdefault(ecode, []).append(
+                        (page, np.frombuffer(blob, _pent_dtype()))
+                    )
+        dead: Dict[int, "np.ndarray"] = {}
+        touched = {
+            page for rows in found.values() for page, _ in rows
+            if pages[page][5]
+        }
+        for page in touched:  # pages with tombstones: rare
+            row = self._c.read_execute(
+                f"SELECT dead FROM {t}_pages WHERE page=?", (page,)
+            ).fetchone()
+            if row is not None and row[0] is not None:
+                dead[page] = np.frombuffer(row[0], np.uint8)
+        lo = _ms(start_time) if start_time is not None else None
+        hi = _ms(until_time) if until_time is not None else None
+        out: Dict[str, list] = {}
+        for name, code in code_of.items():
+            rows = []
+            for page, packed in found.get(code, ()):
+                if page in dead:
+                    packed = packed[dead[page][packed["idx"]] == 0]
+                if lo is not None:
+                    packed = packed[packed["ms"] >= lo]
+                if hi is not None:
+                    packed = packed[packed["ms"] < hi]
+                if len(packed):
+                    rows.append((pages[page], packed))
+            if rows:
+                out[name] = rows
+        return out
+
+    def _page_events_by_entity(
+        self, t, start_time, until_time, entity_type, entity_id,
+        event_names, target_entity_type, target_entity_id,
+    ) -> List[Event]:
+        """``_page_events`` for one entity, through the entity index."""
+        g_code = None
+        if target_entity_id is not UNSET:
+            g_code = self._dict_code(t, target_entity_id)
+            if g_code is None:
+                return []
+        out: List[Event] = []
+        for (page, ev, et, tet, prop, _), packed in self._entity_page_rows(
+            t, entity_type, [entity_id], event_names, target_entity_type,
+            start_time, until_time,
+        ).get(entity_id, ()):
+            if g_code is not None:
+                packed = packed[packed["target"] == g_code]
+            names = self._dict_name_of(t, packed["target"].tolist())
+            for idx, name, val, ms in zip(
+                packed["idx"].tolist(), names, packed["val"].tolist(),
+                packed["ms"].tolist(),
+            ):
+                when = _dt.datetime.fromtimestamp(
+                    ms / 1000.0, _dt.timezone.utc
+                )
+                out.append(
+                    Event(
+                        event_id=f"pg-{page}-{idx}", event=ev,
+                        entity_type=et, entity_id=entity_id,
+                        target_entity_type=tet, target_entity_id=name,
+                        properties=DataMap({prop: float(val)}),
+                        event_time=when, creation_time=when,
+                    )
+                )
+        return out
+
+    def find_by_entities(
+        self,
+        app_id: int,
+        channel_id: Optional[int] = None,
+        *,
+        entity_type: str,
+        entity_ids: Sequence[str],
+        event_names: Sequence[str],
+        target_entity_type: str,
+    ) -> Dict[str, List[tuple]]:
+        """One pass over the store for a whole micro-batch (see
+        ``base.LEvents.find_by_entities``): one indexed statement over
+        the row table of each store for all the entities, one over the
+        pages' entity index; sealed segments, which have no index by
+        entity yet, still cost one scan an entity. Every statement runs
+        on a read connection that sees what any process has committed:
+        an event the Event Server acknowledged is in the answer."""
+        t = self._events_table(app_id, channel_id)
+        with self._c.lock:
+            if not self._exists(t):
+                raise StorageError(f"events table {t} not initialized")
+        ids = list(dict.fromkeys(entity_ids))
+        out: Dict[str, List[tuple]] = {i: [] for i in ids}
+        if not ids or not event_names:
+            return out
+        marks_, segs = self._segment_state(t)
+        ev_marks = ",".join("?" * len(event_names))
+        chunk = 500
+        for key, store in enumerate(self._c.row_stores()):
+            if not store.has_table(t):
+                continue
+            pred = self._residual_clause(marks_, key)
+            for s in range(0, len(ids), chunk):
+                part = ids[s:s + chunk]
+                sql = (
+                    f"SELECT entity_id, event, target_entity_id, "
+                    f"event_time_ms FROM {t} WHERE entity_type = ? AND "
+                    f"entity_id IN ({','.join('?' * len(part))}) AND "
+                    f"event IN ({ev_marks}) AND target_entity_type = ?"
+                )
+                params = [entity_type, *part, *event_names,
+                          target_entity_type]
+                if pred is not None:
+                    sql += " AND " + pred[0]
+                    params.extend(pred[1])
+                for eid, ev, target, ms in store.read_execute(
+                    sql, params
+                ).fetchall():
+                    if target:
+                        out[eid].append((ev, target, ms))
+        if segs:
+            for eid in ids:
+                out[eid].extend(
+                    (e.event, e.target_entity_id, _ms(e.event_time))
+                    for e in self._segment_events(
+                        t, segs, None, None, entity_type, eid,
+                        list(event_names), target_entity_type, UNSET,
+                    )
+                    if e.target_entity_id
+                )
+        for eid, rows in self._entity_page_rows(
+            t, entity_type, ids, list(event_names), target_entity_type,
+        ).items():
+            for (_, ev, _, _, _, _), packed in rows:
+                names = self._dict_name_of(t, packed["target"].tolist())
+                out[eid].extend(
+                    (ev, name, ms)
+                    for name, ms in zip(names, packed["ms"].tolist())
+                )
+        for rows in out.values():
+            rows.sort(key=lambda r: -r[2])
         return out
 
     def iter_row_events(

@@ -489,6 +489,34 @@ class LEventStore:
 
         return iter(_with_deadline(lookup, timeout_seconds))
 
+    def find_by_entities(
+        self,
+        app_name: str,
+        entity_type: str,
+        entity_ids: Sequence[str],
+        event_names: Sequence[str],
+        target_entity_type: str,
+        channel_name: Optional[str] = None,
+        timeout_seconds: Optional[float] = 10.0,
+    ):
+        """A micro-batch's read: ``{entity id: [(event, target entity
+        id, event time ms), ...]}``, newest first, in one pass over the
+        store where the backend has one (``LEvents.find_by_entities``).
+        Nothing is kept between calls: what another process committed
+        before the call is in the answer."""
+
+        def lookup():
+            app_id, channel_id = app_name_to_id(
+                app_name, channel_name, self.storage
+            )
+            return self.storage.get_l_events().find_by_entities(
+                app_id, channel_id, entity_type=entity_type,
+                entity_ids=list(entity_ids), event_names=list(event_names),
+                target_entity_type=target_entity_type,
+            )
+
+        return _with_deadline(lookup, timeout_seconds)
+
     def find(
         self,
         app_name: str,

@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import time
 from typing import Dict, List, Optional, Sequence, Set, Tuple
 
 import numpy as np
@@ -43,8 +44,58 @@ from predictionio_tpu.ops import retrieval
 from predictionio_tpu.ops.als import ALSConfig, train_als, validate_solver
 from predictionio_tpu.ops.retrieval import ItemRetriever
 from predictionio_tpu.ops.similarity import SimilarityScorer, normalize_rows
+from predictionio_tpu.utils import metrics as _metrics
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
+
+
+# --- what the serving path counts (one family each: a /metrics reader
+# sums a family over its labels and cannot pick one) ---
+
+
+def _m_store_events():
+    return _metrics.get_registry().counter(
+        "pio_ecom_store_events_read_total",
+        "Events the e-commerce engine's query-time store reads returned "
+        "(seen and recent-view histories, one read a micro-batch)",
+    )
+
+
+def _m_recent_queries():
+    return _metrics.get_registry().counter(
+        "pio_ecom_recent_queries_total",
+        "Queries of users without factors that were served from their "
+        "recent views by cosine similarity",
+    )
+
+
+def _m_empty_answers():
+    return _metrics.get_registry().counter(
+        "pio_ecom_empty_answers_total",
+        "Queries answered with no item: a user with neither factors nor "
+        "recent views, or filters that leave no candidate with a "
+        "positive score",
+    )
+
+
+def _m_host_fallbacks():
+    return _metrics.get_registry().counter(
+        "pio_ecom_host_fallback_total",
+        "Queries answered on the host because a list, a category count "
+        "or num lay over the top of the warm ladder (never compiled on "
+        "a live batch)",
+    )
+
+
+def _m_pad_waste():
+    return _metrics.get_registry().histogram(
+        "pio_ecom_list_pad_waste_ratio",
+        "Per served micro-batch, 1 - ids asked for / id slots the batch "
+        "was padded to (exclusion and inclusion lists on the warm "
+        "ladder's widths)",
+        buckets=(0.1, 0.25, 0.5, 0.75, 0.9, 0.95, 0.99, 1.0),
+    )
 
 
 @dataclasses.dataclass(frozen=True)
@@ -193,9 +244,10 @@ class ECommAlgorithmParams(Params):
     lambda_: float = 0.01
     seed: Optional[int] = None
     # serving-time TTL of the unavailableItems constraint cache
-    # (data/constraints.py): past this age a query batch serves the
-    # cached set and kicks an out-of-band refresh — the store is never
-    # on the hot path. Training-time predicts (no prepare_serving) keep
+    # (data/constraints.py): a $set is honoured by every query sent
+    # later than this after its acknowledgement (past half this age a
+    # query batch serves the cached set and kicks an out-of-band
+    # refresh — the store is never on the hot path). Training-time predicts (no prepare_serving) keep
     # the reference's read-per-predict semantics.
     constraint_ttl_s: float = 5.0
     # deploy-time warm-up coverage for the retrieval executables: keep
@@ -211,6 +263,17 @@ class ECommAlgorithmParams(Params):
     precision: str = "float32"
     # stage-1 shortlist width multiplier c (shortlist = pow2(c*n))
     shortlist_mult: int = 4
+    # the closed ladder of the serving executables (ops/retrieval.py):
+    # exclusion lists (blackList + seen items) and inclusion lists
+    # (whiteList) pad to the smallest listed width that holds the
+    # batch's longest, batches to 8 doubling up to warm_max_batch;
+    # warm() compiles the whole product, and a query over a ladder's
+    # top (or over warm_num, or naming more than QUERY_CATEGORIES
+    # categories) is answered on the host. Size exclude_widths by the
+    # store's histories, include_widths by the longest whiteList the
+    # shop's pages send
+    exclude_widths: Tuple[int, ...] = (64, 1024)
+    include_widths: Tuple[int, ...] = (1024,)
     # implicit-feedback training (MLlib ALS.trainImplicit parity): treat
     # the rating column as a confidence signal c = alpha*|r| on the
     # preference p = 1(r > 0). The real e-commerce workload — view/buy
@@ -227,13 +290,40 @@ class ECommAlgorithmParams(Params):
         validate_solver(self.solver, self.block_size, self.rank)
 
 
+# how many category codes a query ships to the fused program (a
+# dimension of every serving executable, so not a knob)
+QUERY_CATEGORIES = 4
+
+
+def category_arrays(
+    items: Dict[int, "Item"], n_items: int
+) -> Tuple[Tuple[str, ...], np.ndarray]:
+    """(category names, per-item category codes [n_items, C] int32, -1
+    where an item has fewer than C) from the ``{dense index: Item}``
+    mapping a train produces."""
+    names = sorted({c for it in items.values() for c in it.categories})
+    code = {c: j for j, c in enumerate(names)}
+    width = max([len(set(it.categories)) for it in items.values()] + [1])
+    codes = np.full((n_items, width), -1, np.int32)
+    for idx, it in items.items():
+        cs = sorted({code[c] for c in it.categories})
+        codes[idx, : len(cs)] = cs
+    return tuple(names), codes
+
+
 @dataclasses.dataclass
 class ECommModel:
     user_factors: np.ndarray  # [n_users, k]
     item_factors: np.ndarray  # [n_items, k]
     user_index: BiMap
     item_index: BiMap
-    items: Dict[int, Item]
+    # the items' categories as arrays (an item may carry several): no
+    # Python object an item, neither in the blob nor before the
+    # collector. ``items`` ({dense index: Item}) is accepted at
+    # construction and folded into them.
+    items: Optional[Dict[int, Item]] = None
+    category_names: Tuple[str, ...] = ()
+    item_categories: Optional[np.ndarray] = None  # [n_items, C] int32
     _scorer: Optional[SimilarityScorer] = dataclasses.field(
         default=None, repr=False, compare=False
     )
@@ -254,45 +344,96 @@ class ECommModel:
     _constraints: Optional[ConstraintCache] = dataclasses.field(
         default=None, repr=False, compare=False
     )
-    _normed_host: Optional[np.ndarray] = dataclasses.field(
+    _cat_code: Optional[Dict[str, int]] = dataclasses.field(
         default=None, repr=False, compare=False
     )
-    _cat_items: Optional[Dict[str, np.ndarray]] = dataclasses.field(
+    _item_names: Optional[np.ndarray] = dataclasses.field(
         default=None, repr=False, compare=False
     )
 
+    def __post_init__(self):
+        self._fold_items()
+
+    def _fold_items(self) -> None:
+        n = self.item_factors.shape[0]
+        if self.items is not None:
+            self.category_names, self.item_categories = category_arrays(
+                self.items, n
+            )
+            self.items = None
+        elif self.item_categories is None:
+            self.item_categories = np.full((n, 1), -1, np.int32)
+        self._cat_code = None
+
+    def __setstate__(self, state):
+        self.__dict__.update(state)
+        for name in ("category_names", "item_categories", "_cat_code",
+                     "_item_names"):  # a blob from before the arrays
+            self.__dict__.setdefault(
+                name, () if name == "category_names" else None
+            )
+        for gone in ("_cat_items", "_normed_host"):
+            self.__dict__.pop(gone, None)
+        self._fold_items()
+
     def __getstate__(self):
         state = self.__dict__.copy()
+        state["_cat_code"] = None
+        state["_item_names"] = None
         state["_scorer"] = None
         state["_inv_item"] = None
         state["_serving_mesh"] = None
         state["_retriever"] = None
         state["_constraints"] = None
-        state["_normed_host"] = None
-        state["_cat_items"] = None
         return state
 
     def attach_serving_mesh(self, mesh) -> None:
         self._serving_mesh = mesh
         self._scorer = None
 
-    @property
-    def normed_host(self) -> np.ndarray:
-        """Host L2-normalized factors for building cosine query vectors
-        (the retrieval path never ships the normalized CATALOG to device
-        — the retriever folds norms into the resident state)."""
-        if self._normed_host is None:
-            self._normed_host = normalize_rows(self.item_factors)
-        return self._normed_host
+    def normed_rows(self, idx) -> np.ndarray:
+        """The L2-normalized factor rows of a few items (zero rows stay
+        zero), for cosine query vectors: the catalog is never normalized
+        on the host (the retriever folds the norms into its resident
+        state, and a second table would be 8.5 GB at 4.16 M x 512)."""
+        return normalize_rows(self.item_factors[idx])
+
+    def category_codes(self, categories) -> np.ndarray:
+        """Codes of the given category names (names no item carries
+        have none: an empty array means NO candidates)."""
+        if self._cat_code is None:
+            self._cat_code = {
+                c: j for j, c in enumerate(self.category_names)
+            }
+        return np.asarray(
+            sorted({
+                self._cat_code[c] for c in categories
+                if c in self._cat_code
+            }),
+            np.int32,
+        )
+
+    def category_mask(self, categories) -> np.ndarray:
+        """[n_items] bool: the item carries one of the categories."""
+        return np.isin(
+            self.item_categories, self.category_codes(categories)
+        ).any(axis=1)
 
     def category_items(self, categories) -> np.ndarray:
         """Dense indices of items carrying at least one of the given
-        categories (the host category loop of `_candidate_mask`, turned
-        into a precomputed inverted index consumed as an on-device
-        inclusion list)."""
-        if self._cat_items is None:
-            self._cat_items = retrieval.build_category_index(self.items)
-        return retrieval.category_candidates(self._cat_items, categories)
+        categories."""
+        return np.flatnonzero(self.category_mask(categories))
+
+    @property
+    def item_names(self) -> np.ndarray:
+        """Item names by dense index (an object array: not a dict, and
+        nothing the collector walks)."""
+        if self._item_names is None:
+            names = np.empty(len(self.item_index), object)
+            for name, idx in self.item_index.items():
+                names[idx] = name
+            self._item_names = names
+        return self._item_names
 
     @property
     def scorer(self) -> SimilarityScorer:
@@ -362,7 +503,7 @@ class ECommAlgorithm(BaseAlgorithm):
             user_index=user_index,
             item_index=item_index,
             items={item_index[k]: v for k, v in td.items.items()},
-        )
+        )  # folded into category arrays by the model
 
     # --- predict-time business rules ---
 
@@ -414,11 +555,7 @@ class ECommAlgorithm(BaseAlgorithm):
             model.item_index[i] for i in black_list if i in model.item_index
         ]] = False
         if query.categories is not None:
-            cats = set(query.categories)
-            for idx in np.nonzero(mask)[0]:
-                item = model.items.get(int(idx))
-                if item is None or not cats.intersection(item.categories):
-                    mask[idx] = False
+            mask &= model.category_mask(query.categories)
         return mask
 
     def prepare_serving(self, ctx, model: ECommModel) -> ECommModel:
@@ -433,10 +570,16 @@ class ECommAlgorithm(BaseAlgorithm):
         mesh = ctx.mesh if ctx is not None else None
         if mesh is not None:
             model.attach_serving_mesh(mesh)
+        p = self.params
         retriever = ItemRetriever(
             model.item_factors, mesh=mesh, component="ecommerce",
-            precision=self.params.precision,
-            shortlist_mult=self.params.shortlist_mult,
+            precision=p.precision,
+            shortlist_mult=p.shortlist_mult,
+            category_codes=model.item_categories,
+            category_width=QUERY_CATEGORIES,
+            exclude_ladder=p.exclude_widths,
+            include_ladder=(1, *p.include_widths),
+            max_batch=p.warm_max_batch,
         )
         cache = ConstraintCache(
             self.params.app_name, ttl_s=self.params.constraint_ttl_s
@@ -458,6 +601,8 @@ class ECommAlgorithm(BaseAlgorithm):
         cache.on_change(apply_mask)
         model._retriever = retriever
         model._constraints = cache
+        # a sample from deploy on: a scrape tells "none" from "no family"
+        _m_host_fallbacks().inc(0)
         return model
 
     def serving_precision(self, model: ECommModel) -> Optional[str]:
@@ -484,9 +629,20 @@ class ECommAlgorithm(BaseAlgorithm):
         legacy cosine-sum path when serving was not prepared."""
         if model._retriever is not None:
             p = self.params
+            t0 = time.perf_counter()
+            # one flag pair: known users' raw dots and recent-view
+            # cosine queries ride one program run (per-row flags)
             model._retriever.warm(
                 n=p.warm_num, max_batch=p.warm_max_batch,
-                flag_combos=((True, False), (True, True)),
+                flag_combos=((True, "rows"),),
+            )
+            model.item_names  # built here, not inside the first batch
+            logger.info(
+                "ecommerce warm ladder: %d executables in %.1fs",
+                model._retriever.ladder_size(
+                    tiers=len(model._retriever.warm_tiers(p.warm_num))
+                ),
+                time.perf_counter() - t0,
             )
         else:
             model.scorer.warm(max_q=16)
@@ -583,78 +739,146 @@ class ECommAlgorithm(BaseAlgorithm):
 
     # --- the sharded on-device retrieval path (prepared serving state) ---
 
+    def _read_histories(self, users) -> Dict[str, list]:
+        """ONE pass over the event store for the whole micro-batch:
+        ``{user: [(event, item, time ms), ...]}``, newest first, over
+        the seen events (when ``unseen_only``) and the similar events.
+        Read when the batch is served and kept nowhere: an event the
+        Event Server acknowledged before the query was sent is in it."""
+        p = self.params
+        names = list(dict.fromkeys(
+            (p.seen_events if p.unseen_only else ()) + p.similar_events
+        ))
+        if not users or not names:
+            return {}
+        try:
+            got = LEventStore().find_by_entities(
+                app_name=p.app_name, entity_type="user",
+                entity_ids=users, event_names=names,
+                target_entity_type="item",
+            )
+        except Exception as e:
+            logger.error("Error when reading the batch's events: %s", e)
+            return {}
+        _m_store_events().inc(sum(len(v) for v in got.values()))
+        return got
+
     def _batch_predict_device(
         self, model: ECommModel, queries
     ) -> List[Tuple[int, PredictedResult]]:
-        """The round-12 serving hot path: one fused score+mask+top_k
-        batch per scoring mode, exact-parity with the host `_finish`
-        path. Known users score raw dot products against the resident
-        factors; unknown users ride the same kernel in cosine mode with
-        a summed-normalized-recents query vector. The unavailableItems
-        set never reads the store here — `cache.get()` is the TTL tick
-        that drives the out-of-band mask refresh."""
-        model._constraints.get()
-        known_meta, known_rows = [], []
-        cos_meta, cos_rows = [], []
+        """The serving hot path: one store read and one fused
+        score+mask+top_k run a micro-batch, exact-parity with the host
+        ``_finish`` path. Known users score raw dot products against
+        the resident factors; users without factors ride the same run
+        in cosine mode (per-row flags) with a summed-normalized-recents
+        query vector. Categories travel as a few codes, tested against
+        the resident per-item codes in the program; blackList, seen
+        items and whiteList as id lists on the warm ladder's widths. A
+        query over the ladder's top is answered on the host. The
+        unavailableItems set never reads the store here —
+        ``cache.get()`` is the TTL tick that drives the out-of-band
+        mask refresh."""
+        p = self.params
+        unavailable = model._constraints.get()
+        retriever = model._retriever
+        seen_names, similar_names = set(p.seen_events), set(p.similar_events)
         out: List[Tuple[int, PredictedResult]] = []
-        for qi, q in queries:
-            user_idx = model.user_index.get(q.user)
-            if user_idx is not None and np.any(
-                model.user_factors[user_idx]
-            ):
-                known_meta.append((qi, q))
-                known_rows.append(model.user_factors[user_idx])
-                continue
-            logger.info("no userFeature found for user %s", q.user)
-            qvec = self._recent_query_vector(model, q)
-            if qvec is None:
-                out.append((qi, PredictedResult()))
-            else:
-                cos_meta.append((qi, q))
-                cos_rows.append(qvec)
-        out += self._retrieve_group(
-            model, known_meta, known_rows, normalize=False
+        with _tracing.stage(_tracing.HOST_PREP):
+            known: Dict[int, int] = {}
+            for qi, q in queries:
+                user_idx = model.user_index.get(q.user)
+                if user_idx is not None and np.any(
+                    model.user_factors[user_idx]
+                ):
+                    known[qi] = user_idx
+            # known users are read for their seen items alone; users
+            # without factors also for their recent views
+            readers = [
+                q.user for qi, q in queries
+                if p.unseen_only or qi not in known
+            ]
+        with _tracing.stage(_tracing.STORE_READ):
+            history = self._read_histories(readers)
+        meta, rows, excl, incl, cats, cosine, on_host = (
+            [], [], [], [], [], [], []
         )
-        out += self._retrieve_group(
-            model, cos_meta, cos_rows, normalize=True
-        )
+        with _tracing.stage(_tracing.MASK_PREP):
+            item_index = model.item_index
+            for qi, q in queries:
+                events = history.get(q.user, ())
+                seen = (
+                    {t for ev, t, _ in events if ev in seen_names}
+                    if p.unseen_only else set()
+                )
+                if qi in known:
+                    row = model.user_factors[known[qi]]
+                else:
+                    logger.info("no userFeature found for user %s", q.user)
+                    recent = [
+                        t for ev, t, _ in events if ev in similar_names
+                    ][:10]
+                    recent_idx = [
+                        item_index[t] for t in recent if t in item_index
+                    ]
+                    if not recent_idx:
+                        out.append((qi, PredictedResult()))
+                        continue
+                    row = model.normed_rows(recent_idx).sum(axis=0)
+                    _m_recent_queries().inc()
+                ex = np.asarray(
+                    [item_index[i] for i in seen.union(q.black_list or ())
+                     if i in item_index],
+                    np.int64,
+                )
+                inc = None if q.white_list is None else np.asarray(
+                    [item_index[i] for i in q.white_list
+                     if i in item_index],
+                    np.int64,
+                )
+                cc = (
+                    None if q.categories is None
+                    else model.category_codes(q.categories)
+                )
+                if q.num > max(16, p.warm_num) or not retriever.fits(
+                    exclude=len(ex),
+                    include=0 if inc is None else len(inc),
+                    categories=0 if cc is None else len(cc),
+                ):
+                    on_host.append((qi, q, row, qi not in known, seen, inc))
+                    continue
+                meta.append((qi, q))
+                rows.append(row)
+                excl.append(ex)
+                incl.append(inc)
+                cats.append(cc)
+                cosine.append(qi not in known)
+        top = retriever.max_batch
+        for s in range(0, len(meta), top):  # a batch over the ladder's top
+            out += self._retrieve_group(
+                model, meta[s:s + top], rows[s:s + top], excl[s:s + top],
+                incl[s:s + top], cats[s:s + top], cosine[s:s + top],
+            )
+        for qi, q, row, is_cosine, seen, inc in on_host:
+            _m_host_fallbacks().inc()
+            # a whiteList's rows alone where there is one; cosine = dot
+            # over the item's norm, from the retriever's own norms: no
+            # normalized copy of the catalog, no pass over it a query
+            at = slice(None) if inc is None else np.unique(inc)
+            part = model.item_factors[at] @ row
+            if is_cosine:
+                part *= retriever.reciprocal_norms[at]
+            scores = np.zeros(retriever.n_items, np.float32)
+            scores[at] = part
+            out.append(
+                (qi, self._finish(model, q, scores, unavailable, seen))
+            )
+        empty = sum(1 for _, r in out if not r.item_scores)
+        if empty:
+            _m_empty_answers().inc(empty)
         return out
 
-    def _recent_query_vector(
-        self, model: ECommModel, query: Query
-    ) -> Optional[np.ndarray]:
-        """Unknown-user cosine query vector: the sum of the normalized
-        factor rows of the 10 most recent similar-event items — the same
-        value `_similar_to_recent`'s cosine_sum scores against, folded
-        to one [k] row so it batches with other queries."""
-        recent_idx = self._recent_item_idx(model, query)
-        if recent_idx is None:
-            return None
-        return model.normed_host[recent_idx].sum(axis=0)
-
-    def _exclude_for(self, model: ECommModel, query: Query) -> np.ndarray:
-        """Per-query exclusion indices: query blackList + (unseen_only)
-        the user's seen items. The unavailableItems set is NOT here — it
-        is the resident global mask."""
-        black = set(query.black_list or ())
-        black |= self._seen_items(query)
-        return np.asarray(
-            [model.item_index[i] for i in black if i in model.item_index],
-            np.int64,
-        )
-
-    def _include_for(
-        self, model: ECommModel, query: Query
-    ) -> Optional[np.ndarray]:
-        """Per-query inclusion indices (None = unrestricted; empty =
-        NO candidates): whiteList ∩ category index."""
-        return retrieval.include_candidates(
-            model.item_index, query.white_list, query.categories,
-            model.category_items,
-        )
-
     def _retrieve_group(
-        self, model: ECommModel, meta, rows, *, normalize: bool
+        self, model: ECommModel, meta, rows, excl, incl, cats, cosine
     ) -> List[Tuple[int, PredictedResult]]:
         if not meta:
             return []
@@ -665,27 +889,35 @@ class ECommAlgorithm(BaseAlgorithm):
         scores, idx = retriever.topn(
             np.stack(rows).astype(np.float32),
             n_req,
-            exclude=[self._exclude_for(model, q) for _, q in meta],
-            include=[self._include_for(model, q) for _, q in meta],
+            exclude=excl,
+            include=incl,
+            categories=cats,
             positive_only=True,
-            normalize=normalize,
+            normalize=np.asarray(cosine, bool),
         )
-        inv_item = model.inv_item
-        trimmed = retrieval.trimmed_results(
-            scores, idx, [q.num for _, q in meta]
+        b_pad, w_excl, w_incl = retriever.last_padded
+        asked = sum(len(a) for a in excl) + sum(
+            len(a) for a in incl if a is not None
         )
-        return [
-            (
-                qi,
-                PredictedResult(
-                    item_scores=tuple(
-                        ItemScore(item=inv_item[int(i)], score=float(s))
-                        for i, s in zip(ids, ss)
-                    )
-                ),
+        slots = b_pad * (w_excl + (w_incl if w_incl > 1 else 0))
+        _m_pad_waste().observe(1.0 - asked / slots)
+        with _tracing.stage(_tracing.BUILD):
+            names = model.item_names
+            trimmed = retrieval.trimmed_results(
+                scores, idx, [q.num for _, q in meta]
             )
-            for (qi, _), (ids, ss) in zip(meta, trimmed)
-        ]
+            return [
+                (
+                    qi,
+                    PredictedResult(
+                        item_scores=tuple(
+                            ItemScore(item=names[i], score=s)
+                            for i, s in zip(ids.tolist(), ss.tolist())
+                        )
+                    ),
+                )
+                for (qi, _), (ids, ss) in zip(meta, trimmed)
+            ]
 
     def _finish(
         self,
@@ -693,9 +925,10 @@ class ECommAlgorithm(BaseAlgorithm):
         query: Query,
         scores: np.ndarray,
         unavailable: Set[str],
+        seen: Optional[Set[str]] = None,
     ) -> PredictedResult:
         black_list = set(query.black_list or ())
-        black_list |= self._seen_items(query)
+        black_list |= self._seen_items(query) if seen is None else seen
         black_list |= unavailable
         mask = self._candidate_mask(model, query, black_list)
         scores = np.where(mask & (scores > 0), scores, -np.inf)
@@ -710,6 +943,16 @@ class ECommAlgorithm(BaseAlgorithm):
                 for i in top
             )
         )
+
+    def query_from_json(self, json_obj) -> Query:
+        """Upstream's query spells ``whiteList`` and ``blackList``; the
+        dataclass's own field names are taken too."""
+        obj = dict(json_obj or {})
+        for theirs, ours in (("whiteList", "white_list"),
+                             ("blackList", "black_list")):
+            if theirs in obj:
+                obj[ours] = obj.pop(theirs)
+        return super().query_from_json(obj)
 
     def result_to_json(self, result: PredictedResult):
         return {

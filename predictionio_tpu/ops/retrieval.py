@@ -105,11 +105,12 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from predictionio_tpu.ops.als import _pack_topn, unpack_topn
-from predictionio_tpu.ops.similarity import pad_rows_pow2, pow2_at_least
+from predictionio_tpu.ops.similarity import pow2_at_least
 from predictionio_tpu.parallel.mesh import pad_to_multiple
 from predictionio_tpu.utils import compilation_cache as _cc
 from predictionio_tpu.utils import device_ledger as _ledger
 from predictionio_tpu.utils import metrics as _metrics
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -156,25 +157,137 @@ def _reciprocal_norms(factors: np.ndarray) -> np.ndarray:
     this yields cosine-against-normalized-candidates, so ONE resident
     factor matrix serves both raw-dot (known-user) and cosine
     (similar-items) scoring instead of two catalog-sized copies."""
-    norms = np.linalg.norm(np.asarray(factors, np.float32), axis=1)
+    f = np.asarray(factors, np.float32)
+    # einsum, not linalg.norm: the latter squares into a temporary as
+    # large as the table (8.5 GB at 4.16 M x 512)
+    norms = np.sqrt(np.einsum("ij,ij->i", f, f))
     return np.where(norms > 0, 1.0 / np.where(norms == 0, 1.0, norms), 0.0).astype(
         np.float32
     )
 
 
-def _mask_scores(scores, allow0, excl, incl, has_incl, positive_only):
+def _batch_sizes(max_batch: int) -> Tuple[int, ...]:
+    """The padded batch sizes: 8 doubling until ``max_batch`` is held."""
+    sizes = [8]
+    while sizes[-1] < max_batch:
+        sizes.append(sizes[-1] * 2)
+    return tuple(sizes)
+
+
+def _ladder(widths) -> Optional[Tuple[int, ...]]:
+    """A closed ladder of widths, ascending and at least 1, or None."""
+    if widths is None:
+        return None
+    out = tuple(sorted({max(1, int(w)) for w in widths}))
+    if not out:
+        raise ValueError("a ladder needs at least one width")
+    return out
+
+
+# an item id splits into a high and a low digit, id = hi * _LO + lo
+_LO = 2048
+# lists wider than this are folded in pieces, so that the one-hots of a
+# [128, 8192] block never stand in memory at once
+_LIST_CHUNK = 1024
+
+
+def _membership(ids, rows: int):
+    """[B, rows] bool: does row j appear in ``ids[b]``? Computed as the
+    product of two one-hot encodings (the id's high digit, its low
+    digit) on the matrix unit: ``grid[b, hi, lo] = sum_w [hi_w == hi] *
+    [lo_w == lo]``. A scatter of ``[B, W]`` ids into a ``[B, rows]``
+    mask lowers on the TPU to a loop over the batch's rows that rewrites
+    each whole row (0.7 ms a row at 4.16 M items: over half of the fused
+    program at a batch of 32, PERF.md PR 28); this costs 2·B·W·rows
+    operations in one bfloat16 pass, exact because its operands are 0
+    and 1. Ids outside ``[0, rows)`` (the sentinel of padded slots, ids
+    owned by another shard) match no high digit or fall into the grid's
+    unread tail, and are dropped."""
+    n_hi = -(-rows // _LO)
+    b, w = ids.shape
+    hi_of = jnp.arange(n_hi, dtype=jnp.int32)[None, None, :]
+    lo_of = jnp.arange(_LO, dtype=jnp.int32)[None, None, :]
+
+    def fold(part):
+        hi = (part // _LO)[:, :, None] == hi_of
+        lo = (part % _LO)[:, :, None] == lo_of
+        return jnp.einsum(
+            "bwh,bwl->bhl", hi.astype(jnp.bfloat16),
+            lo.astype(jnp.bfloat16), preferred_element_type=jnp.float32,
+        )
+
+    if w <= _LIST_CHUNK:
+        grid = fold(ids)
+    else:
+        ids = jnp.pad(  # -1 has no high digit: it matches nothing
+            ids, ((0, 0), (0, -w % _LIST_CHUNK)), constant_values=-1
+        )
+        pieces = ids.reshape(b, -1, _LIST_CHUNK)
+        grid, _ = jax.lax.scan(
+            lambda acc, part: (acc + fold(part), None),
+            jnp.zeros((b, n_hi, _LO), jnp.float32),
+            jnp.moveaxis(pieces, 1, 0),
+        )
+    return grid.reshape(b, n_hi * _LO)[:, :rows] > 0
+
+
+# the width of one block of the block-wise top-k
+_BLOCK = 1024
+
+
+def _top_k(scores, n: int):
+    """``lax.top_k(scores, n)`` over a wide score block, in two steps:
+    the maximum of each block of ``_BLOCK`` scores, the ``n`` blocks
+    with the largest maxima, then the top ``n`` of those blocks' scores.
+    Exact, ties included: an element of the true top ``n`` lies in one
+    of the ``n`` best blocks (each better block holds an element that
+    beats it), the blocks are taken up in index order, so that equal
+    scores still go to the lowest index. ``lax.top_k`` over [B, 4.16 M]
+    is 8 ms at a batch of 32 and 36 ms at 128 (PERF.md PR 28); the
+    block maxima are one pass over the scores."""
+    b, rows = scores.shape
+    n_blocks = -(-rows // _BLOCK)
+    if n_blocks <= 2 * n:  # a narrow block: nothing to gain
+        return jax.lax.top_k(scores, n)
+    padded = jnp.pad(
+        scores, ((0, 0), (0, n_blocks * _BLOCK - rows)),
+        constant_values=-jnp.inf,
+    ).reshape(b, n_blocks, _BLOCK)
+    _, best = jax.lax.top_k(padded.max(axis=2), n)
+    best = jnp.sort(best, axis=1)
+    cand = jnp.take_along_axis(padded, best[:, :, None], axis=1)
+    s, j = jax.lax.top_k(cand.reshape(b, n * _BLOCK), n)
+    i = jnp.take_along_axis(best, j // _BLOCK, axis=1) * _BLOCK + j % _BLOCK
+    # a dead slot (fewer than n live candidates) may point into the pad
+    return s, jnp.minimum(i, rows - 1)
+
+
+def _mask_scores(
+    scores, allow0, excl, incl, has_incl, positive_only, cat=None
+):
     """Shared mask application: ``allow0`` is the resident [rows] mask,
     ``excl``/``incl`` are per-query id lists already mapped into THIS
     score block's index space with out-of-range values pointing past the
     last row (``mode="drop"`` discards them — sentinel-padded slots and,
     on a shard, ids owned by other shards). ``has_incl`` flags queries
     with a whitelist: only their rows intersect with the scattered
-    inclusion mask."""
-    b = jnp.arange(scores.shape[0], dtype=jnp.int32)[:, None]
-    allow = jnp.broadcast_to(allow0[None, :], scores.shape)
-    allow = allow.at[b, excl].set(False, mode="drop")
-    inc = jnp.zeros(scores.shape, bool).at[b, incl].set(True, mode="drop")
-    allow = allow & (inc | ~has_incl[:, None])
+    inclusion mask. ``cat`` = (resident per-item category codes
+    [rows, C], the queries' category codes [B, Wc], which queries
+    carry any): membership is a compare in this program, so a category
+    filter ships its few codes and never a list as wide as the
+    category. Item slots without a category hold -1, query slots -2:
+    padding never matches."""
+    rows = scores.shape[1]
+    allow = allow0[None, :] & ~_membership(excl, rows)
+    allow = allow & (_membership(incl, rows) | ~has_incl[:, None])
+    if cat is not None:
+        codes, cats, has_cat = cat
+        member = jnp.zeros(scores.shape, bool)
+        for c in range(codes.shape[1]):  # static and small: 1 for Taobao
+            member = member | jnp.any(
+                codes[None, :, c, None] == cats[:, None, :], axis=2
+            )
+        allow = allow & (member | ~has_cat[:, None])
     if positive_only:
         allow = allow & (scores > 0)
     return jnp.where(allow, scores, -jnp.inf)
@@ -261,18 +374,30 @@ def include_candidates(
     jax.jit, static_argnames=("n", "positive_only", "normalize")
 )
 def _fused_topn_single(
-    q, Y, rn, allow0, excl, incl, has_incl, n, positive_only, normalize
+    q, Y, rn, allow0, excl, incl, has_incl, codes, cats, has_cat, row_norm,
+    n, positive_only, normalize
 ):
     """The single-device path as ONE program: matmul + optional cosine
     scaling + mask scatter + top_k, no [B, N] score materialization on
     host and no host post-filter (the pre-round-12 ecommerce predict
     computed the full score row in numpy and masked it in Python)."""
-    scores = _exact_scores(q, Y)
-    if normalize:
-        scores = scores * rn[None, :]
-    scores = _mask_scores(scores, allow0, excl, incl, has_incl, positive_only)
-    s, i = jax.lax.top_k(scores, n)
+    scores = _scale_cosine(_exact_scores(q, Y), rn, row_norm, normalize)
+    scores = _mask_scores(
+        scores, allow0, excl, incl, has_incl, positive_only,
+        (codes, cats, has_cat),
+    )
+    s, i = _top_k(scores, n)
     return _pack_topn(s, i)
+
+
+def _scale_cosine(scores, rn, row_norm, normalize):
+    """Cosine scaling of a score block by the resident reciprocal
+    norms: every row (``normalize`` True), none (False), or the rows
+    flagged in ``row_norm`` (``"rows"``: known users' raw dots and
+    recent-view cosine queries ride one program run)."""
+    if normalize == "rows":
+        return scores * jnp.where(row_norm[:, None], rn[None, :], 1.0)
+    return scores * rn[None, :] if normalize else scores
 
 
 def _exact_scores(q, Y):
@@ -306,7 +431,8 @@ def _approx_scores(q, Yq, scale, precision):
 
 
 def _rescore_exact(
-    q, Yq, scale, s1, i1, rn, positive_only, normalize, precision
+    q, Yq, scale, s1, i1, rn, positive_only, normalize, precision,
+    row_norm=None,
 ):
     """Stage 2: gather ONLY the shortlisted rows, dequantize to f32,
     and rescore against the full-precision query — a returned score is
@@ -319,7 +445,11 @@ def _rescore_exact(
     if precision == "int8":
         rows = rows * jnp.take(scale, i1)[:, :, None]
     rescored = jnp.einsum("bk,bck->bc", q, rows, precision="highest")
-    if normalize:
+    if normalize == "rows":
+        rescored = rescored * jnp.where(
+            row_norm[:, None], jnp.take(rn, i1), 1.0
+        )
+    elif normalize:
         rescored = rescored * jnp.take(rn, i1)
     if positive_only:
         rescored = jnp.where(rescored > 0, rescored, -jnp.inf)
@@ -334,6 +464,7 @@ def _rescore_exact(
 )
 def _fused_topn_single_2s(
     q, Yq, scale, rn, allow0, excl, incl, has_incl,
+    codes, cats, has_cat, row_norm,
     n, shortlist, positive_only, normalize, precision,
 ):
     """Quantized single-device path: BOTH stages in one program —
@@ -341,15 +472,17 @@ def _fused_topn_single_2s(
     mask scatter as the exact path + top-(c·n) shortlist, then the
     exact-f32 rescore of just the shortlist rows and the final
     top_k."""
-    approx = _approx_scores(q, Yq, scale, precision)
-    if normalize:
-        approx = approx * rn[None, :]
-    approx = _mask_scores(
-        approx, allow0, excl, incl, has_incl, positive_only
+    approx = _scale_cosine(
+        _approx_scores(q, Yq, scale, precision), rn, row_norm, normalize
     )
-    s1, i1 = jax.lax.top_k(approx, shortlist)
+    approx = _mask_scores(
+        approx, allow0, excl, incl, has_incl, positive_only,
+        (codes, cats, has_cat),
+    )
+    s1, i1 = _top_k(approx, shortlist)
     rescored = _rescore_exact(
-        q, Yq, scale, s1, i1, rn, positive_only, normalize, precision
+        q, Yq, scale, s1, i1, rn, positive_only, normalize, precision,
+        row_norm,
     )
     s, j = jax.lax.top_k(rescored, n)
     return _pack_topn(s, jnp.take_along_axis(i1, j, axis=1))
@@ -357,6 +490,7 @@ def _fused_topn_single_2s(
 
 def _shard_topk_kernel_2s(
     q, Yq, scale, rn, allow0, excl, incl, has_incl,
+    codes, cats, has_cat, row_norm,
     *, axis, n_local, shortlist, positive_only, normalize, precision,
 ):
     """Per-shard two-stage body (runs under shard_map): the quantized
@@ -371,23 +505,24 @@ def _shard_topk_kernel_2s(
     def localize(g):
         return jnp.where((g >= off) & (g < off + rows_l), g - off, rows_l)
 
-    approx = _approx_scores(q, Yq, scale, precision)
-    if normalize:
-        approx = approx * rn[None, :]
+    approx = _scale_cosine(
+        _approx_scores(q, Yq, scale, precision), rn, row_norm, normalize
+    )
     approx = _mask_scores(
         approx, allow0, localize(excl), localize(incl), has_incl,
-        positive_only,
+        positive_only, (codes, cats, has_cat),
     )
-    s1, i1 = jax.lax.top_k(approx, shortlist)
+    s1, i1 = _top_k(approx, shortlist)
     rescored = _rescore_exact(
-        q, Yq, scale, s1, i1, rn, positive_only, normalize, precision
+        q, Yq, scale, s1, i1, rn, positive_only, normalize, precision,
+        row_norm,
     )
     s, j = jax.lax.top_k(rescored, n_local)
     return _pack_topn(s, jnp.take_along_axis(i1, j, axis=1) + off)
 
 
 def _shard_topk_kernel(
-    q, Y, rn, allow0, excl, incl, has_incl,
+    q, Y, rn, allow0, excl, incl, has_incl, codes, cats, has_cat, row_norm,
     *, axis, n_local, positive_only, normalize,
 ):
     """Per-shard body (runs under shard_map): local slice views of the
@@ -402,14 +537,12 @@ def _shard_topk_kernel(
         # which .at[] would WRAP NumPy-style back into this shard
         return jnp.where((g >= off) & (g < off + rows_l), g - off, rows_l)
 
-    scores = _exact_scores(q, Y)
-    if normalize:
-        scores = scores * rn[None, :]
+    scores = _scale_cosine(_exact_scores(q, Y), rn, row_norm, normalize)
     scores = _mask_scores(
         scores, allow0, localize(excl), localize(incl), has_incl,
-        positive_only,
+        positive_only, (codes, cats, has_cat),
     )
-    s, i = jax.lax.top_k(scores, n_local)
+    s, i = _top_k(scores, n_local)
     return _pack_topn(s, i + off)
 
 
@@ -568,7 +701,24 @@ class ItemRetriever:
         device=None,
         precision: str = "float32",
         shortlist_mult: int = 4,
+        category_codes: Optional[np.ndarray] = None,
+        category_width: int = 1,
+        exclude_ladder: Optional[Sequence[int]] = None,
+        include_ladder: Optional[Sequence[int]] = None,
+        max_batch: Optional[int] = None,
     ):
+        """``category_codes`` ([n_items, C] int32, -1 where an item has
+        fewer than C categories) makes the items' categories resident
+        beside the mask; a query then ships up to ``category_width``
+        category codes (``topn(categories=...)``). The two ladders and
+        ``max_batch`` close the executable space: with them set, id
+        lists pad to the smallest listed width that holds the batch's
+        longest list (a width of 1 stands for "no list"), the batch to
+        8 doubling up to ``max_batch``, and ``warm()`` compiles every
+        combination; a list or batch over the top is refused
+        (``fits()`` says so beforehand) instead of compiled on a live
+        batch. Without them widths are powers of two, as traffic brings
+        them, and ``warm()`` covers the widths it is told."""
         if precision not in PRECISIONS:
             raise ValueError(
                 f"precision must be one of {PRECISIONS}, got {precision!r}"
@@ -597,8 +747,16 @@ class ItemRetriever:
         self._n_shards = n_shards
         n_pad = pad_to_multiple(max(self.n_items, 1), n_shards)
         self._n_pad = n_pad
-        padded = np.zeros((n_pad, self.rank), np.float32)
-        padded[: self.n_items] = factors
+        # the caller's array, never a second copy of it: at float32
+        # with no padding row to add, the table that is uploaded IS the
+        # caller's (8.5 GB at 4.16 M x 512 would otherwise sit twice in
+        # host RAM for the life of the server)
+        self._factors = factors
+        if n_pad == self.n_items:
+            padded = factors
+        else:
+            padded = np.zeros((n_pad, self.rank), np.float32)
+            padded[: self.n_items] = factors
         # residency tier: the resident row storage + the f32 matrix the
         # device rescore (and the parity oracle) actually scores
         # against. Norms fold from the DEQUANTIZED rows, so the cosine
@@ -612,24 +770,43 @@ class ItemRetriever:
             deq = y_host.astype(np.float32)
         else:
             y_host, deq = padded, padded
-        self._y_host = y_host
+        # float32 keeps no staging copy: dequantized_factors() hands
+        # back the caller's array
+        self._y_host = y_host if precision != "float32" else None
         self._scale_host = scale_host
         # the final exact-rescore stage reads the ORIGINAL f32 rows out
         # of host RAM (every engine keeps item_factors host-resident
         # for pickling anyway) — only the quantized rows occupy HBM
-        if precision != "float32":
-            self._y_f32_host: Optional[np.ndarray] = padded
-            rn_exact = np.zeros(n_pad, np.float32)
-            rn_exact[: self.n_items] = _reciprocal_norms(factors)
-            self._rn_f32_host: Optional[np.ndarray] = rn_exact
-        else:
-            self._y_f32_host = None
-            self._rn_f32_host = None
+        self._y_f32_host: Optional[np.ndarray] = (
+            padded if precision != "float32" else None
+        )
         rn = np.zeros(n_pad, np.float32)
         rn[: self.n_items] = _reciprocal_norms(deq[: self.n_items])
+        # the ORIGINAL rows' norms stay on the host (4 bytes an item):
+        # the exact rescore and an engine's host path read them
+        if precision != "float32":
+            rn_exact = np.zeros(n_pad, np.float32)
+            rn_exact[: self.n_items] = _reciprocal_norms(factors)
+        else:
+            rn_exact = rn
+        self._rn_f32_host: Optional[np.ndarray] = rn_exact
         self._valid = np.zeros(n_pad, bool)
         self._valid[: self.n_items] = True
         self._excluded_ids: Optional[np.ndarray] = None
+        self.has_categories = category_codes is not None
+        codes = np.full((n_pad, 1), -1, np.int32)
+        if category_codes is not None:
+            cc = np.asarray(category_codes, np.int32).reshape(
+                self.n_items, -1
+            )
+            codes = np.full((n_pad, max(1, cc.shape[1])), -1, np.int32)
+            codes[: self.n_items, : cc.shape[1]] = cc
+        self.category_width = max(1, int(category_width))
+        self._exclude_ladder = _ladder(exclude_ladder)
+        self._include_ladder = _ladder(include_ladder)
+        self._batch_ladder = (
+            None if max_batch is None else _batch_sizes(max_batch)
+        )
         if mesh is None:
             self._device = device
             put = lambda a: (
@@ -642,6 +819,7 @@ class ItemRetriever:
             )
             self._rn_dev = put(rn)
             self._allow_dev = put(self._valid)
+            self._codes_dev = put(codes)
             self._rep_q = None
         else:
             self._device = None
@@ -655,6 +833,9 @@ class ItemRetriever:
             self._rn_dev = jax.device_put(rn, NamedSharding(mesh, P(axis)))
             self._allow_dev = jax.device_put(
                 self._valid, NamedSharding(mesh, P(axis))
+            )
+            self._codes_dev = jax.device_put(
+                codes, NamedSharding(mesh, P(axis, None))
             )
             self._rep_q = NamedSharding(mesh, P())
             self._rep_out = NamedSharding(mesh, P(None, None))
@@ -706,7 +887,7 @@ class ItemRetriever:
             component=component, precision=precision
         ).set(f_bytes / max(1, self.n_items))
         m_label, m_bytes, m_members = _ledger.device_footprint(
-            self._allow_dev
+            self._allow_dev, self._codes_dev
         )
         self._ledger_mask = _ledger.get_ledger().register(
             component=f"{component}-mask",
@@ -763,7 +944,9 @@ class ItemRetriever:
         # stale number here is exactly the reconcile() drift the ledger
         # exists to catch. The resident-bytes gauge re-reads the actual
         # arrays for the same reason.
-        _, m_bytes, m_members = _ledger.device_footprint(self._allow_dev)
+        _, m_bytes, m_members = _ledger.device_footprint(
+            self._allow_dev, self._codes_dev
+        )
         self._ledger_mask.set(m_bytes, members=m_members)
         _m_resident_bytes().labels(component=self.component).set(
             self.resident_bytes
@@ -784,10 +967,22 @@ class ItemRetriever:
 
     @property
     def resident_bytes(self) -> int:
-        arrays = [self._y_dev, self._rn_dev, self._allow_dev]
+        arrays = [
+            self._y_dev, self._rn_dev, self._allow_dev, self._codes_dev
+        ]
         if self._scale_dev is not None:
             arrays.append(self._scale_dev)
         return int(sum(a.nbytes for a in arrays))
+
+    @property
+    def reciprocal_norms(self) -> np.ndarray:
+        """1/||y|| of the original float32 rows, [n_items], on the host."""
+        return self._rn_f32_host[: self.n_items]
+
+    @property
+    def max_batch(self) -> Optional[int]:
+        """The closed ladder's widest batch (None: no ladder)."""
+        return self._batch_ladder[-1] if self._batch_ladder else None
 
     def dequantized_factors(self) -> np.ndarray:
         """Host f32 matrix the device path actually scores against —
@@ -799,19 +994,43 @@ class ItemRetriever:
         elif self.precision == "bf16":
             deq = self._y_host.astype(np.float32)
         else:
-            deq = self._y_host
+            return self._factors
         return deq[: self.n_items]
 
     # --- the hot path ---
 
+    def fits(self, *, exclude=0, include=0, categories=0) -> bool:
+        """Whether a query whose exclusion list, inclusion list and
+        category list are that long is served by an executable of the
+        closed ladder (always, where no ladder is set). The engine asks
+        before it calls ``topn``: what does not fit is answered on the
+        host, never compiled on a live batch."""
+        for ladder, n in (
+            (self._exclude_ladder, exclude), (self._include_ladder, include),
+        ):
+            if ladder is not None and n > ladder[-1]:
+                return False
+        return categories <= self.category_width
+
+    @staticmethod
+    def _width(ladder, width: int, what: str) -> int:
+        if ladder is None:
+            return pow2_at_least(width)
+        for w in ladder:
+            if w >= width:
+                return w
+        raise ValueError(
+            f"{what} of {width} is over the ladder's top {ladder[-1]}"
+        )
+
     def _assemble_idx(
-        self, lists, b_pad: int
+        self, lists, b_pad: int, ladder=None, what: str = "id list"
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Per-query id lists -> a sentinel-padded [b_pad, W] int32 block
-        (W the next power of two, so executables bucket O(log) widths)
-        plus the has-list flag vector. The sentinel is n_pad: out of
-        range on every shard and on the single device, so the mask
-        scatter drops it."""
+        (W the next power of two, or the next width of ``ladder``, so
+        executables bucket O(log) widths) plus the has-list flag vector.
+        The sentinel is n_pad: out of range on every shard and on the
+        single device, so the mask scatter drops it."""
         has = np.zeros(b_pad, bool)
         width = 1
         rows: List[np.ndarray] = []
@@ -822,7 +1041,7 @@ class ItemRetriever:
             a = np.asarray(a, np.int64)
             rows.append(a)
             width = max(width, len(a))
-        width = pow2_at_least(width)
+        width = self._width(ladder, width, what)
         out = np.full((b_pad, width), self._n_pad, np.int32)
         for r, a in enumerate(rows):
             if len(a):
@@ -837,10 +1056,17 @@ class ItemRetriever:
         *,
         exclude: Optional[Sequence] = None,
         include: Optional[Sequence] = None,
+        categories: Optional[Sequence] = None,
         positive_only: bool = False,
-        normalize: bool = False,
+        normalize=False,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Exact masked top-``n`` for a query batch.
+
+        ``categories`` are per-query arrays of category codes (``None``
+        = no category filter; an empty array = NO candidates), tested
+        against the resident per-item codes inside the program.
+        ``normalize`` may be a per-row boolean sequence: the flagged
+        rows score as cosine, the others as raw dots, in one run.
 
         ``exclude``/``include`` are per-query dense item-index arrays
         (``None`` entries mean no list for that query; an ``include``
@@ -874,14 +1100,36 @@ class ItemRetriever:
             n if self.precision == "float32"
             else self._shortlist_width(n, self.n_items)
         )
-        qp = pad_rows_pow2(q, 8)
-        b_pad = qp.shape[0]
-        excl, _ = self._assemble_idx(
-            list(exclude or []) + [None] * (b_pad - b), b_pad
-        )
-        incl, has_incl = self._assemble_idx(
-            list(include or []) + [None] * (b_pad - b), b_pad
-        )
+        with _tracing.stage(_tracing.MASK_PREP):
+            b_pad = max(8, self._width(self._batch_ladder, b, "a batch"))
+            qp = np.zeros((b_pad, q.shape[1]), np.float32)
+            qp[:b] = q
+            excl, _ = self._assemble_idx(
+                list(exclude or []) + [None] * (b_pad - b), b_pad,
+                self._exclude_ladder, "an exclusion list",
+            )
+            incl, has_incl = self._assemble_idx(
+                list(include or []) + [None] * (b_pad - b), b_pad,
+                self._include_ladder, "an inclusion list",
+            )
+            cats = np.full((b_pad, self.category_width), -2, np.int32)
+            has_cat = np.zeros(b_pad, bool)
+            for r, c in enumerate(categories or ()):
+                if c is not None:
+                    if len(c) > self.category_width:
+                        raise ValueError(
+                            f"{len(c)} categories in one query, the "
+                            f"retriever holds {self.category_width}"
+                        )
+                    cats[r, : len(c)] = c
+                    has_cat[r] = True
+            row_norm = np.zeros(b_pad, bool)
+            if not isinstance(normalize, (bool, np.bool_)):
+                row_norm[:b] = np.asarray(normalize, bool)
+                normalize = "rows"
+            else:
+                normalize = bool(normalize)
+        self.last_padded = (b_pad, excl.shape[1], incl.shape[1])
         _m_mask_age().labels(component=self.component).set(self.mask_age_s)
         _m_padding_waste().labels(site="retrieval_batch").set(
             (b_pad - b) / b_pad
@@ -899,15 +1147,18 @@ class ItemRetriever:
                 exec_key = (
                     self._n_pad, self.rank, b_pad,
                     excl.shape[1], incl.shape[1],
+                    self._codes_dev.shape[1], cats.shape[1],
                     n, positive_only, normalize,
                 )
-                with _cc.track_compile(
+                with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
                     packed = _fused_topn_single(
                         put(qp), self._y_dev, self._rn_dev,
                         self._allow_dev,
                         put(excl), put(incl), put(has_incl),
+                        self._codes_dev, put(cats), put(has_cat),
+                        put(row_norm),
                         n, positive_only, normalize,
                     )
             else:
@@ -915,32 +1166,42 @@ class ItemRetriever:
                 exec_key = (
                     self._n_pad, self.rank, b_pad,
                     excl.shape[1], incl.shape[1],
+                    self._codes_dev.shape[1], cats.shape[1],
                     n_dev, shortlist, positive_only, normalize,
                     self.precision,
                 )
-                with _cc.track_compile(
+                with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
                     packed = _fused_topn_single_2s(
                         put(qp), self._y_dev, self._scale_operand,
                         self._rn_dev, self._allow_dev,
                         put(excl), put(incl), put(has_incl),
+                        self._codes_dev, put(cats), put(has_cat),
+                        put(row_norm),
                         n_dev, shortlist, positive_only, normalize,
                         self.precision,
                     )
-            host = np.asarray(packed)[:b]
+            with _tracing.stage(_tracing.DEVICE_WAIT):
+                host = np.asarray(packed)[:b]
             _m_shard_seconds().observe(time.perf_counter() - t0)
-            if self.precision != "float32":
-                return self._refine_exact(
-                    q, host, n_dev, n, positive_only, normalize
-                )
-            return unpack_topn(host, n)
+            with _tracing.stage(_tracing.BUILD):
+                if self.precision != "float32":
+                    return self._refine_exact(
+                        q, host, n_dev, n, positive_only,
+                        row_norm[:b] if normalize == "rows" else normalize,
+                    )
+                return unpack_topn(host, n)
 
         rep = self._rep_q
         q_dev = jax.device_put(qp, rep)
         excl_dev = jax.device_put(excl, rep)
         incl_dev = jax.device_put(incl, rep)
         has_dev = jax.device_put(has_incl, rep)
+        cat_args = (
+            self._codes_dev, jax.device_put(cats, rep),
+            jax.device_put(has_cat, rep), jax.device_put(row_norm, rep),
+        )
         n_local = min(n_dev, self._n_pad // self._n_shards)
         shortlist = (
             None if self.precision == "float32"
@@ -958,27 +1219,31 @@ class ItemRetriever:
         split = self._batches % _SPLIT_SAMPLE_EVERY == 1
         exec_key = (
             n_local, positive_only, normalize, b_pad,
-            excl.shape[1], incl.shape[1], shortlist, self.precision,
+            excl.shape[1], incl.shape[1], cats.shape[1], shortlist,
+            self.precision,
         )
         if shortlist is None:
             args = (
                 q_dev, self._y_dev, self._rn_dev, self._allow_dev,
-                excl_dev, incl_dev, has_dev,
+                excl_dev, incl_dev, has_dev, *cat_args,
             )
         else:
             args = (
                 q_dev, self._y_dev, self._scale_operand, self._rn_dev,
-                self._allow_dev, excl_dev, incl_dev, has_dev,
+                self._allow_dev, excl_dev, incl_dev, has_dev, *cat_args,
             )
         t0 = time.perf_counter()
-        with _cc.track_compile("retrieval-stage1", self._exec_seen, exec_key):
+        with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
+            "retrieval-stage1", self._exec_seen, exec_key
+        ):
             cand = stage1(*args)
         if split:
             jax.block_until_ready(cand)
             t1 = time.perf_counter()
             _m_shard_seconds().observe(t1 - t0)
         packed = _merge_candidates(cand, n_dev, n_local, self._rep_out)
-        host = np.asarray(packed)[:b]
+        with _tracing.stage(_tracing.DEVICE_WAIT):
+            host = np.asarray(packed)[:b]
         if split:
             _m_merge_seconds().observe(time.perf_counter() - t1)
             # sampled skew: the candidate buffer is already synced (the
@@ -987,7 +1252,8 @@ class ItemRetriever:
             self._record_skew(np.asarray(cand)[:b], host, n_dev, n_local)
         if self.precision != "float32":
             return self._refine_exact(
-                q, host, n_dev, n, positive_only, normalize
+                q, host, n_dev, n, positive_only,
+                row_norm[:b] if normalize == "rows" else normalize,
             )
         return unpack_topn(host, n)
 
@@ -998,7 +1264,7 @@ class ItemRetriever:
         n_dev: int,
         n: int,
         positive_only: bool,
-        normalize: bool,
+        normalize,
     ) -> Tuple[np.ndarray, np.ndarray]:
         """Final exact rescore of the device's merged c·n candidates
         against the ORIGINAL float32 rows (host RAM — the engines keep
@@ -1011,7 +1277,11 @@ class ItemRetriever:
         sc = np.einsum(
             "bk,bnk->bn", q, rows, optimize=True
         ).astype(np.float32)
-        if normalize:
+        if isinstance(normalize, np.ndarray):  # per-row cosine flags
+            sc = sc * np.where(
+                normalize[:, None], self._rn_f32_host[i_d], np.float32(1.0)
+            )
+        elif normalize:
             sc = sc * self._rn_f32_host[i_d]
         if positive_only:
             sc = np.where(sc > 0, sc, -np.inf)
@@ -1103,6 +1373,10 @@ class ItemRetriever:
                     P(None, None),  # excl (global ids, replicated)
                     P(None, None),  # incl
                     P(None,),       # has_incl
+                    P(axis, None),  # per-item category codes
+                    P(None, None),  # the queries' category codes
+                    P(None,),       # has_cat
+                    P(None,),       # row_norm
                 )
             else:
                 kernel = functools.partial(
@@ -1121,6 +1395,10 @@ class ItemRetriever:
                     P(None, None),  # excl (global ids, replicated)
                     P(None, None),  # incl
                     P(None,),       # has_incl
+                    P(axis, None),  # per-item category codes
+                    P(None, None),  # the queries' category codes
+                    P(None,),       # has_cat
+                    P(None,),       # row_norm
                 )
             fn = jax.jit(
                 jax.shard_map(
@@ -1136,6 +1414,23 @@ class ItemRetriever:
             self._stage1_cache[key] = fn
         return fn
 
+    def warm_tiers(self, n: int) -> List[int]:
+        """The top-k widths ``warm(n=n)`` compiles: 16 doubling to ``n``,
+        clamped to the catalog."""
+        tiers, w = [], 16
+        while True:
+            tiers.append(min(w, self.n_items))
+            if w >= min(n, self.n_items):
+                return sorted(set(tiers))
+            w *= 2
+
+    def ladder_size(self, tiers: int = 1, flags: int = 1) -> int:
+        """How many executables the closed ladder holds."""
+        return (
+            len(self._batch_ladder or ()) * len(self._exclude_ladder or (1,))
+            * len(self._include_ladder or (1,)) * tiers * flags
+        )
+
     def free(self) -> None:
         """Drop the device-resident buffers (factors, norms, mask) and
         the compiled stage cache. Owner contract (the engines'
@@ -1150,6 +1445,8 @@ class ItemRetriever:
         self._scale_dev = None
         self._rn_dev = None
         self._allow_dev = None
+        self._codes_dev = None
+        self._factors = None
         self._y_f32_host = None
         self._rn_f32_host = None
         if self.mesh is not None:
@@ -1186,33 +1483,38 @@ class ItemRetriever:
         its derived stage-1 shortlist width — so the whole
         precision x shortlist combination space this instance can
         serve compiles here, never inside the first live batch that
-        asks for a wider ``num``."""
+        asks for a wider ``num``.
+
+        A retriever built with ladders (``max_batch``,
+        ``exclude_ladder``, ``include_ladder``) compiles their whole
+        product instead, for every tier and flag combination: the
+        executable space is then closed, and ``max_batch`` and
+        ``exclude_widths`` are not read. A flag pair's ``normalize``
+        may be ``"rows"`` (per-row cosine flags)."""
         k = self.rank
-        tiers: List[int] = []
-        w = 16
-        while True:
-            tiers.append(min(w, self.n_items))
-            if w >= min(n, self.n_items):
-                break
-            w *= 2
-        for nn in sorted(set(tiers)):
+        batches = self._batch_ladder or _batch_sizes(max_batch)
+        for nn in self.warm_tiers(n):
             for positive_only, normalize in flag_combos:
-                for ew in exclude_widths:
-                    excl_row = np.zeros(ew, np.int64) if ew > 1 else None
-                    b = 8
-                    while True:
-                        self.topn(
-                            np.zeros((b, k), np.float32), nn,
-                            exclude=(
-                                [excl_row] * b
-                                if excl_row is not None else None
-                            ),
-                            positive_only=positive_only,
-                            normalize=normalize,
-                        )
-                        if b >= max_batch:
-                            break
-                        b *= 2
+                for b in batches:
+                    rows = (
+                        np.zeros(b, bool) if normalize == "rows"
+                        else normalize
+                    )
+                    for ew in self._exclude_ladder or exclude_widths:
+                        for iw in self._include_ladder or (1,):
+                            self.topn(
+                                np.zeros((b, k), np.float32), nn,
+                                exclude=(
+                                    [np.zeros(ew, np.int64)] * b
+                                    if ew > 1 else None
+                                ),
+                                include=(
+                                    [np.zeros(iw, np.int64)] * b
+                                    if iw > 1 else None
+                                ),
+                                positive_only=positive_only,
+                                normalize=rows,
+                            )
 
 
 def naive_topn_reference(

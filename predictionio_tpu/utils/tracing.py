@@ -172,7 +172,12 @@ HOST_PREP = "host_prep"
 DISPATCH = "dispatch"
 DEVICE_WAIT = "device_wait"
 BUILD = "build"
-BATCH_STAGES = (HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD)
+# carved out of host prep by engines that read the event store and
+# assemble candidacy lists a batch (models/ecommerce): every store read
+# of the batch, and the id lookups, list assembly and pad
+STORE_READ = "store_read"
+MASK_PREP = "mask_prep"
+BATCH_STAGES = (HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD, STORE_READ, MASK_PREP)
 
 # the per-batch accumulator of stage() durations, bound by the engine
 # server's executor for the length of one serve_batch

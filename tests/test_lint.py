@@ -819,6 +819,10 @@ DEVICE_RESIDENCY_ALLOWED = {
     ("ops/retrieval.py", "self._rn_dev = jax.device_put(rn, NamedSharding(mesh, P(axis)))"),
     ("ops/retrieval.py", "self._allow_dev = jax.device_put("),
     ("ops/retrieval.py", "self._allow_dev = ("),
+    # the per-item category codes: resident beside the mask and
+    # counted in the same _ledger_mask handle (device_footprint of both)
+    ("ops/retrieval.py", "self._codes_dev = put(codes)"),
+    ("ops/retrieval.py", "self._codes_dev = jax.device_put("),
     # ServingFactors.__init__: covered by the serving-factors handle
     # with the anchor finalizer (release is refcount-driven)
     ("ops/als.py", "self._uf_dev = jax.device_put("),

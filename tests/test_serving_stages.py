@@ -58,11 +58,65 @@ def _reco(storage):
     return recommendation_engine(), {"user": "u3", "num": 4}
 
 
-ENGINES = {"fake": _fake, "reco": _reco}
+def _ecom(storage):
+    from predictionio_tpu.data.event import DataMap, Event
+    from predictionio_tpu.data.storage.base import App
+    from predictionio_tpu.models.ecommerce.engine import ecommerce_engine
+
+    app_id = storage.get_meta_data_apps().insert(App(id=0, name="shop"))
+    events = storage.get_l_events()
+    events.init(app_id)
+    t0 = dt.datetime(2026, 9, 1, tzinfo=dt.timezone.utc)
+
+    def put(event, etype, eid, target=None, props=None):
+        events.insert(Event(
+            event=event, entity_type=etype, entity_id=eid,
+            target_entity_type="item" if target else None,
+            target_entity_id=target, properties=DataMap(props or {}),
+            event_time=t0), app_id)
+
+    for j in range(12):
+        put("$set", "item", f"i{j}", props={"categories": [f"c{j % 3}"]})
+    for u in range(8):
+        put("$set", "user", f"u{u}")
+        for j in range(4):
+            put("rate", "user", f"u{u}", target=f"i{(u + 3 * j) % 12}",
+                props={"rating": float(1 + (u + j) % 5)})
+    engine = ecommerce_engine()
+    params = engine.jvalue_to_engine_params({
+        "datasource": {"params": {"app_name": "shop"}},
+        "algorithms": [{"name": "ecomm", "params": {
+            "app_name": "shop", "rank": 4, "num_iterations": 3, "seed": 1,
+            "unseen_only": True, "seen_events": ["rate"],
+            "exclude_widths": [16], "include_widths": [8],
+            "warm_max_batch": 8}}],
+    })
+    now = dt.datetime.now(dt.timezone.utc)
+    CoreWorkflow.run_train(
+        engine, params,
+        EngineInstance(
+            id="", status="", start_time=now, end_time=now,
+            engine_id="ecom", engine_version="1",
+            engine_variant="engine.json",
+            engine_factory=("predictionio_tpu.models.ecommerce.engine."
+                            "ECommerceEngineFactory"),
+        ),
+        ctx=WorkflowContext(mode="training", storage=storage),
+    )
+    return engine, {"user": "u3", "num": 4, "categories": ["c1", "c2"]}
+
+
+ENGINES = {"fake": _fake, "reco": _reco, "ecom": _ecom}
 # the stages each engine brackets: every engine's serve_batch enters
 # host_prep (supplement) and build (serving.serve); the recommendation
-# engine's float32 path names its dispatch and its blocking fetch too
-ENTERED = {"fake": (tr.HOST_PREP, tr.BUILD), "reco": BATCH_STAGES}
+# engine's float32 path names its dispatch and its blocking fetch too;
+# the e-commerce engine carves its store read and its list assembly
+# out of host prep
+ENTERED = {
+    "fake": (tr.HOST_PREP, tr.BUILD),
+    "reco": (tr.HOST_PREP, tr.DISPATCH, tr.DEVICE_WAIT, tr.BUILD),
+    "ecom": BATCH_STAGES,
+}
 
 
 @pytest.fixture(params=sorted(ENGINES))

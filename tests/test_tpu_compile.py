@@ -168,6 +168,10 @@ def _stage1_shapes(replicated, rows, rows_k):
         _shape((BATCH, 1), jnp.int32, replicated),
         _shape((BATCH, 1), jnp.int32, replicated),
         _shape((BATCH,), jnp.bool_, replicated),
+        _shape((N_ITEMS, 1), jnp.int32, rows_k),  # per-item category codes
+        _shape((BATCH, 1), jnp.int32, replicated),  # the queries' codes
+        _shape((BATCH,), jnp.bool_, replicated),  # has_cat
+        _shape((BATCH,), jnp.bool_, replicated),  # row_norm
     )
 
 
@@ -176,6 +180,29 @@ def test_int8_stage1_single_device_compiles(one_chip):
         *_stage1_shapes(one_chip, one_chip, one_chip),
         n=64, shortlist=64, positive_only=False, normalize=False,
         precision="int8",
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
+def test_ecommerce_fused_program_compiles_at_the_taobao_shape(one_chip):
+    """The float32 fused retrieval program of the e-commerce cell at its
+    widest warm corner: 4,162,024 x 512 resident, a batch of 32,
+    exclusion lists of 8,192, a whitelist of 1,024, four category codes,
+    per-row cosine flags. It has to fit one chip beside the table."""
+    n, k, b = 4_162_024, 512, 32
+    compiled = retrieval._fused_topn_single.lower(
+        _shape((b, k), jnp.float32, one_chip),
+        _shape((n, k), jnp.float32, one_chip),
+        _shape((n,), jnp.float32, one_chip),
+        _shape((n,), jnp.bool_, one_chip),
+        _shape((b, 8192), jnp.int32, one_chip),
+        _shape((b, 1024), jnp.int32, one_chip),
+        _shape((b,), jnp.bool_, one_chip),
+        _shape((n, 1), jnp.int32, one_chip),
+        _shape((b, 4), jnp.int32, one_chip),
+        _shape((b,), jnp.bool_, one_chip),
+        _shape((b,), jnp.bool_, one_chip),
+        n=16, positive_only=True, normalize="rows",
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
 
@@ -194,6 +221,7 @@ def test_int8_stage1_shards_over_four_chips(mesh4):
             in_specs=(
                 P(None, None), P("data", None), P("data"), P("data"),
                 P("data"), P(None, None), P(None, None), P(None),
+                P("data", None), P(None, None), P(None), P(None),
             ),
             out_specs=P(None, "data"),
             check_vma=False,
