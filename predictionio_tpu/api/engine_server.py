@@ -28,7 +28,11 @@ batched device predict (`BaseAlgorithm.batch_predict`, e.g. a single
 [B, k] x [k, n_items] MXU matmul + top_k for the recommendation engine)
 as soon as a slot frees, so the batch grows with the load by itself and
 throughput scales with batch size instead of request count. No timer
-and no window: an idle server adds no wait.
+and no window: an idle server adds no wait. What an algorithm declares
+to depend on one query alone (``BaseAlgorithm.prepare_query``: the
+e-commerce engine's read of the user's history) starts when the query
+arrives, on a small pool beside the batch ahead, and not in the serial
+path of the query's own batch.
 """
 
 from __future__ import annotations
@@ -130,6 +134,9 @@ class ServerConfig:
     # lazily-built index) is legal under that API and would silently race
     # at depth 2. The packaged templates are pure: deploy them with
     # `--pipeline-depth 2` to overlap device dispatch with result fetch.
+    # (An algorithm that defines `prepare_query` has THAT step, and the
+    # serving's `supplement` before it, run beside serve_batch at any
+    # depth: defining it is the opt-in, BaseAlgorithm's contract.)
     pipeline_depth: int = 1
     # REST transport: "async" = the event-loop frontend (api/aio_http.py,
     # in-flight queries are queue entries awaited as futures — the
@@ -251,6 +258,14 @@ class DeployedEngine:
         self._inflight = 0
         self._inflight_cond = threading.Condition()
         self._released = False
+        # which algorithms define the per-query preparation step
+        # (BaseAlgorithm.prepare_query): what the executor reads to
+        # decide whether a query of this engine is prepared at arrival
+        self._prepares = tuple(
+            callable(getattr(algo, "prepare_query", None))
+            for algo in self.algorithms
+        )
+        self.prepares_queries = any(self._prepares)
 
     @classmethod
     def from_storage(
@@ -322,10 +337,36 @@ class DeployedEngine:
 
     # --- the serving pipeline over one coalesced batch ---
 
-    def serve_batch(self, queries: Sequence[Any]) -> List[Any]:
+    def prepare_query(self, query: Any) -> Tuple[Any, tuple]:
+        """One query's preparation, for an engine with an algorithm that
+        defines the step: ``(supplemented query, one prepared value an
+        algorithm)``, None for an algorithm without the step. It depends
+        on the query alone and touches no device state, so the executor
+        runs it on its prepare pool when the query arrives, beside
+        whatever batch is being served."""
+        supplemented = self.serving.supplement(query)
+        return supplemented, tuple(
+            algo.prepare_query(model, supplemented) if prepares else None
+            for algo, model, prepares in zip(
+                self.algorithms, self.models, self._prepares
+            )
+        )
+
+    def serve_batch(
+        self, queries: Sequence[Any], prepared: Optional[Sequence] = None
+    ) -> List[Any]:
         """supplement each -> ONE batch_predict per algorithm -> serve each
         with its original query (reference Engine.scala:769-810 eval path
         applies the same supplement/batch/serve order).
+
+        ``prepared`` is what the executor hands an engine that prepares
+        its queries at arrival: ``prepare_query``'s result for each
+        query, in the batch's order. Those queries were supplemented
+        there and are not supplemented again; each algorithm that
+        defines the step gets its values as ``batch_predict``'s third
+        argument. Without it (an engine with no such step, ``pio
+        replay``, a test) the path is the one above, and an algorithm
+        with the step prepares inline.
 
         May be called concurrently (up to ServerConfig.pipeline_depth
         batches in flight): algorithms/serving with mutable predict-time
@@ -334,11 +375,24 @@ class DeployedEngine:
             self._inflight += 1
         try:
             with _tracing.stage(_tracing.HOST_PREP):
-                supplemented = [self.serving.supplement(q) for q in queries]
+                if prepared is None:
+                    supplemented = [
+                        self.serving.supplement(q) for q in queries
+                    ]
+                else:
+                    supplemented = [p[0] for p in prepared]
                 indexed = list(enumerate(supplemented))
             per_algo: List[Dict[int, Any]] = [
-                dict(algo.batch_predict(model, indexed))
-                for algo, model in zip(self.algorithms, self.models)
+                dict(
+                    algo.batch_predict(
+                        model, indexed, [p[1][k] for p in prepared]
+                    )
+                    if prepared is not None and self._prepares[k]
+                    else algo.batch_predict(model, indexed)
+                )
+                for k, (algo, model) in enumerate(
+                    zip(self.algorithms, self.models)
+                )
             ]
             with _tracing.stage(_tracing.BUILD):
                 return [
@@ -492,6 +546,114 @@ class _StageTimes:
         )
 
 
+class _Preparations:
+    """Per-query preparation at arrival, for deployed engines whose
+    algorithm defines ``prepare_query`` (BaseAlgorithm): the part of a
+    query's work that depends on that query alone starts when the query
+    is enqueued, on this pool, while the serve thread is still inside
+    the batch ahead (mostly blocked on the device, the interpreter lock
+    released), instead of in the serial path of the query's own batch,
+    where every queued request waits for it too. An executor creates
+    one with the first such query; engines without the step never do.
+
+    The pool's size is fixed: a preparation is a store read of about a
+    millisecond, arrivals are spread over the batch ahead, and whatever
+    has not started when its batch does is taken over by the serve
+    thread, so a small pool bounds the Python that competes with the
+    serve thread for the interpreter lock and nothing waits on it."""
+
+    WORKERS = 2
+
+    def __init__(self):
+        self._pool = concurrent.futures.ThreadPoolExecutor(
+            max_workers=self.WORKERS, thread_name_prefix="prepare"
+        )
+        registry = _metrics.get_registry()
+        self._families = {
+            "seconds": registry.histogram(
+                "pio_serving_prepare_seconds",
+                "One query's preparation (supplement and the "
+                "algorithms' prepare_query), wherever it ran: on the "
+                "prepare pool when the query arrived or on the serve "
+                "thread inside its batch, by model version",
+                labels=("version",),
+                buckets=_metrics.LATENCY_BUCKETS_S,
+            ),
+            "late": registry.counter(
+                "pio_serving_prepare_late_total",
+                "Queries whose preparation was not finished when their "
+                "micro-batch started: waited for, taken over by the "
+                "serve thread or computed again after an error, by "
+                "model version",
+                labels=("version",),
+            ),
+        }
+        self._children: Dict[str, Dict[str, Any]] = {}
+
+    def start(
+        self, deployed: DeployedEngine, query: Any
+    ) -> "concurrent.futures.Future":
+        return self._pool.submit(self._run, deployed, query)
+
+    def _run(self, deployed: DeployedEngine, query: Any) -> Any:
+        t0 = time.perf_counter()
+        try:
+            with _tracing.annotation("prepare"):
+                return deployed.prepare_query(query)
+        finally:
+            _version_children(
+                self._children, self._families, _version_of(deployed)
+            )["seconds"].observe(time.perf_counter() - t0)
+
+    def collect(
+        self, dep: DeployedEngine, items, outcomes: List[tuple]
+    ) -> Tuple[list, list]:
+        """On the serve thread, as a batch starts: its prepared values
+        in its order. A preparation still queued is cancelled and
+        computed here; one that is running is waited for (charged to
+        the batch's store-read stage: a preparation is a read before
+        anything else); one that raised is logged and computed again
+        here. A query whose preparation raises here too gets that error
+        as its outcome and leaves the batch: returns the items that
+        stay and their values."""
+        kept, values, late = [], [], 0
+        pendings = [item[4] for item in items]
+        ready = [pending.done() for pending in pendings]
+        # entered once a batch, so that the family holds one sample a
+        # served batch whether or not the batch had to wait
+        with _tracing.stage(_tracing.STORE_READ):
+            running = [p for p in pendings if not (p.done() or p.cancel())]
+            if running:
+                concurrent.futures.wait(running)
+        for item, pending, on_time in zip(items, pendings, ready):
+            value = None
+            if not pending.cancelled():
+                try:
+                    value = pending.result()
+                except Exception:
+                    logger.exception(
+                        "preparing query %r failed; computing it again "
+                        "inside its batch", item[1],
+                    )
+            late += not (on_time and value is not None)
+            if value is None:
+                try:
+                    value = self._run(dep, item[1])
+                except Exception as e:
+                    outcomes.append((item[2], e, None))
+                    continue
+            kept.append(item)
+            values.append(value)
+        if late:
+            _version_children(
+                self._children, self._families, _version_of(dep)
+            )["late"].inc(late)
+        return kept, values
+
+    def close(self) -> None:
+        self._pool.shutdown(wait=False, cancel_futures=True)
+
+
 class _BatchingExecutor:
     """Coalesces concurrent requests into device-sized batches.
 
@@ -508,6 +670,18 @@ class _BatchingExecutor:
     thread, and the collector can actually accumulate ``max_batch``-
     sized device batches under load. ``submit`` is the blocking wrapper
     the threaded transport (and in-process callers) use.
+
+    Where a deployed engine's algorithm defines ``prepare_query``
+    (``DeployedEngine.prepares_queries``), ``submit_nowait`` also starts
+    that query's preparation at once on the ``prepare`` pool
+    (``_Preparations``), the queue entry carries the pending result, and
+    the serve thread hands ``serve_batch`` the batch's values in its
+    order: what depends on one query alone runs while the query waits
+    in the queue, not in its batch's serial path. A preparation belongs
+    to the deployed engine it was started under (batches are grouped by
+    deployed engine); a cancelled request's is dropped. An engine
+    without the step is served by the code above alone: no pool, no
+    future, ``serve_batch(queries)``.
 
     The default depth is 1: strictly serial serving, the reference's
     contract (CreateServer.scala:473-624), safe for engines with mutable
@@ -532,6 +706,9 @@ class _BatchingExecutor:
         self._serve_pool = concurrent.futures.ThreadPoolExecutor(
             max_workers=self.pipeline_depth, thread_name_prefix="serve"
         )
+        # created with the first query of an engine that prepares its
+        # queries at arrival; None for ever with any other engine
+        self._preparations: Optional[_Preparations] = None
         # collector batch-size accounting (served-group granularity, the
         # actual device batch): proves micro-batches coalesce under load.
         # The instrument is the process-global registry's mergeable
@@ -612,7 +789,9 @@ class _BatchingExecutor:
         prediction (or raises its per-query error) once the micro-batch
         it rides is served. ``times`` rides the queue entry: the
         executor writes the request's stage boundaries into it, and the
-        caller reads them when the future resolves."""
+        caller reads them when the future resolves. For an engine that
+        prepares its queries at arrival the preparation starts here,
+        and the entry carries its pending result."""
         fut: "concurrent.futures.Future" = concurrent.futures.Future()
         if times is None:
             times = _StageTimes()
@@ -626,7 +805,12 @@ class _BatchingExecutor:
             if self._worker is None or not self._worker.is_alive():
                 self._worker = threading.Thread(target=self._run, daemon=True)
                 self._worker.start()
-            self._queue.put((deployed, query, fut, times))
+            pending = None
+            if getattr(deployed, "prepares_queries", False):
+                if self._preparations is None:
+                    self._preparations = _Preparations()
+                pending = self._preparations.start(deployed, query)
+            self._queue.put((deployed, query, fut, times, pending))
         return fut
 
     def submit(self, deployed: DeployedEngine, query: Any) -> Any:
@@ -689,6 +873,8 @@ class _BatchingExecutor:
         # blocks interpreter exit — same as the reference's in-flight
         # Futures on undeploy.
         self._serve_pool.shutdown(wait=False)
+        if self._preparations is not None:
+            self._preparations.close()
 
     def _run(self) -> None:
         while True:
@@ -727,10 +913,13 @@ class _BatchingExecutor:
                 # a future the transport cancelled (client gone before
                 # its batch formed) is dropped here; marking the rest
                 # RUNNING pins them against late cancellation
-                items = [
-                    it for it in items
-                    if it[2].set_running_or_notify_cancel()
-                ]
+                live = []
+                for it in items:
+                    if it[2].set_running_or_notify_cancel():
+                        live.append(it)
+                    elif it[4] is not None:
+                        it[4].cancel()  # nobody waits for its value
+                items = live
                 if not items:
                     continue
                 if not slot_held:
@@ -763,8 +952,8 @@ class _BatchingExecutor:
                     # in flight): fail these futures instead of leaving
                     # their waiters pending forever
                     self._inflight.release()
-                    for _, _, f, _ in items:
-                        f.set_exception(
+                    for it in items:
+                        it[2].set_exception(
                             RuntimeError(f"server is shutting down: {e}")
                         )
             if slot_held:
@@ -781,7 +970,7 @@ class _BatchingExecutor:
         # chains into the request's trace tree, and under a fresh
         # accumulator of the engine's stage() durations
         batch_trace = next(
-            (t.trace for _, _, _, t in items if t.trace is not None), None
+            (it[3].trace for it in items if it[3].trace is not None), None
         )
         compile_events: List[dict] = []
         stage_s: Dict[str, float] = {}
@@ -792,7 +981,16 @@ class _BatchingExecutor:
                     _tracing.stage_totals() as stage_s, \
                     _tracing.annotation("predict"):
                 try:
-                    self._serve_isolating(dep, items, outcomes)
+                    prepared = None
+                    serving = items
+                    if items[0][4] is not None:
+                        serving, prepared = self._preparations.collect(
+                            dep, items, outcomes
+                        )
+                    if serving:
+                        self._serve_isolating(
+                            dep, serving, outcomes, prepared
+                        )
                 finally:
                     served = time.perf_counter()
                     compile_events = _cc.drain_compile_events()
@@ -818,7 +1016,8 @@ class _BatchingExecutor:
                     }
                 if compile_events:
                     predict_attrs["cold_compiles"] = compile_events
-            for _, _, _, times in items:
+            for it in items:
+                times = it[3]
                 times.started, times.served = started, served
                 times.attrs = predict_attrs
             # the futures' callbacks (QueryAPI._finish_query: response
@@ -833,25 +1032,36 @@ class _BatchingExecutor:
                         f.set_result(result)
 
     def _serve_isolating(
-        self, dep: DeployedEngine, items, outcomes: List[tuple]
+        self, dep: DeployedEngine, items, outcomes: List[tuple],
+        prepared: Optional[list] = None,
     ) -> None:
         """Serve a batch; on failure bisect it so the poison query is
         located in O(log n) batched calls and its batchmates still get
         batched service (a serial per-query retry would multiply every
         innocent's latency by the batch size). Outcomes are collected as
         (future, exception, result) rather than resolved here so the
-        caller controls when waiters wake."""
+        caller controls when waiters wake. ``prepared`` is the items'
+        prepared values where their engine prepares queries at arrival
+        (halved with them), else None: ``serve_batch`` is then called
+        with the queries alone."""
         try:
-            results = dep.serve_batch([q for _, q, _, _ in items])
-            for (_, _, f, _), r in zip(items, results):
-                outcomes.append((f, None, r))
+            queries = [it[1] for it in items]
+            results = (
+                dep.serve_batch(queries) if prepared is None
+                else dep.serve_batch(queries, prepared)
+            )
+            for it, r in zip(items, results):
+                outcomes.append((it[2], None, r))
         except Exception as e:
             if len(items) == 1:
                 outcomes.append((items[0][2], e, None))
                 return
             mid = len(items) // 2
-            self._serve_isolating(dep, items[:mid], outcomes)
-            self._serve_isolating(dep, items[mid:], outcomes)
+            for half in (slice(None, mid), slice(mid, None)):
+                self._serve_isolating(
+                    dep, items[half], outcomes,
+                    None if prepared is None else prepared[half],
+                )
 
 
 class QueryAPI:

@@ -172,6 +172,24 @@ class BaseAlgorithm(Controller, Generic[PD, M, Q, P]):
         with a vectorized device predict for the TPU fast path."""
         return [(i, self.predict(model, q)) for i, q in queries]
 
+    # An algorithm MAY define ``prepare_query(self, model, query)``: the
+    # part of a query's serving work that depends on that query alone
+    # (a read of the user's history, name -> id lookups), returning an
+    # opaque prepared value. The engine server then starts it when the
+    # query ARRIVES, on a pool thread of the executor, instead of when
+    # its micro-batch closes, and hands the values to ``batch_predict``
+    # as a third argument: ``batch_predict(model, queries, prepared)``,
+    # ``prepared[k]`` belonging to ``queries[k]``, ``None`` where there
+    # is none (and ``prepared`` itself None from callers that prepared
+    # nothing: ``pio eval``, ``predict``), which the algorithm then
+    # prepares inline by the same function. CONTRACT: the step (and the
+    # serving's ``supplement``, applied to the query before it) runs
+    # concurrently with ``batch_predict`` and with itself, whatever
+    # ``pipeline_depth`` is: it reads shared state and mutates none.
+    # No reference analog; an algorithm that does not define it is
+    # served exactly as before (no pool, ``batch_predict(model, queries)``).
+    prepare_query = None
+
     def prepare_serving(self, ctx, model: M) -> M:
         """Deploy-time hook between model resolution and warm-up
         (Engine.prepare_deploy calls it per algorithm): attach serving

@@ -20,7 +20,7 @@ from __future__ import annotations
 import dataclasses
 import logging
 import time
-from typing import Dict, List, Optional, Sequence, Set, Tuple
+from typing import Dict, List, NamedTuple, Optional, Sequence, Set, Tuple
 
 import numpy as np
 
@@ -58,7 +58,7 @@ def _m_store_events():
     return _metrics.get_registry().counter(
         "pio_ecom_store_events_read_total",
         "Events the e-commerce engine's query-time store reads returned "
-        "(seen and recent-view histories, one read a micro-batch)",
+        "(seen and recent-view histories, one read a query)",
     )
 
 
@@ -450,6 +450,25 @@ class ECommModel:
         return self._inv_item
 
 
+class PreparedQuery(NamedTuple):
+    """``ECommAlgorithm.prepare_query``'s value: what one query needs
+    of itself to ride a device batch. ``row`` is None for a user with
+    neither factors nor a recent item (the answer is empty)."""
+
+    row: Optional[np.ndarray]  # the user's factors, or the recents' sum
+    cosine: bool  # a user without factors: scored by cosine
+    exclude: np.ndarray  # ids of seen and blacklisted items
+    include: Optional[np.ndarray]  # whiteList ids
+    categories: Optional[np.ndarray]  # category codes
+    seen: Set[str]  # seen names, for the host path's own filter
+    on_host: bool  # a list, the categories or num over the warm ladder
+
+
+_NO_RECENT_ITEM = PreparedQuery(
+    None, True, np.zeros(0, np.int64), None, None, frozenset(), False
+)
+
+
 class ECommAlgorithm(BaseAlgorithm):
     """ALS + predict-time business rules (reference ALSAlgorithm.scala
     of the train-with-rate-event variant). Explicit by default; set
@@ -706,14 +725,18 @@ class ECommAlgorithm(BaseAlgorithm):
             return None
         return model.scorer.cosine_sum(model.scorer.normed[recent_idx])
 
-    def batch_predict(self, model, queries) -> List[Tuple[int, PredictedResult]]:
+    def batch_predict(
+        self, model, queries, prepared=None
+    ) -> List[Tuple[int, PredictedResult]]:
         """Known users score as ONE [B, k] x [k, n_items] matmul; unknown
         users fall back to the per-query similar-items path. The
         query-independent unavailableItems constraint reads once per batch.
         With a prepared serving state the whole batch routes through the
-        sharded on-device retrieval path instead."""
+        sharded on-device retrieval path instead, with ``prepared`` the
+        queries' ``prepare_query`` values where the engine server made
+        them at arrival."""
         if model._retriever is not None:
-            return self._batch_predict_device(model, queries)
+            return self._batch_predict_device(model, queries, prepared)
         unavailable = self._unavailable_items()
         known = [
             (qi, model.user_index[q.user])
@@ -739,138 +762,174 @@ class ECommAlgorithm(BaseAlgorithm):
 
     # --- the sharded on-device retrieval path (prepared serving state) ---
 
-    def _read_histories(self, users) -> Dict[str, list]:
-        """ONE pass over the event store for the whole micro-batch:
-        ``{user: [(event, item, time ms), ...]}``, newest first, over
-        the seen events (when ``unseen_only``) and the similar events.
-        Read when the batch is served and kept nowhere: an event the
-        Event Server acknowledged before the query was sent is in it."""
+    def _read_history(self, user: str) -> list:
+        """One user's ``[(event, item, time ms), ...]``, newest first,
+        over the seen events (when ``unseen_only``) and the similar
+        events: one read of the event store a query, made by
+        ``prepare_query`` after the query has arrived (on the engine
+        server's prepare pool, or inside the batch where nothing was
+        prepared) and kept nowhere. A query arrives after it was sent
+        and the read connection sees every committed write, so an event
+        the Event Server acknowledged before the query was sent is in
+        it."""
         p = self.params
         names = list(dict.fromkeys(
             (p.seen_events if p.unseen_only else ()) + p.similar_events
         ))
-        if not users or not names:
-            return {}
+        if not names:
+            return []
         try:
             got = LEventStore().find_by_entities(
                 app_name=p.app_name, entity_type="user",
-                entity_ids=users, event_names=names,
+                entity_ids=[user], event_names=names,
                 target_entity_type="item",
-            )
+            )[user]
         except Exception as e:
-            logger.error("Error when reading the batch's events: %s", e)
-            return {}
-        _m_store_events().inc(sum(len(v) for v in got.values()))
+            logger.error("Error when reading the user's events: %s", e)
+            return []
+        _m_store_events().inc(len(got))
         return got
 
+    def prepare_query(
+        self, model: ECommModel, query: Query
+    ) -> Optional[PreparedQuery]:
+        """What serving ``query`` on the device needs of the query
+        alone (BaseAlgorithm.prepare_query): whether the user has
+        factors, the one read of the user's history, seen and
+        blacklisted names -> the exclusion ids, whiteList -> ids,
+        categories -> codes, the summed normalised recent-view row of a
+        user without factors, and whether the lists fit the warm
+        ladder. The engine server runs it when the query arrives,
+        beside the batch ahead; ``_batch_predict_device`` runs it
+        inline for a query that comes without (``predict``, ``pio
+        eval``), so there is one code path. None when the model holds
+        no prepared serving state (the host path reads for itself)."""
+        retriever = model._retriever
+        if retriever is None:
+            return None
+        p = self.params
+        item_index = model.item_index
+        with _tracing.stage(_tracing.HOST_PREP):
+            user_idx = model.user_index.get(query.user)
+            known = user_idx is not None and bool(
+                np.any(model.user_factors[user_idx])
+            )
+        # a known user is read for the seen items alone; a user without
+        # factors also for the recent views
+        with _tracing.stage(_tracing.STORE_READ):
+            events = (
+                self._read_history(query.user)
+                if p.unseen_only or not known else ()
+            )
+        with _tracing.stage(_tracing.MASK_PREP):
+            seen = (
+                {t for ev, t, _ in events if ev in p.seen_events}
+                if p.unseen_only else set()
+            )
+            if known:
+                row = model.user_factors[user_idx]
+            else:
+                logger.info("no userFeature found for user %s", query.user)
+                recent = [
+                    t for ev, t, _ in events if ev in p.similar_events
+                ][:10]
+                recent_idx = [
+                    item_index[t] for t in recent if t in item_index
+                ]
+                if not recent_idx:
+                    return _NO_RECENT_ITEM
+                row = model.normed_rows(recent_idx).sum(axis=0)
+            exclude = np.asarray(
+                [item_index[i] for i in seen.union(query.black_list or ())
+                 if i in item_index],
+                np.int64,
+            )
+            include = None if query.white_list is None else np.asarray(
+                [item_index[i] for i in query.white_list
+                 if i in item_index],
+                np.int64,
+            )
+            categories = (
+                None if query.categories is None
+                else model.category_codes(query.categories)
+            )
+            on_host = query.num > max(16, p.warm_num) or not retriever.fits(
+                exclude=len(exclude),
+                include=0 if include is None else len(include),
+                categories=0 if categories is None else len(categories),
+            )
+        return PreparedQuery(
+            row, not known, exclude, include, categories, seen, on_host
+        )
+
     def _batch_predict_device(
-        self, model: ECommModel, queries
+        self, model: ECommModel, queries, prepared=None
     ) -> List[Tuple[int, PredictedResult]]:
-        """The serving hot path: one store read and one fused
-        score+mask+top_k run a micro-batch, exact-parity with the host
-        ``_finish`` path. Known users score raw dot products against
-        the resident factors; users without factors ride the same run
-        in cosine mode (per-row flags) with a summed-normalized-recents
-        query vector. Categories travel as a few codes, tested against
-        the resident per-item codes in the program; blackList, seen
-        items and whiteList as id lists on the warm ladder's widths. A
-        query over the ladder's top is answered on the host. The
-        unavailableItems set never reads the store here —
+        """The serving hot path: one fused score+mask+top_k run a
+        micro-batch, exact-parity with the host ``_finish`` path. What
+        depends on one query alone (its store read, its id lists) is
+        ``prepare_query``'s: ``prepared[k]`` is its value for
+        ``queries[k]`` where the engine server ran it at the query's
+        arrival, and a query without one is prepared here, inline, by
+        the same function. The batch then stacks the rows and pads the
+        lists to the warm ladder's widths (``ItemRetriever.topn``).
+        Known users score raw dot products against the resident
+        factors; users without factors ride the same run in cosine mode
+        (per-row flags) with a summed-normalized-recents query vector.
+        Categories travel as a few codes, tested against the resident
+        per-item codes in the program; blackList, seen items and
+        whiteList as id lists. A query over the ladder's top is
+        answered on the host. The unavailableItems set is the batch's,
+        not the query's, and never reads the store here:
         ``cache.get()`` is the TTL tick that drives the out-of-band
         mask refresh."""
-        p = self.params
         unavailable = model._constraints.get()
         retriever = model._retriever
-        seen_names, similar_names = set(p.seen_events), set(p.similar_events)
         out: List[Tuple[int, PredictedResult]] = []
-        with _tracing.stage(_tracing.HOST_PREP):
-            known: Dict[int, int] = {}
-            for qi, q in queries:
-                user_idx = model.user_index.get(q.user)
-                if user_idx is not None and np.any(
-                    model.user_factors[user_idx]
-                ):
-                    known[qi] = user_idx
-            # known users are read for their seen items alone; users
-            # without factors also for their recent views
-            readers = [
-                q.user for qi, q in queries
-                if p.unseen_only or qi not in known
-            ]
-        with _tracing.stage(_tracing.STORE_READ):
-            history = self._read_histories(readers)
         meta, rows, excl, incl, cats, cosine, on_host = (
             [], [], [], [], [], [], []
         )
+        if prepared is None:
+            prepared = [None] * len(queries)
+        values = [
+            pq or self.prepare_query(model, q)
+            for pq, (_, q) in zip(prepared, queries)
+        ]
         with _tracing.stage(_tracing.MASK_PREP):
-            item_index = model.item_index
-            for qi, q in queries:
-                events = history.get(q.user, ())
-                seen = (
-                    {t for ev, t, _ in events if ev in seen_names}
-                    if p.unseen_only else set()
-                )
-                if qi in known:
-                    row = model.user_factors[known[qi]]
-                else:
-                    logger.info("no userFeature found for user %s", q.user)
-                    recent = [
-                        t for ev, t, _ in events if ev in similar_names
-                    ][:10]
-                    recent_idx = [
-                        item_index[t] for t in recent if t in item_index
-                    ]
-                    if not recent_idx:
-                        out.append((qi, PredictedResult()))
-                        continue
-                    row = model.normed_rows(recent_idx).sum(axis=0)
+            for (qi, q), pq in zip(queries, values):
+                if pq.row is None:
+                    out.append((qi, PredictedResult()))
+                    continue
+                if pq.cosine:
                     _m_recent_queries().inc()
-                ex = np.asarray(
-                    [item_index[i] for i in seen.union(q.black_list or ())
-                     if i in item_index],
-                    np.int64,
-                )
-                inc = None if q.white_list is None else np.asarray(
-                    [item_index[i] for i in q.white_list
-                     if i in item_index],
-                    np.int64,
-                )
-                cc = (
-                    None if q.categories is None
-                    else model.category_codes(q.categories)
-                )
-                if q.num > max(16, p.warm_num) or not retriever.fits(
-                    exclude=len(ex),
-                    include=0 if inc is None else len(inc),
-                    categories=0 if cc is None else len(cc),
-                ):
-                    on_host.append((qi, q, row, qi not in known, seen, inc))
+                if pq.on_host:
+                    on_host.append((qi, q, pq))
                     continue
                 meta.append((qi, q))
-                rows.append(row)
-                excl.append(ex)
-                incl.append(inc)
-                cats.append(cc)
-                cosine.append(qi not in known)
+                rows.append(pq.row)
+                excl.append(pq.exclude)
+                incl.append(pq.include)
+                cats.append(pq.categories)
+                cosine.append(pq.cosine)
         top = retriever.max_batch
         for s in range(0, len(meta), top):  # a batch over the ladder's top
             out += self._retrieve_group(
                 model, meta[s:s + top], rows[s:s + top], excl[s:s + top],
                 incl[s:s + top], cats[s:s + top], cosine[s:s + top],
             )
-        for qi, q, row, is_cosine, seen, inc in on_host:
+        for qi, q, pq in on_host:
             _m_host_fallbacks().inc()
             # a whiteList's rows alone where there is one; cosine = dot
             # over the item's norm, from the retriever's own norms: no
             # normalized copy of the catalog, no pass over it a query
-            at = slice(None) if inc is None else np.unique(inc)
-            part = model.item_factors[at] @ row
-            if is_cosine:
+            at = slice(None) if pq.include is None else np.unique(pq.include)
+            part = model.item_factors[at] @ pq.row
+            if pq.cosine:
                 part *= retriever.reciprocal_norms[at]
             scores = np.zeros(retriever.n_items, np.float32)
             scores[at] = part
             out.append(
-                (qi, self._finish(model, q, scores, unavailable, seen))
+                (qi, self._finish(model, q, scores, unavailable, pq.seen))
             )
         empty = sum(1 for _, r in out if not r.item_scores)
         if empty:

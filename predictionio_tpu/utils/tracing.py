@@ -173,8 +173,13 @@ DISPATCH = "dispatch"
 DEVICE_WAIT = "device_wait"
 BUILD = "build"
 # carved out of host prep by engines that read the event store and
-# assemble candidacy lists a batch (models/ecommerce): every store read
-# of the batch, and the id lookups, list assembly and pad
+# assemble candidacy lists (models/ecommerce): the store reads and the
+# id lookups, list assembly and pad that the BATCH still spends serial
+# time on. Where a query is prepared at its arrival
+# (BaseAlgorithm.prepare_query), that is the residue: the serve
+# thread's wait for a preparation still running (store read: a
+# preparation is a read before anything else), a preparation it runs
+# inline, and the batch's own stacking and padding
 STORE_READ = "store_read"
 MASK_PREP = "mask_prep"
 BATCH_STAGES = (HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD, STORE_READ, MASK_PREP)

@@ -83,6 +83,7 @@ class World:
         self.n_items = n_items
         self.seen = {}  # user -> set of item names
         self.views = {}  # user -> [(ms, item)]
+        self.n_events = {}  # user -> view and buy events in the store
         self.unavailable = set()
         rng = np.random.default_rng(5)
         users = [f"u{j}" for j in range(N_USERS)] + ["visitor", "manyviews"]
@@ -120,6 +121,7 @@ class World:
         self.model = self.algo.prepare_serving(None, seeded_model(n_items))
 
     def note(self, user, item, kind, ms):
+        self.n_events[user] = self.n_events.get(user, 0) + 1
         self.seen.setdefault(user, set()).add(item)
         if kind == "view":
             self.views.setdefault(user, []).append((ms, item))
@@ -151,9 +153,9 @@ class World:
             category_names=m.category_names,
         )
 
-    def check(self, queries):
+    def check(self, queries, prepared=None):
         got = dict(self.algo.batch_predict(
-            self.model, list(enumerate(queries))))
+            self.model, list(enumerate(queries)), prepared))
         assert sorted(got) == list(range(len(queries)))
         for k, q in enumerate(queries):
             want = self.expected(q)
@@ -215,25 +217,162 @@ def test_device_path_matches_the_reference(world, case):
         assert any(int(n[1:]) % 7 != 0 for n in names) or len(names) < 16
 
 
-def test_a_batch_of_mixed_shapes_is_one_store_read_and_one_run(world):
+class _CountedReads:
+    """The store's find_by_entities, counting the entities of each call
+    while the block runs."""
+
+    def __init__(self, world):
+        self.store = type(world.events)
+        self.calls = []
+
+    def __enter__(self):
+        real = self.real = self.store.find_by_entities
+        calls = self.calls
+
+        def counting(self, *a, **kw):
+            calls.append(len(kw["entity_ids"]))
+            return real(self, *a, **kw)
+
+        self.store.find_by_entities = counting
+        return calls
+
+    def __exit__(self, *exc):
+        self.store.find_by_entities = self.real
+
+
+def _events_read() -> float:
+    return _metrics.get_registry().counter(
+        "pio_ecom_store_events_read_total", "").labels().value
+
+
+@pytest.mark.parametrize("prepared", [False, True])
+def test_a_batch_of_mixed_shapes_is_one_store_read_a_query_and_one_run(
+    world, prepared
+):
+    """Every query reads its own user's history, once: inside the batch
+    where nothing was prepared, before it where the values come with
+    the queries (the batch then reads nothing)."""
     queries = [CASES[k] for k in sorted(CASES)][:16]
-    reads = []
-    store = type(world.events)
-    real = store.find_by_entities
-
-    def counting(self, *a, **kw):
-        reads.append(len(kw["entity_ids"]))
-        return real(self, *a, **kw)
-
-    store.find_by_entities = counting
+    values = None
+    events0 = _events_read()
+    with _CountedReads(world) as before_the_batch:
+        if prepared:
+            values = [world.algo.prepare_query(world.model, q)
+                      for q in queries]
+    events_prepared = _events_read() - events0
     runs0 = retrieval._m_shard_seconds().labels().count
-    try:
-        world.check(queries)
-    finally:
-        store.find_by_entities = real
-    assert len(reads) == 1 and reads[0] >= 12
+    with _CountedReads(world) as in_the_batch:
+        world.check(queries, values)
+    assert before_the_batch + in_the_batch == [1] * 16
+    assert (in_the_batch == []) == prepared
+    # the same events whichever side of the batch they were read on
+    assert _events_read() - events0 == sum(
+        world.n_events.get(q.user, 0) for q in queries)
+    assert (events_prepared > 0) == prepared
     # known users and recent-view users rode ONE fused program run
     assert retrieval._m_shard_seconds().labels().count - runs0 == 1
+
+
+PREPARED_CASES = {
+    "plain": CASES["plain"],
+    "category": CASES["category"],
+    "black_list": CASES["black_list"],
+    "white_list": CASES["white_list"],
+    "visitor_cosine": CASES["unknown_user_recent_views"],
+    "no_recent_item": CASES["unknown_user_no_views"],
+    "over_the_ladder": Query(
+        user="u22", num=5,
+        black_list=tuple(f"i{j}" for j in range(100, 200))),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREPARED_CASES))
+def test_answers_with_prepared_values_equal_answers_prepared_inline(
+    world, case
+):
+    """One code path, two call sites: the value made when the query
+    arrives serves the answer the batch would have made for itself
+    (and the reference's)."""
+    q = PREPARED_CASES[case]
+    fallbacks = _metrics.get_registry().counter(
+        "pio_ecom_host_fallback_total", "").labels()
+    before = fallbacks.value
+    inline = world.check([q])[0]
+    value = world.algo.prepare_query(world.model, q)
+    with _CountedReads(world) as reads:
+        served = world.check([q], [value])[0]
+    assert served == inline and reads == []
+    assert value.on_host == (case == "over_the_ladder")
+    assert fallbacks.value - before == 2 * (case == "over_the_ladder")
+    assert (value.row is None) == (case == "no_recent_item")
+    assert value.cosine == (case in ("visitor_cosine", "no_recent_item"))
+
+
+def test_an_event_committed_before_the_query_is_enqueued_is_excluded(world):
+    """The configuration's first guarantee, through the executor: the
+    history is read when the query arrives (here while another engine's
+    batch holds the one serve slot), which is after it was sent, so a
+    view acknowledged before that is out of the answer; and the batch,
+    when its turn comes, reads nothing more."""
+    import threading
+    import types
+
+    from predictionio_tpu.api.engine_server import (
+        DeployedEngine, _BatchingExecutor,
+    )
+    from predictionio_tpu.controller.engine import EngineParams
+    from predictionio_tpu.models.ecommerce.engine import (
+        DataSourceParams, ecommerce_engine,
+    )
+
+    dep = DeployedEngine(
+        ecommerce_engine(),
+        EngineParams(
+            data_source_params=("", DataSourceParams(app_name="shop")),
+            algorithm_params_list=(("ecomm", world.algo.params),),
+        ),
+        types.SimpleNamespace(id="ecom-arrival"), [world.model],
+    )
+    assert dep.prepares_queries
+
+    class Holder:
+        entered, go = threading.Event(), threading.Event()
+
+        def serve_batch(self, queries):
+            self.entered.set()
+            assert self.go.wait(10.0)
+            return list(queries)
+
+    q = Query(user="u26", num=5)
+    first = [s.item for s in world.check([q])[0].item_scores]
+    prepared = _metrics.get_registry().histogram(
+        "pio_serving_prepare_seconds", "", labels=("version",),
+        buckets=_metrics.LATENCY_BUCKETS_S).labels(version="ecom-arrival")
+    ex = _BatchingExecutor(max_batch=8)
+    try:
+        held = ex.submit_nowait(Holder(), "held")
+        assert Holder.entered.wait(10.0)
+        world.events.insert(Event(
+            event="view", entity_type="user", entity_id="u26",
+            target_entity_type="item", target_entity_id=first[0],
+            event_time=dt.datetime.now(dt.timezone.utc)), world.app_id)
+        world.note("u26", first[0], "view", int(time.time() * 1000))
+        with _CountedReads(world) as reads:
+            fut = ex.submit_nowait(dep, q)
+            deadline = time.time() + 10
+            while prepared.count < 1 and time.time() < deadline:
+                time.sleep(0.005)
+            assert reads == [1] and prepared.count == 1
+            Holder.go.set()
+            served = [s.item for s in fut.result(timeout=10).item_scores]
+            assert reads == [1]
+        assert held.result(timeout=10) == "held"
+    finally:
+        Holder.go.set()
+        ex.close()
+    assert first[0] not in served and served[:4] == first[1:5]
+    assert served == [
+        s.item for s in world.check([q])[0].item_scores]
 
 
 def test_a_constraint_change_is_honoured_after_the_ttl(world):

@@ -5,6 +5,7 @@ import itertools
 import json
 import threading
 import time
+import types
 import urllib.request
 
 import pytest
@@ -105,6 +106,77 @@ class TestDeploy:
         # both algorithms contribute: pd_id = ds(7) + offset(1) = 8
         assert results[0].models == ((1, 8), (2, 8))
         assert results[0].qx == 3 and results[1].qx == 4
+
+
+class _PreparingAlgo(fe.Algo0):
+    """Algo0 with the per-query preparation step: its value says which
+    model and which (supplemented) query it was made for, and the
+    prediction carries what batch_predict was handed."""
+
+    def prepare_query(self, model, query):
+        return ("prepared", model.algo_id, query.qx)
+
+    def batch_predict(self, model, queries, prepared=None):
+        if prepared is None:  # nothing came with the queries: inline
+            prepared = [self.prepare_query(model, q) for _, q in queries]
+        return [
+            (i, fe.Prediction(q.qx, models=(value,)))
+            for (i, q), value in zip(queries, prepared)
+        ]
+
+
+class TestPrepareStepOfADeployedEngine:
+    def _deployed(self, mem_storage, algo0):
+        fe.reset_counters()
+        train_instance(mem_storage)
+        engine = Engine(
+            data_source_classes=fe.DataSource0,
+            preparator_classes=fe.Preparator0,
+            algorithm_classes={"a0": algo0, "a1": fe.Algo1},
+            serving_classes=fe.SupplementServing,
+        )
+        return DeployedEngine.from_storage(engine, mem_storage)
+
+    @pytest.mark.parametrize("at_arrival", [True, False])
+    def test_values_reach_the_algorithm_that_defines_it(
+        self, mem_storage, at_arrival
+    ):
+        dep = self._deployed(mem_storage, _PreparingAlgo)
+        assert dep.prepares_queries
+        queries = [fe.Query(3), fe.Query(4)]
+        prepared = (
+            [dep.prepare_query(q) for q in queries] if at_arrival else None
+        )
+        if at_arrival:  # supplemented there: qx + 1000
+            assert prepared[0] == (fe.Query(1003), (("prepared", 1, 1003), None))
+        results = dep.serve_batch(queries, prepared)
+        # a0 was handed its own values, made for the query supplemented
+        # ONCE; a1, without the step, was called as it always was; each
+        # result is served with its original query
+        assert [r.models for r in results] == [
+            (("prepared", 1, 1000 + q.qx), (2, 8)) for q in queries
+        ]
+        assert [r.qx for r in results] == [3, 4]
+        assert all(r.supplemented for r in results)
+
+    def test_an_engine_without_the_step_does_not_prepare(self, mem_storage):
+        dep = self._deployed(mem_storage, fe.Algo0)
+        assert not dep.prepares_queries
+
+    def test_through_the_query_api(self, mem_storage):
+        """End to end: the server's own executor prepares at arrival and
+        answers with the prepared value."""
+        dep = self._deployed(mem_storage, _PreparingAlgo)
+        api = QueryAPI(dep, ServerConfig())
+        try:
+            status, body, _ = api.handle(
+                "POST", "/queries.json", {}, json.dumps({"qx": 5}).encode()
+            )
+            assert status == 200
+            assert tuple(body["models"][0]) == ("prepared", 1, 1005)
+            assert api._executor._preparations is not None
+        finally:
+            api.close()
 
 
 def after_submits(executor, n) -> threading.Event:
@@ -271,15 +343,78 @@ class _WatchedSlots:
         assert self._given_back.acquire(timeout=10.0)
 
 
-def _immediate_batches() -> float:
-    """pio_serving_batch_immediate_total over its versions, as a
-    /metrics reader sums it."""
+def _counter_total(name) -> float:
+    """A counter family over its versions, as a /metrics reader sums
+    it."""
     from predictionio_tpu.utils import metrics
 
     return metrics.counter_sum(
-        metrics.parse_exposition(metrics.get_registry().render()),
-        "pio_serving_batch_immediate_total",
+        metrics.parse_exposition(metrics.get_registry().render()), name
     )
+
+
+def _immediate_batches() -> float:
+    return _counter_total("pio_serving_batch_immediate_total")
+
+
+def _late_preparations() -> float:
+    return _counter_total("pio_serving_prepare_late_total")
+
+
+class _PreparingDep(_GateDep):
+    """A _GateDep whose engine defines the per-query preparation step:
+    it says on which thread each query was prepared and what each
+    serve_batch call was handed; a test can hold a query's preparation
+    (``hold``) or make it raise its next ``fail[query]`` times."""
+
+    prepares_queries = True
+
+    def __init__(self, name="A", let_go=0):
+        super().__init__(let_go)
+        self.name = name
+        self.engine_instance = types.SimpleNamespace(id=f"prep-{name}")
+        self.prepared_on = {}  # query -> the thread's name, each call
+        self.handed = []  # the prepared values of each serve_batch call
+        self.prepare_entered = threading.Semaphore(0)
+        self.prepare_left = threading.Semaphore(0)
+        self.hold = {}  # query -> the semaphore its preparation waits on
+        self.fail = {}  # query -> times its preparation still raises
+
+    def value(self, query):
+        return (f"supplemented:{query}", (f"{self.name}:{query}",))
+
+    def prepare_query(self, query):
+        with self._lock:
+            self.prepared_on.setdefault(query, []).append(
+                threading.current_thread().name
+            )
+            failing = self.fail.get(query, 0)
+            self.fail[query] = max(0, failing - 1)
+        self.prepare_entered.release()
+        try:
+            if query in self.hold:
+                assert self.hold[query].acquire(timeout=10.0)
+            if failing:
+                raise ValueError(f"cannot prepare {query}")
+            return self.value(query)
+        finally:
+            self.prepare_left.release()
+
+    def serve_batch(self, queries, prepared):
+        with self._lock:
+            self.handed.append(list(prepared))
+        return super().serve_batch(queries)
+
+    def await_preparations(self, n, left=True):
+        sem = self.prepare_left if left else self.prepare_entered
+        for _ in range(n):
+            assert sem.acquire(timeout=10.0)
+
+
+def _prepare_threads():
+    return {
+        t for t in threading.enumerate() if t.name.startswith("prepare")
+    }
 
 
 @pytest.mark.parametrize("depth", [1, 2])
@@ -366,16 +501,21 @@ class TestBatchClosesWhenASlotIsFree:
         finally:
             ex.close()
 
-    def test_stress_each_request_served_once_within_the_caps(self, depth):
+    @pytest.mark.parametrize("dep_class", [_GateDep, _PreparingDep])
+    def test_stress_each_request_served_once_within_the_caps(
+        self, depth, dep_class
+    ):
         """More submitting threads than cores at a shortened switch
         interval: every request is answered with its own result exactly
         once, no batch passes max_batch, and never more than ``depth``
-        serve_batch calls run at once."""
+        serve_batch calls run at once. With an engine that prepares its
+        queries at arrival, each query is prepared exactly once and its
+        own value reaches its place in its batch."""
         import sys
 
         from predictionio_tpu.api.engine_server import _BatchingExecutor
 
-        dep = _GateDep(let_go=1600)
+        dep = dep_class(let_go=1600)
         ex = _BatchingExecutor(max_batch=4, pipeline_depth=depth)
         interval = sys.getswitchinterval()
         sys.setswitchinterval(1e-5)
@@ -402,6 +542,161 @@ class TestBatchClosesWhenASlotIsFree:
         assert sorted(sum(dep.calls, [])) == sorted(answers)
         assert max(len(call) for call in dep.calls) <= 4
         assert dep.max_running <= depth
+        if dep_class is _PreparingDep:
+            assert all(len(on) == 1 for on in dep.prepared_on.values())
+            assert sorted(dep.prepared_on) == sorted(answers)
+            # calls and handed are appended under separate takes of the
+            # lock: pair them by content, not by position
+            assert sorted(
+                v for handed in dep.handed for v in handed
+            ) == sorted(dep.value(q) for q in answers)
+            by_first = {call[0]: call for call in dep.calls}
+            for handed in dep.handed:
+                first = int(handed[0][1][0].split(":")[1])
+                assert handed == [dep.value(q) for q in by_first[first]]
+
+
+class TestPreparationAtArrival:
+    """An engine whose algorithm defines prepare_query: the executor
+    starts each query's preparation when it is enqueued, on its prepare
+    pool, and hands serve_batch the values in the batch's order."""
+
+    def _held(self, *deps, max_batch=8):
+        """An executor whose one slot is held by the lone request "h"
+        of the first engine, prepared and inside serve_batch."""
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        ex = _BatchingExecutor(max_batch=max_batch)
+        holder = ex.submit_nowait(deps[0], "h")
+        deps[0].await_entry()
+        deps[0].await_preparations(1)
+        return ex, holder
+
+    def test_prepared_once_off_the_serve_thread_before_the_batch(self):
+        dep = _PreparingDep()
+        ex, holder = self._held(dep)
+        try:
+            late = _late_preparations()
+            futs = [ex.submit_nowait(dep, q) for q in "bcd"]
+            dep.await_preparations(3)
+            # all three are ready while the batch ahead still holds the
+            # slot: serve_batch has seen none of them
+            assert dep.calls == [["h"]]
+            for q in "bcd":
+                [thread] = dep.prepared_on[q]
+                assert thread.startswith("prepare")
+            dep.go.release()
+            dep.await_entry()
+            assert dep.calls[1] == ["b", "c", "d"]
+            assert dep.handed[1] == [dep.value(q) for q in "bcd"]
+            assert _late_preparations() == late
+            dep.go.release()
+            assert [f.result(timeout=10) for f in [holder] + futs] == list(
+                "hbcd"
+            )
+            assert all(len(on) == 1 for on in dep.prepared_on.values())
+        finally:
+            ex.close()
+
+    def test_one_not_started_is_taken_over_and_a_cancelled_one_dropped(self):
+        dep = _PreparingDep()
+        gate = threading.Semaphore(0)
+        dep.hold = {"b0": gate, "b1": gate}
+        ex, holder = self._held(dep)
+        try:
+            blockers = [ex.submit_nowait(dep, q) for q in ("b0", "b1")]
+            dep.await_preparations(2, left=False)  # both pool threads held
+            c = ex.submit_nowait(dep, "c")  # queued behind them
+            d = ex.submit_nowait(dep, "d")
+            assert all(f.cancel() for f in blockers + [d])
+            late = _late_preparations()
+            dep.go.release()
+            dep.await_entry()
+            # the batch is "c" alone, prepared by the serve thread itself
+            assert dep.calls[1] == ["c"] and dep.handed[1] == [dep.value("c")]
+            [thread] = dep.prepared_on["c"]
+            assert thread.startswith("serve")
+            assert _late_preparations() == late + 1
+            gate.release()
+            gate.release()
+            dep.await_preparations(3)  # b0, b1 and c
+            dep.go.release()
+            assert c.result(timeout=10) == "c"
+        finally:
+            ex.close()
+        # the cancelled requests' values went nowhere, and the one that
+        # had not started never ran
+        assert "d" not in dep.prepared_on
+        assert [v for handed in dep.handed for v in handed] == [
+            dep.value("h"), dep.value("c"),
+        ]
+
+    @pytest.mark.parametrize("fails", [1, 2])
+    def test_one_that_raises_is_computed_again_inside_its_batch(self, fails):
+        dep = _PreparingDep()
+        dep.fail["x"] = fails
+        ex, holder = self._held(dep)
+        try:
+            late = _late_preparations()
+            x, y = ex.submit_nowait(dep, "x"), ex.submit_nowait(dep, "y")
+            dep.await_preparations(2)
+            dep.go.release()
+            dep.await_entry()
+            assert [t[:5] for t in dep.prepared_on["x"]] == ["prepa", "serve"]
+            assert _late_preparations() == late + 1
+            dep.go.release()
+            assert y.result(timeout=10) == "y"
+            if fails == 1:  # answered as if nothing had happened
+                assert dep.handed[1] == [dep.value("x"), dep.value("y")]
+                assert x.result(timeout=10) == "x"
+            else:  # the error is the query's own; its batchmate is served
+                assert dep.handed[1] == [dep.value("y")]
+                with pytest.raises(ValueError, match="cannot prepare x"):
+                    x.result(timeout=10)
+        finally:
+            ex.close()
+
+    def test_a_batch_that_spans_a_reload_hands_each_engine_its_own(self):
+        old, new = _PreparingDep("old"), _PreparingDep("new", let_go=1)
+        ex, holder = self._held(old)
+        try:
+            futs = [
+                ex.submit_nowait(dep, q)
+                for dep, q in ((old, "a1"), (new, "b1"), (old, "a2"))
+            ]
+            old.await_preparations(2)
+            new.await_preparations(1)
+            old.go.release()
+            old.go.release()
+            assert [f.result(timeout=10) for f in futs] == ["a1", "b1", "a2"]
+            assert old.handed[1] == [old.value("a1"), old.value("a2")]
+            assert new.handed == [[new.value("b1")]]
+        finally:
+            ex.close()
+
+    @pytest.mark.parametrize("preparing", [True, False])
+    def test_the_pool_exists_with_the_step_alone_and_close_ends_it(
+        self, preparing
+    ):
+        """An engine that does not define the step is served by the code
+        it always was: no pool, no thread, serve_batch(queries) (the
+        plain _GateDep takes no second argument). With the step the
+        pool's threads end with close()."""
+        from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+        before = _prepare_threads()
+        dep = _PreparingDep(let_go=4) if preparing else _GateDep(let_go=4)
+        ex = _BatchingExecutor(max_batch=8)
+        try:
+            assert [ex.submit(dep, q) for q in "abc"] == list("abc")
+            started = _prepare_threads() - before
+            assert (ex._preparations is not None) == preparing
+            assert bool(started) == preparing
+        finally:
+            ex.close()
+        for t in started:
+            t.join(timeout=10.0)
+        assert not any(t.is_alive() for t in started)
 
 
 class TestSlotAccounting:
@@ -511,7 +806,7 @@ class TestSlotAccounting:
             futs = []
             for dep, q in ((old, "a"), (new, "b")):
                 futs.append(concurrent.futures.Future())
-                ex._queue.put((dep, q, futs[-1], _StageTimes()))
+                ex._queue.put((dep, q, futs[-1], _StageTimes(), None))
             ex._worker = threading.Thread(target=ex._run, daemon=True)
             ex._worker.start()
             old.await_entry()
