@@ -44,8 +44,10 @@ Metrics (utils/metrics.py conventions, visible in ``pio top``):
 ``pio_retrieval_shard_topk_seconds`` / ``pio_retrieval_merge_seconds``
 (every batch off-mesh; SAMPLED on the sharded path — the split needs a
 host sync), ``pio_retrieval_mask_refresh_total{component,outcome}``,
-``pio_retrieval_mask_age_seconds{component}``, and
-``pio_retrieval_resident_bytes{component}``.
+``pio_retrieval_mask_age_seconds{component}``,
+``pio_retrieval_resident_bytes{component}``, and
+``pio_retrieval_operand_transfers_total{component}`` (host-to-device
+transfers ``topn`` made: one a call).
 
 Device-observability round: the resident factors/norms and the
 candidacy mask register in the HBM residency ledger
@@ -182,6 +184,11 @@ def _ladder(widths) -> Optional[Tuple[int, ...]]:
     if not out:
         raise ValueError("a ladder needs at least one width")
     return out
+
+
+def _longest(lists) -> int:
+    """The length of the longest list (``None`` entries carry none)."""
+    return max((len(a) for a in lists if a is not None), default=0)
 
 
 # an item id splits into a high and a low digit, id = hi * _LO + lo
@@ -370,17 +377,88 @@ def include_candidates(
     return wl
 
 
+def _operand_slices(k: int, widths) -> List[slice]:
+    """Where each part of a batch's packed operand lies among its
+    columns: the query rows' ``k`` float32 bit patterns, the exclusion
+    block, the inclusion block, the category codes (``widths`` = their
+    three widths) and the flags has_incl, has_cat, row_norm. The wide
+    parts come first, so that at the serving widths (k 512, lists of
+    1,024 and 8,192) each starts on a multiple of 128 lanes."""
+    edges = np.cumsum((0, k) + tuple(widths) + (3,)).tolist()
+    return [slice(a, b) for a, b in zip(edges, edges[1:])]
+
+
+def _assemble_idx(lists, block, has=None) -> None:
+    """Per-query id lists into ``block``, a [b_pad, W] view of the
+    packed operand already filled with its padding value (W wide enough:
+    ``ItemRetriever._width``). ``has`` is the flag column of the
+    queries that carry a list at all (``None`` entries carry none; an
+    empty list is a list)."""
+    for r, a in enumerate(lists):
+        if a is None:
+            continue
+        if has is not None:
+            has[r] = 1
+        if len(a):
+            block[r, : len(a)] = a
+
+
+def _pack_operand(
+    q, b_pad: int, widths, sentinel: int,
+    exclude=(), include=(), categories=(), row_norm=(),
+) -> np.ndarray:
+    """Everything a batch sends to the device as ONE [b_pad, W] int32
+    buffer (``_operand_slices``), written in place into one allocation:
+    the float32 query rows as their bits, the id lists padded with
+    ``sentinel`` (n_pad: out of range on every shard and on the single
+    device, so the masks drop it), the category codes padded with -2
+    (an item without a category holds -1: padding never matches), the
+    three flags as 0/1. Floats travel as integer bits, never integers
+    as float bits (``_pack_topn`` says what the chip does to those).
+    One buffer is one ``device_put`` a batch where there were seven."""
+    b, k = q.shape
+    rows, excl, incl, cats, flags = _operand_slices(k, widths)
+    buf = np.empty((b_pad, flags.stop), np.int32)
+    bits = buf[:, rows].view(np.float32)
+    bits[:b] = q
+    bits[b:] = 0.0
+    buf[:, excl.start:incl.stop] = sentinel
+    buf[:, cats] = -2
+    buf[:, flags] = 0
+    _assemble_idx(exclude, buf[:, excl])
+    _assemble_idx(include, buf[:, incl], buf[:, flags.start])
+    _assemble_idx(categories, buf[:, cats], buf[:, flags.start + 1])
+    buf[: len(row_norm), flags.start + 2] = row_norm
+    return buf
+
+
+def _unpack_operand(packed, k: int, widths):
+    """The traced inverse of ``_pack_operand``: static slices, a bitcast
+    for the rows, ``!= 0`` for the flags. Returns q, excl, incl,
+    has_incl, cats, has_cat, row_norm."""
+    rows, excl, incl, cats, flags = _operand_slices(k, widths)
+    on = packed[:, flags] != 0
+    return (
+        jax.lax.bitcast_convert_type(packed[:, rows], jnp.float32),
+        packed[:, excl], packed[:, incl], on[:, 0],
+        packed[:, cats], on[:, 1], on[:, 2],
+    )
+
+
 @functools.partial(
-    jax.jit, static_argnames=("n", "positive_only", "normalize")
+    jax.jit, static_argnames=("n", "positive_only", "normalize", "widths")
 )
 def _fused_topn_single(
-    q, Y, rn, allow0, excl, incl, has_incl, codes, cats, has_cat, row_norm,
-    n, positive_only, normalize
+    packed, Y, rn, allow0, codes, n, positive_only, normalize, widths
 ):
-    """The single-device path as ONE program: matmul + optional cosine
-    scaling + mask scatter + top_k, no [B, N] score materialization on
-    host and no host post-filter (the pre-round-12 ecommerce predict
-    computed the full score row in numpy and masked it in Python)."""
+    """The single-device path as ONE program over ONE operand a batch
+    (``_pack_operand``): matmul + optional cosine scaling + masks +
+    top_k, no [B, N] score materialization on host and no host
+    post-filter (the pre-round-12 ecommerce predict computed the full
+    score row in numpy and masked it in Python)."""
+    q, excl, incl, has_incl, cats, has_cat, row_norm = _unpack_operand(
+        packed, Y.shape[1], widths
+    )
     scores = _scale_cosine(_exact_scores(q, Y), rn, row_norm, normalize)
     scores = _mask_scores(
         scores, allow0, excl, incl, has_incl, positive_only,
@@ -459,19 +537,22 @@ def _rescore_exact(
 @functools.partial(
     jax.jit,
     static_argnames=(
-        "n", "shortlist", "positive_only", "normalize", "precision"
+        "n", "shortlist", "positive_only", "normalize", "precision",
+        "widths",
     ),
 )
 def _fused_topn_single_2s(
-    q, Yq, scale, rn, allow0, excl, incl, has_incl,
-    codes, cats, has_cat, row_norm,
-    n, shortlist, positive_only, normalize, precision,
+    packed, Yq, scale, rn, allow0, codes,
+    n, shortlist, positive_only, normalize, precision, widths,
 ):
     """Quantized single-device path: BOTH stages in one program —
     approx score with the fused dequant-rescale epilogue + the same
-    mask scatter as the exact path + top-(c·n) shortlist, then the
-    exact-f32 rescore of just the shortlist rows and the final
+    packed operand and masks as the exact path + top-(c·n) shortlist,
+    then the exact-f32 rescore of just the shortlist rows and the final
     top_k."""
+    q, excl, incl, has_incl, cats, has_cat, row_norm = _unpack_operand(
+        packed, Yq.shape[1], widths
+    )
     approx = _scale_cosine(
         _approx_scores(q, Yq, scale, precision), rn, row_norm, normalize
     )
@@ -489,9 +570,9 @@ def _fused_topn_single_2s(
 
 
 def _shard_topk_kernel_2s(
-    q, Yq, scale, rn, allow0, excl, incl, has_incl,
-    codes, cats, has_cat, row_norm,
+    packed, Yq, scale, rn, allow0, codes,
     *, axis, n_local, shortlist, positive_only, normalize, precision,
+    widths,
 ):
     """Per-shard two-stage body (runs under shard_map): the quantized
     counterpart of ``_shard_topk_kernel`` — candidacy masks and the
@@ -499,6 +580,9 @@ def _shard_topk_kernel_2s(
     (quantized stage 1 + exact rescore of the top-(c·n_local)
     shortlist) differs. Emits packed top-n_local EXACT candidates with
     global ids, so the cross-shard merge is unchanged."""
+    q, excl, incl, has_incl, cats, has_cat, row_norm = _unpack_operand(
+        packed, Yq.shape[1], widths
+    )
     rows_l = Yq.shape[0]
     off = jax.lax.axis_index(axis).astype(jnp.int32) * rows_l
 
@@ -522,12 +606,16 @@ def _shard_topk_kernel_2s(
 
 
 def _shard_topk_kernel(
-    q, Y, rn, allow0, excl, incl, has_incl, codes, cats, has_cat, row_norm,
-    *, axis, n_local, positive_only, normalize,
+    packed, Y, rn, allow0, codes,
+    *, axis, n_local, positive_only, normalize, widths,
 ):
     """Per-shard body (runs under shard_map): local slice views of the
-    resident arrays, replicated query block, NO collective — each shard
-    emits its own packed top-n_local candidates with GLOBAL ids."""
+    resident arrays, the batch's packed operand replicated, NO
+    collective — each shard emits its own packed top-n_local candidates
+    with GLOBAL ids."""
+    q, excl, incl, has_incl, cats, has_cat, row_norm = _unpack_operand(
+        packed, Y.shape[1], widths
+    )
     rows_l = Y.shape[0]
     off = jax.lax.axis_index(axis).astype(jnp.int32) * rows_l
 
@@ -602,6 +690,16 @@ def _m_mask_refresh():
     )
 
 
+def _m_operand_transfers():
+    return _metrics.get_registry().counter(
+        "pio_retrieval_operand_transfers_total",
+        "Host-to-device transfers ItemRetriever.topn made for its "
+        "batches' operands (query rows, id lists, category codes and "
+        "flags travel as one packed buffer: one a call)",
+        labels=("component",),
+    )
+
+
 def _m_mask_age():
     return _metrics.get_registry().gauge(
         "pio_retrieval_mask_age_seconds",
@@ -669,8 +767,9 @@ class ItemRetriever:
 
     Upload-once semantics: construct at ``prepare_serving`` (the engine
     server's prepared-serving state owns the instance), after which each
-    query batch ships only [B, k] query rows plus the small per-query
-    id lists up, and one packed [B, 2n] buffer down.
+    query batch ships ONE packed buffer up (``_pack_operand``: its
+    [B, k] query rows, the per-query id lists, category codes and
+    flags) and one packed [B, 2n] buffer down.
 
     With a ``mesh`` the factor rows (and the norm/mask vectors) shard
     over ``axis`` and stay resident between queries; without one (or on
@@ -820,7 +919,9 @@ class ItemRetriever:
             self._rn_dev = put(rn)
             self._allow_dev = put(self._valid)
             self._codes_dev = put(codes)
-            self._rep_q = None
+            # where a batch's packed operand goes (None: the default
+            # device)
+            self._operand_at = device
         else:
             self._device = None
             self._y_dev = jax.device_put(
@@ -837,10 +938,10 @@ class ItemRetriever:
             self._codes_dev = jax.device_put(
                 codes, NamedSharding(mesh, P(axis, None))
             )
-            self._rep_q = NamedSharding(mesh, P())
             self._rep_out = NamedSharding(mesh, P(None, None))
-            # per-(n_local, flags, shortlist) jitted shard_map stage-1
-            # executables
+            self._operand_at = self._rep_out
+            # per-(n_local, flags, widths, shortlist) jitted shard_map
+            # stage-1 executables
             self._stage1_cache: Dict[tuple, object] = {}
         self._batches = 0
         self._freed = False
@@ -1023,31 +1124,11 @@ class ItemRetriever:
             f"{what} of {width} is over the ladder's top {ladder[-1]}"
         )
 
-    def _assemble_idx(
-        self, lists, b_pad: int, ladder=None, what: str = "id list"
-    ) -> Tuple[np.ndarray, np.ndarray]:
-        """Per-query id lists -> a sentinel-padded [b_pad, W] int32 block
-        (W the next power of two, or the next width of ``ladder``, so
-        executables bucket O(log) widths) plus the has-list flag vector.
-        The sentinel is n_pad: out of range on every shard and on the
-        single device, so the mask scatter drops it."""
-        has = np.zeros(b_pad, bool)
-        width = 1
-        rows: List[np.ndarray] = []
-        for a in lists:
-            if a is None:
-                rows.append(np.zeros(0, np.int64))
-                continue
-            a = np.asarray(a, np.int64)
-            rows.append(a)
-            width = max(width, len(a))
-        width = self._width(ladder, width, what)
-        out = np.full((b_pad, width), self._n_pad, np.int32)
-        for r, a in enumerate(rows):
-            if len(a):
-                out[r, : len(a)] = a
-            has[r] = lists[r] is not None
-        return out, has
+    def _upload(self, operand: np.ndarray):
+        """A batch's packed operand onto the device(s): the one
+        host-to-device transfer of a ``topn`` call, counted."""
+        _m_operand_transfers().labels(component=self.component).inc()
+        return jax.device_put(operand, self._operand_at)
 
     def topn(
         self,
@@ -1102,71 +1183,68 @@ class ItemRetriever:
         )
         with _tracing.stage(_tracing.MASK_PREP):
             b_pad = max(8, self._width(self._batch_ladder, b, "a batch"))
-            qp = np.zeros((b_pad, q.shape[1]), np.float32)
-            qp[:b] = q
-            excl, _ = self._assemble_idx(
-                list(exclude or []) + [None] * (b_pad - b), b_pad,
-                self._exclude_ladder, "an exclusion list",
+            exclude, include, categories = (
+                list(lists or ()) for lists in (exclude, include, categories)
             )
-            incl, has_incl = self._assemble_idx(
-                list(include or []) + [None] * (b_pad - b), b_pad,
-                self._include_ladder, "an inclusion list",
+            n_cats = _longest(categories)
+            if n_cats > self.category_width:
+                raise ValueError(
+                    f"{n_cats} categories in one query, the retriever "
+                    f"holds {self.category_width}"
+                )
+            # the widths an executable is compiled for: the smallest of
+            # the ladder (or the next power of two) that holds the
+            # batch's longest list; 1 stands for "no list"
+            widths = (
+                self._width(
+                    self._exclude_ladder, max(1, _longest(exclude)),
+                    "an exclusion list",
+                ),
+                self._width(
+                    self._include_ladder, max(1, _longest(include)),
+                    "an inclusion list",
+                ),
+                self.category_width,
             )
-            cats = np.full((b_pad, self.category_width), -2, np.int32)
-            has_cat = np.zeros(b_pad, bool)
-            for r, c in enumerate(categories or ()):
-                if c is not None:
-                    if len(c) > self.category_width:
-                        raise ValueError(
-                            f"{len(c)} categories in one query, the "
-                            f"retriever holds {self.category_width}"
-                        )
-                    cats[r, : len(c)] = c
-                    has_cat[r] = True
-            row_norm = np.zeros(b_pad, bool)
+            row_norm = np.zeros(b, bool)
             if not isinstance(normalize, (bool, np.bool_)):
-                row_norm[:b] = np.asarray(normalize, bool)
+                row_norm[:] = np.asarray(normalize, bool)
                 normalize = "rows"
             else:
                 normalize = bool(normalize)
-        self.last_padded = (b_pad, excl.shape[1], incl.shape[1])
+            operand = _pack_operand(
+                q, b_pad, widths, self._n_pad,
+                exclude, include, categories, row_norm,
+            )
+        self.last_padded = (b_pad, widths[0], widths[1])
         _m_mask_age().labels(component=self.component).set(self.mask_age_s)
         _m_padding_waste().labels(site="retrieval_batch").set(
             (b_pad - b) / b_pad
         )
         if self.mesh is None:
             t0 = time.perf_counter()
-            dev = self._device
-            put = lambda a: (
-                jax.device_put(a, dev) if dev is not None else jnp.asarray(a)
-            )
             # executable-cache accounting: the fused program's jit cache
             # is keyed by shapes + statics; a NEW key here is a compile
             # (cold if it happens under a serving compile_site)
             if self.precision == "float32":
                 exec_key = (
-                    self._n_pad, self.rank, b_pad,
-                    excl.shape[1], incl.shape[1],
-                    self._codes_dev.shape[1], cats.shape[1],
+                    self._n_pad, self.rank, b_pad, *widths,
+                    self._codes_dev.shape[1],
                     n, positive_only, normalize,
                 )
                 with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
                     packed = _fused_topn_single(
-                        put(qp), self._y_dev, self._rn_dev,
-                        self._allow_dev,
-                        put(excl), put(incl), put(has_incl),
-                        self._codes_dev, put(cats), put(has_cat),
-                        put(row_norm),
-                        n, positive_only, normalize,
+                        self._upload(operand), self._y_dev, self._rn_dev,
+                        self._allow_dev, self._codes_dev,
+                        n, positive_only, normalize, widths,
                     )
             else:
                 shortlist = self._shortlist_width(n_dev, self._n_pad)
                 exec_key = (
-                    self._n_pad, self.rank, b_pad,
-                    excl.shape[1], incl.shape[1],
-                    self._codes_dev.shape[1], cats.shape[1],
+                    self._n_pad, self.rank, b_pad, *widths,
+                    self._codes_dev.shape[1],
                     n_dev, shortlist, positive_only, normalize,
                     self.precision,
                 )
@@ -1174,13 +1252,11 @@ class ItemRetriever:
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
                     packed = _fused_topn_single_2s(
-                        put(qp), self._y_dev, self._scale_operand,
-                        self._rn_dev, self._allow_dev,
-                        put(excl), put(incl), put(has_incl),
-                        self._codes_dev, put(cats), put(has_cat),
-                        put(row_norm),
+                        self._upload(operand), self._y_dev,
+                        self._scale_operand, self._rn_dev, self._allow_dev,
+                        self._codes_dev,
                         n_dev, shortlist, positive_only, normalize,
-                        self.precision,
+                        self.precision, widths,
                     )
             with _tracing.stage(_tracing.DEVICE_WAIT):
                 host = np.asarray(packed)[:b]
@@ -1189,19 +1265,10 @@ class ItemRetriever:
                 if self.precision != "float32":
                     return self._refine_exact(
                         q, host, n_dev, n, positive_only,
-                        row_norm[:b] if normalize == "rows" else normalize,
+                        row_norm if normalize == "rows" else normalize,
                     )
                 return unpack_topn(host, n)
 
-        rep = self._rep_q
-        q_dev = jax.device_put(qp, rep)
-        excl_dev = jax.device_put(excl, rep)
-        incl_dev = jax.device_put(incl, rep)
-        has_dev = jax.device_put(has_incl, rep)
-        cat_args = (
-            self._codes_dev, jax.device_put(cats, rep),
-            jax.device_put(has_cat, rep), jax.device_put(row_norm, rep),
-        )
         n_local = min(n_dev, self._n_pad // self._n_shards)
         shortlist = (
             None if self.precision == "float32"
@@ -1209,7 +1276,9 @@ class ItemRetriever:
                 n_local, self._n_pad // self._n_shards
             )
         )
-        stage1 = self._stage1(n_local, positive_only, normalize, shortlist)
+        stage1 = self._stage1(
+            n_local, positive_only, normalize, widths, shortlist
+        )
         # the shard-vs-merge timing split needs a host sync between the
         # two programs, which would serialize an otherwise back-to-back
         # dispatch on EVERY batch — so the split is SAMPLED (first
@@ -1218,25 +1287,21 @@ class ItemRetriever:
         self._batches += 1
         split = self._batches % _SPLIT_SAMPLE_EVERY == 1
         exec_key = (
-            n_local, positive_only, normalize, b_pad,
-            excl.shape[1], incl.shape[1], cats.shape[1], shortlist,
+            n_local, positive_only, normalize, b_pad, *widths, shortlist,
             self.precision,
         )
-        if shortlist is None:
-            args = (
-                q_dev, self._y_dev, self._rn_dev, self._allow_dev,
-                excl_dev, incl_dev, has_dev, *cat_args,
-            )
-        else:
-            args = (
-                q_dev, self._y_dev, self._scale_operand, self._rn_dev,
-                self._allow_dev, excl_dev, incl_dev, has_dev, *cat_args,
-            )
+        resident = (
+            (self._y_dev, self._rn_dev) if shortlist is None
+            else (self._y_dev, self._scale_operand, self._rn_dev)
+        )
         t0 = time.perf_counter()
         with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
             "retrieval-stage1", self._exec_seen, exec_key
         ):
-            cand = stage1(*args)
+            cand = stage1(
+                self._upload(operand), *resident, self._allow_dev,
+                self._codes_dev,
+            )
         if split:
             jax.block_until_ready(cand)
             t1 = time.perf_counter()
@@ -1253,7 +1318,7 @@ class ItemRetriever:
         if self.precision != "float32":
             return self._refine_exact(
                 q, host, n_dev, n, positive_only,
-                row_norm[:b] if normalize == "rows" else normalize,
+                row_norm if normalize == "rows" else normalize,
             )
         return unpack_topn(host, n)
 
@@ -1352,53 +1417,42 @@ class ItemRetriever:
         self,
         n_local: int,
         positive_only: bool,
-        normalize: bool,
+        normalize,
+        widths: Tuple[int, int, int],
         shortlist: Optional[int] = None,
     ):
-        key = (n_local, positive_only, normalize, shortlist)
+        key = (n_local, positive_only, normalize, widths, shortlist)
         fn = self._stage1_cache.get(key)
         if fn is None:
             axis = self._axis
             if shortlist is None:
                 kernel = functools.partial(
                     _shard_topk_kernel,
-                    axis=self._axis, n_local=n_local,
+                    axis=axis, n_local=n_local,
                     positive_only=positive_only, normalize=normalize,
+                    widths=widths,
                 )
                 in_specs = (
-                    P(None, None),  # q: replicated
+                    P(None, None),  # the batch's packed operand: replicated
                     P(axis, None),  # Y: row-sharded
                     P(axis),        # rn
                     P(axis),        # allow
-                    P(None, None),  # excl (global ids, replicated)
-                    P(None, None),  # incl
-                    P(None,),       # has_incl
                     P(axis, None),  # per-item category codes
-                    P(None, None),  # the queries' category codes
-                    P(None,),       # has_cat
-                    P(None,),       # row_norm
                 )
             else:
                 kernel = functools.partial(
                     _shard_topk_kernel_2s,
-                    axis=self._axis, n_local=n_local,
-                    shortlist=shortlist,
+                    axis=axis, n_local=n_local, shortlist=shortlist,
                     positive_only=positive_only, normalize=normalize,
-                    precision=self.precision,
+                    precision=self.precision, widths=widths,
                 )
                 in_specs = (
-                    P(None, None),  # q: replicated
+                    P(None, None),  # the batch's packed operand: replicated
                     P(axis, None),  # Yq: row-sharded quantized rows
                     P(axis),        # per-row scales
                     P(axis),        # rn
                     P(axis),        # allow
-                    P(None, None),  # excl (global ids, replicated)
-                    P(None, None),  # incl
-                    P(None,),       # has_incl
                     P(axis, None),  # per-item category codes
-                    P(None, None),  # the queries' category codes
-                    P(None,),       # has_cat
-                    P(None,),       # row_norm
                 )
             fn = jax.jit(
                 jax.shard_map(

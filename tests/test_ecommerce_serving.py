@@ -273,6 +273,46 @@ def test_a_batch_of_mixed_shapes_is_one_store_read_a_query_and_one_run(
     assert retrieval._m_shard_seconds().labels().count - runs0 == 1
 
 
+CELL_SHAPES = {  # the query shapes of `ecom-taobao-d512.query-filtered`
+    "plain": ["plain"],
+    "one_category": ["category"],
+    "black_list": ["black_list"],
+    "white_list": ["white_list"],
+    "cosine_visitor": ["unknown_user_recent_views"],
+    "mixed_in_one_batch": [
+        "plain", "category", "black_list", "white_list",
+        "unknown_user_recent_views", "upstream_num",
+    ],
+}
+
+
+@pytest.mark.parametrize("shape", sorted(CELL_SHAPES))
+def test_a_batch_goes_up_as_one_packed_operand_and_answers_as_the_host_path(
+    world, shape
+):
+    """Rows, id lists, category codes and the cosine flags of a batch
+    are one transfer, and what the program makes of them is what the
+    float64 reference (``check``) and the host ``_finish`` path make of
+    the same query, id for id."""
+    queries = [CASES[k] for k in CELL_SHAPES[shape]]
+    retriever = world.model._retriever
+    sent = retrieval._m_operand_transfers().labels(
+        component=retriever.component)
+    before = sent.value
+    got = world.check(queries)
+    assert sent.value - before == 1
+    for k, q in enumerate(queries):
+        pq = world.algo.prepare_query(world.model, q)
+        scores = world.model.item_factors @ pq.row
+        if pq.cosine:
+            scores = scores * retriever.reciprocal_norms
+        host = world.algo._finish(
+            world.model, q, scores, world.model._constraints.get(), pq.seen)
+        assert [s.item for s in got[k].item_scores] == [
+            s.item for s in host.item_scores]
+        assert got[k].item_scores  # no shape of the cell answers nothing
+
+
 PREPARED_CASES = {
     "plain": CASES["plain"],
     "category": CASES["category"],

@@ -17,6 +17,7 @@ import pytest
 from predictionio_tpu.data import storage as storage_mod
 from predictionio_tpu.data.event import DataMap, Event
 from predictionio_tpu.data.storage.base import App
+from predictionio_tpu.ops import retrieval
 from predictionio_tpu.ops.retrieval import (
     ItemRetriever,
     naive_topn_reference,
@@ -203,6 +204,187 @@ class TestRetrieverParity:
             _family_value("pio_retrieval_merge_seconds_count")
             > before_merge
         )
+
+
+class _CountedPuts:
+    """``jax.device_put`` as ``ops/retrieval.py`` calls it, counting the
+    calls made while the block runs."""
+
+    def __enter__(self):
+        real = self.real = jax.device_put
+        calls = self.calls = []
+
+        def counting(x, *a, **kw):
+            calls.append(np.shape(x))
+            return real(x, *a, **kw)
+
+        jax.device_put = counting
+        return calls
+
+    def __exit__(self, *exc):
+        jax.device_put = self.real
+
+
+def _transfers(component):
+    return retrieval._m_operand_transfers().labels(component=component).value
+
+
+class TestPackedOperand:
+    """A batch's query rows, id lists, category codes and flags travel
+    as one int32 buffer and one transfer; the program takes them apart."""
+
+    @pytest.mark.parametrize("widths", [
+        (1, 1, 1), (4, 1, 1), (8, 8, 4), (1024, 1, 1), (16, 1024, 2),
+    ])
+    def test_pack_then_unpack_is_bit_exact(self, widths):
+        k, b, b_pad = 12, 5, 8
+        sentinel = 2**24 + 11  # a catalog past what a float32 counts
+        w_excl, w_incl, w_cat = widths
+        rng = np.random.default_rng(sum(widths))
+        q = rng.standard_normal((b, k)).astype(np.float32)
+        q[0, :6] = [-0.0, 0.0, 1e-40, -1e-45, np.inf, -np.inf]
+        # bits that are small integers (subnormals as floats), and the
+        # largest and smallest normal magnitudes
+        q[1, :5] = np.array([1, 2, 7, 2**23 - 1, 2**23], np.int32).view(
+            np.float32)
+        q[1, 5:7] = [np.finfo(np.float32).max, np.finfo(np.float32).tiny]
+        exclude = [
+            np.array([2**24, 2**24 + 1, 5][:w_excl]), None,
+            np.zeros(0, np.int64),
+            rng.integers(0, sentinel, w_excl), [sentinel - 1][:w_excl],
+        ]
+        include = [
+            None, np.array([2**24 + 3, 0][:w_incl]), np.zeros(0, np.int64),
+            None, rng.integers(0, sentinel, w_incl),
+        ]
+        categories = [
+            None, np.arange(w_cat), np.zeros(0, np.int32), None, [9437][:w_cat]
+        ]
+        row_norm = np.array([True, False, False, True, True])
+        buf = retrieval._pack_operand(
+            q, b_pad, widths, sentinel, exclude, include, categories, row_norm)
+        assert buf.dtype == np.int32
+        assert buf.shape == (b_pad, k + sum(widths) + 3)
+        got = [np.asarray(a) for a in jax.jit(
+            retrieval._unpack_operand, static_argnums=(1, 2)
+        )(buf, k, widths)]
+        rows, excl, incl, has_incl, cats, has_cat, norm = got
+        assert rows.dtype == np.float32 and excl.dtype == np.int32
+        np.testing.assert_array_equal(
+            rows[:b].view(np.int32), q.view(np.int32))  # bit for bit
+        assert not rows[b:].view(np.int32).any()
+        for lists, block, pad in (
+            (exclude, excl, sentinel), (include, incl, sentinel),
+            (categories, cats, -2),
+        ):
+            for r in range(b_pad):
+                a = lists[r] if r < b and lists[r] is not None else []
+                assert block[r, : len(a)].tolist() == list(a)
+                assert (block[r, len(a):] == pad).all()
+        assert has_incl.tolist() == [
+            False, True, True, False, True, False, False, False]
+        assert has_cat.tolist() == [
+            False, True, True, False, True, False, False, False]
+        assert norm.tolist() == [
+            True, False, False, True, True, False, False, False]
+
+    def test_wide_parts_start_on_lane_multiples_at_the_serving_widths(self):
+        for widths in ((1024, 1, 1), (8192, 1, 1), (1024, 1024, 1),
+                       (8192, 1024, 4)):
+            rows, excl, incl, cats, flags = retrieval._operand_slices(
+                512, widths)
+            assert rows.start == 0 and excl.start % 128 == 0
+            assert incl.start % 128 == 0
+            assert flags.stop - flags.start == 3
+            assert flags.stop == 512 + sum(widths) + 3
+
+    @pytest.mark.parametrize("branch", ["float32", "int8", "bf16", "mesh"])
+    def test_topn_is_one_transfer_a_call(self, branch):
+        rng = np.random.default_rng(3)
+        Y = rng.standard_normal((96, 8)).astype(np.float32)
+        codes = rng.integers(0, 5, (96, 1)).astype(np.int32)
+        component = f"oneput-{branch}"
+        r = ItemRetriever(
+            Y, mesh=_mesh_or_none(4) if branch == "mesh" else None,
+            precision=branch if branch in ("int8", "bf16") else "float32",
+            component=component, category_codes=codes, category_width=2,
+        )
+        q = rng.standard_normal((3, 8)).astype(np.float32)
+        calls = [
+            {},
+            {"exclude": [np.arange(9), None, np.arange(40)]},
+            {"include": [None, np.arange(20, 60), None],
+             "categories": [np.array([1, 3]), None, None],
+             "normalize": [True, False, True], "positive_only": True},
+        ]
+        for kw in calls:
+            before = _transfers(component)
+            with _CountedPuts() as puts:
+                r.topn(q, 4, **kw)
+            assert len(puts) == 1, puts
+            assert _transfers(component) - before == 1
+
+    def test_warm_compiles_the_ladder_and_live_batches_add_none(self):
+        """A catalog size no other test of this file uses: every
+        executable met here is compiled by this test's own warm()."""
+        rng = np.random.default_rng(8)
+        n_items, k = 311, 8
+        Y = rng.standard_normal((n_items, k)).astype(np.float32)
+        codes = rng.integers(0, 5, (n_items, 1)).astype(np.int32)
+        r = ItemRetriever(
+            Y, component="packed-ladder", category_codes=codes,
+            category_width=2, exclude_ladder=(16, 64),
+            include_ladder=(1, 32), max_batch=16,
+        )
+        size0 = retrieval._fused_topn_single._cache_size()
+        r.warm(n=16, flag_combos=((True, "rows"),))
+        assert r.ladder_size() == 2 * 2 * 2
+        assert (
+            retrieval._fused_topn_single._cache_size() - size0
+            == r.ladder_size()
+        )
+        live = [  # (batch, exclude, include, categories)
+            (1, None, None, None),
+            (3, [np.arange(5), None, np.arange(60)], None, None),
+            (9, None, [np.arange(30)] + [None] * 8, None),
+            (16, [np.arange(17)] * 16, [np.arange(3)] * 16,
+             [np.array([2])] * 16),
+            (2, None, None, [np.array([0, 4]), None]),
+        ]
+        before = _transfers("packed-ladder")
+        for b, exclude, include, categories in live:
+            q = rng.standard_normal((b, k)).astype(np.float32)
+            cosine = rng.random(b) < 0.5
+            s, i = r.topn(
+                q, 16, exclude=exclude, include=include,
+                categories=categories, positive_only=True, normalize=cosine,
+            )
+            # the reference knows no categories and no per-row flags:
+            # categories become a whitelist, each row runs on its own
+            for row in range(b):
+                wl = None if include is None else include[row]
+                if categories is not None and categories[row] is not None:
+                    member = np.flatnonzero(
+                        np.isin(codes[:, 0], categories[row]))
+                    wl = member if wl is None else np.intersect1d(wl, member)
+                es, ei = naive_topn_reference(
+                    Y, q[row: row + 1], 16,
+                    exclude=None if exclude is None else [exclude[row]],
+                    include=None if wl is None else [wl],
+                    positive_only=True, normalize=bool(cosine[row]),
+                )
+                alive = es[0] > -np.inf
+                assert (s[row] > -np.inf).sum() == alive.sum()
+                np.testing.assert_array_equal(i[row][alive], ei[0][alive])
+        assert _transfers("packed-ladder") - before == len(live)
+        assert (
+            retrieval._fused_topn_single._cache_size() - size0
+            == r.ladder_size()
+        )
+        with pytest.raises(ValueError, match="over the ladder's top"):
+            r.topn(q, 16, exclude=[np.arange(65)] + [None] * (len(q) - 1))
+        with pytest.raises(ValueError, match="3 categories in one query"):
+            r.topn(q, 16, categories=[np.arange(3)] + [None] * (len(q) - 1))
 
 
 class TestConstraintCache:

@@ -168,6 +168,61 @@ class TestQuantizedRecall:
             r.free()
 
 
+class TestQuantizedPackedOperand:
+    """The two-stage kernels take the batch's one packed operand apart
+    as the float32 program does: flags, category codes and id lists
+    arrive whole, and a call is one transfer."""
+
+    @pytest.mark.parametrize("precision", ["bf16", "int8"])
+    @pytest.mark.parametrize("shards", [1, 2, 4])
+    def test_flags_codes_and_lists_arrive_in_one_transfer(
+        self, shards, precision
+    ):
+        mesh = _mesh_or_none(shards)
+        rng = np.random.default_rng(40 + shards)
+        N, k, n = 203, 8, 8
+        Y = rng.standard_normal((N, k)).astype(np.float32)
+        codes = rng.integers(0, 6, (N, 1)).astype(np.int32)
+        q = rng.standard_normal((6, k)).astype(np.float32)
+        kw = dict(
+            exclude=[None, np.arange(90), None, np.array([5]), None, None],
+            include=[None, None, np.arange(40, 160), None,
+                     np.zeros(0, np.int64), None],
+            categories=[None, None, np.array([1, 4]), np.array([2]), None,
+                        np.zeros(0, np.int32)],
+            positive_only=True,
+            normalize=[True, False, True, False, False, True],
+        )
+        component = f"qpacked-{precision}-{shards}"
+        exact = ItemRetriever(
+            Y, component=f"{component}-f32", category_codes=codes,
+            category_width=2,
+        )
+        r = ItemRetriever(
+            Y, mesh=mesh, component=component, precision=precision,
+            category_codes=codes, category_width=2, shortlist_mult=8,
+        )
+        transfers = lambda: _gauge(
+            "pio_retrieval_operand_transfers_total", component=component)
+        try:
+            before = transfers()
+            s, i = r.topn(q, n, **kw)
+            assert transfers() - before == 1
+            es, ei = exact.topn(q, n, **kw)
+            live = es > -np.inf
+            assert (s > -np.inf).sum() == live.sum()
+            # rows 4 (an empty whitelist) and 5 (an empty category list)
+            # have no candidate at all
+            assert not live[4:].any() and live[:4].any(axis=1).all()
+            np.testing.assert_array_equal(i[live], ei[live])
+            np.testing.assert_allclose(
+                s[live], es[live], rtol=1e-5, atol=1e-6
+            )
+        finally:
+            r.free()
+            exact.free()
+
+
 class TestShortlistBoundaryTies:
     """Float tie-break at the shortlist boundary: a tie group wider
     than the device candidate width must resolve exactly as the naive

@@ -158,20 +158,19 @@ def test_serving_topn_compiles(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+# a batch's packed operand with no lists: the rows' bits, an exclusion
+# and an inclusion block and a category block of width 1, three flags
+WIDTHS = (1, 1, 1)
+
+
 def _stage1_shapes(replicated, rows, rows_k):
     return (
-        _shape((BATCH, RANK), jnp.float32, replicated),
+        _shape((BATCH, RANK + sum(WIDTHS) + 3), jnp.int32, replicated),
         _shape((N_ITEMS, RANK), jnp.int8, rows_k),
         _shape((N_ITEMS,), jnp.float32, rows),  # per-row scales
         _shape((N_ITEMS,), jnp.float32, rows),  # reciprocal norms
         _shape((N_ITEMS,), jnp.bool_, rows),  # candidacy mask
-        _shape((BATCH, 1), jnp.int32, replicated),
-        _shape((BATCH, 1), jnp.int32, replicated),
-        _shape((BATCH,), jnp.bool_, replicated),
         _shape((N_ITEMS, 1), jnp.int32, rows_k),  # per-item category codes
-        _shape((BATCH, 1), jnp.int32, replicated),  # the queries' codes
-        _shape((BATCH,), jnp.bool_, replicated),  # has_cat
-        _shape((BATCH,), jnp.bool_, replicated),  # row_norm
     )
 
 
@@ -179,7 +178,7 @@ def test_int8_stage1_single_device_compiles(one_chip):
     compiled = retrieval._fused_topn_single_2s.lower(
         *_stage1_shapes(one_chip, one_chip, one_chip),
         n=64, shortlist=64, positive_only=False, normalize=False,
-        precision="int8",
+        precision="int8", widths=WIDTHS,
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
 
@@ -188,21 +187,17 @@ def test_ecommerce_fused_program_compiles_at_the_taobao_shape(one_chip):
     """The float32 fused retrieval program of the e-commerce cell at its
     widest warm corner: 4,162,024 x 512 resident, a batch of 32,
     exclusion lists of 8,192, a whitelist of 1,024, four category codes,
-    per-row cosine flags. It has to fit one chip beside the table."""
+    per-row cosine flags, all in the batch's one packed operand. It has
+    to fit one chip beside the table."""
     n, k, b = 4_162_024, 512, 32
+    widths = (8192, 1024, 4)
     compiled = retrieval._fused_topn_single.lower(
-        _shape((b, k), jnp.float32, one_chip),
+        _shape((b, k + sum(widths) + 3), jnp.int32, one_chip),
         _shape((n, k), jnp.float32, one_chip),
         _shape((n,), jnp.float32, one_chip),
         _shape((n,), jnp.bool_, one_chip),
-        _shape((b, 8192), jnp.int32, one_chip),
-        _shape((b, 1024), jnp.int32, one_chip),
-        _shape((b,), jnp.bool_, one_chip),
         _shape((n, 1), jnp.int32, one_chip),
-        _shape((b, 4), jnp.int32, one_chip),
-        _shape((b,), jnp.bool_, one_chip),
-        _shape((b,), jnp.bool_, one_chip),
-        n=16, positive_only=True, normalize="rows",
+        n=16, positive_only=True, normalize="rows", widths=widths,
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
 
@@ -213,15 +208,14 @@ def test_int8_stage1_shards_over_four_chips(mesh4):
     kernel = functools.partial(
         retrieval._shard_topk_kernel_2s, axis="data", n_local=64,
         shortlist=64, positive_only=False, normalize=False,
-        precision="int8",
+        precision="int8", widths=WIDTHS,
     )
     fn = jax.jit(
         jax.shard_map(
             kernel, mesh=mesh4,
             in_specs=(
                 P(None, None), P("data", None), P("data"), P("data"),
-                P("data"), P(None, None), P(None, None), P(None),
-                P("data", None), P(None, None), P(None), P(None),
+                P("data"), P("data", None),
             ),
             out_specs=P(None, "data"),
             check_vma=False,
