@@ -72,7 +72,9 @@ def load_persistent_model(
     return cls.load(id, params, ctx)
 
 
-def _local_model_dir() -> str:
+def local_model_dir() -> str:
+    """``<PIO_FS_BASEDIR>/pmodels``: where the models that persist
+    themselves to the local file system keep their files."""
     d = os.path.join(
         fs_basedir(),
         "pmodels",
@@ -89,13 +91,13 @@ class LocalFileSystemPersistentModel(PersistentModel):
     def save(self, id: str, params: Params, ctx) -> bool:
         from predictionio_tpu.utils.serialize import to_host
 
-        path = os.path.join(_local_model_dir(), f"{id}-{type(self).__name__}")
+        path = os.path.join(local_model_dir(), f"{id}-{type(self).__name__}")
         with open(path, "wb") as f:
             pickle.dump(to_host(self), f, protocol=pickle.HIGHEST_PROTOCOL)
         return True
 
     @classmethod
     def load(cls, id: str, params: Params, ctx) -> "LocalFileSystemPersistentModel":
-        path = os.path.join(_local_model_dir(), f"{id}-{cls.__name__}")
+        path = os.path.join(local_model_dir(), f"{id}-{cls.__name__}")
         with open(path, "rb") as f:
             return pickle.load(f)

@@ -295,22 +295,6 @@ class ECommAlgorithmParams(Params):
 QUERY_CATEGORIES = 4
 
 
-def category_arrays(
-    items: Dict[int, "Item"], n_items: int
-) -> Tuple[Tuple[str, ...], np.ndarray]:
-    """(category names, per-item category codes [n_items, C] int32, -1
-    where an item has fewer than C) from the ``{dense index: Item}``
-    mapping a train produces."""
-    names = sorted({c for it in items.values() for c in it.categories})
-    code = {c: j for j, c in enumerate(names)}
-    width = max([len(set(it.categories)) for it in items.values()] + [1])
-    codes = np.full((n_items, width), -1, np.int32)
-    for idx, it in items.items():
-        cs = sorted({code[c] for c in it.categories})
-        codes[idx, : len(cs)] = cs
-    return tuple(names), codes
-
-
 @dataclasses.dataclass
 class ECommModel:
     user_factors: np.ndarray  # [n_users, k]
@@ -357,8 +341,8 @@ class ECommModel:
     def _fold_items(self) -> None:
         n = self.item_factors.shape[0]
         if self.items is not None:
-            self.category_names, self.item_categories = category_arrays(
-                self.items, n
+            self.category_names, self.item_categories = (
+                retrieval.category_arrays(self.items, n)
             )
             self.items = None
         elif self.item_categories is None:
@@ -372,8 +356,7 @@ class ECommModel:
             self.__dict__.setdefault(
                 name, () if name == "category_names" else None
             )
-        for gone in ("_cat_items", "_normed_host"):
-            self.__dict__.pop(gone, None)
+        self.__dict__.pop("_cat_items", None)  # an old blob's index
         self._fold_items()
 
     def __getstate__(self):
@@ -405,13 +388,7 @@ class ECommModel:
             self._cat_code = {
                 c: j for j, c in enumerate(self.category_names)
             }
-        return np.asarray(
-            sorted({
-                self._cat_code[c] for c in categories
-                if c in self._cat_code
-            }),
-            np.int32,
-        )
+        return retrieval.category_codes(self._cat_code, categories)
 
     def category_mask(self, categories) -> np.ndarray:
         """[n_items] bool: the item carries one of the categories."""
@@ -429,10 +406,7 @@ class ECommModel:
         """Item names by dense index (an object array: not a dict, and
         nothing the collector walks)."""
         if self._item_names is None:
-            names = np.empty(len(self.item_index), object)
-            for name, idx in self.item_index.items():
-                names[idx] = name
-            self._item_names = names
+            self._item_names = retrieval.names_by_index(self.item_index)
         return self._item_names
 
     @property

@@ -65,7 +65,12 @@ class ALSLocalAlgorithm(ALSAlgorithm):
                 for j in range(device_model.item_factors.shape[0])
             },
             item_index=device_model.item_index,
-            items=device_model.items,
+            # the device model folds its items into category arrays;
+            # the local model keeps the reference's map
+            items={
+                device_model.item_index[i]: item
+                for i, item in pd.td.items.items()
+            },
         )
 
     def warm(self, model: ALSLocalModel) -> None:

@@ -18,6 +18,9 @@ from __future__ import annotations
 
 import dataclasses
 import logging
+import os
+import pickle
+import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -32,12 +35,17 @@ from predictionio_tpu.controller import (
     SanityCheck,
 )
 from predictionio_tpu.controller.engine import Engine
+from predictionio_tpu.controller.persistent_model import (
+    PersistentModel,
+    local_model_dir,
+)
 from predictionio_tpu.data.bimap import BiMap
 from predictionio_tpu.data.store import PEventStore
 from predictionio_tpu.ops import retrieval
 from predictionio_tpu.ops.als import ALSConfig, train_als, validate_solver
 from predictionio_tpu.ops.retrieval import ItemRetriever
-from predictionio_tpu.ops.similarity import SimilarityScorer, normalize_rows
+from predictionio_tpu.utils import metrics as _metrics
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -193,9 +201,8 @@ class ALSAlgorithmParams(Params):
     num_iterations: int = 20
     lambda_: float = 0.01
     seed: Optional[int] = None
-    # deploy-time warm-up: largest query-item count to pre-compile the
-    # cosine-sum executables for (wider queries still work but pay a
-    # one-time cold compile on live traffic)
+    # read by nothing since PR 33 (the host path is numpy and compiles
+    # nothing); kept so that instances stored with it still load
     warm_max_query_items: int = 16
     # deploy-time warm-up coverage for the retrieval executables: keep
     # warm_max_batch >= the server's --max-batch, or the first saturated
@@ -210,6 +217,18 @@ class ALSAlgorithmParams(Params):
     precision: str = "float32"
     # stage-1 shortlist width multiplier c (shortlist = pow2(c*n))
     shortlist_mult: int = 4
+    # the closed ladder of the serving executables (ops/retrieval.py),
+    # under the e-commerce engine's names: exclusion lists (the query
+    # items + blackList) and inclusion lists (whiteList) pad to the
+    # smallest listed width that holds the batch's longest, batches to
+    # 8 doubling up to warm_max_batch; warm() compiles the whole
+    # product, and a query over a ladder's top (or over warm_num, or
+    # naming more than QUERY_CATEGORIES categories) is answered on the
+    # host. Size exclude_widths by 10 query items plus the longest
+    # blackList the shop's pages send, include_widths by the longest
+    # whiteList
+    exclude_widths: Tuple[int, ...] = (16, 64)
+    include_widths: Tuple[int, ...] = (256,)
     # confidence scale for the implicit objective this engine always
     # trains (c = alpha*|r| on view events, MLlib trainImplicit parity)
     alpha: float = 1.0
@@ -222,201 +241,314 @@ class ALSAlgorithmParams(Params):
         validate_solver(self.solver, self.block_size, self.rank)
 
 
-@dataclasses.dataclass
-class SPModel:
-    """Item factors + metadata for similarity serving. The normalized
-    factor matrix lives on device via a lazily-built SimilarityScorer."""
+# how many category codes a query ships to the fused program (a
+# dimension of every serving executable, so not a knob)
+QUERY_CATEGORIES = 4
 
-    item_factors: np.ndarray  # [n_items, k]
+# rows of the float32 table the host path scores at a time
+_HOST_BLOCK = 1 << 16
+
+
+def _m_host_fallbacks():
+    return _metrics.get_registry().counter(
+        "pio_similar_host_fallback_total",
+        "Similar-product queries of a prepared serving state answered "
+        "on the host: a list over the warm ladder's top, a num over "
+        "warm_num, or more categories than the fused program takes",
+    )
+
+
+def _m_query_items():
+    return _metrics.get_registry().histogram(
+        "pio_similar_query_items",
+        "Items of a similar-product query that the model has factors "
+        "for (the rows its query vector sums)",
+        buckets=(1, 2, 3, 4, 5, 6, 8, 10, 16, 32),
+    )
+
+
+@dataclasses.dataclass
+class SPModel(PersistentModel):
+    """Item factors + the items' categories as columns, for similarity
+    serving. Nothing a Python object an item: the index is one BiMap,
+    the categories are ``[n_items, C]`` codes (``category_arrays``, as
+    ``ECommModel`` holds them), and ``items`` (``{dense index: Item}``,
+    what a train produces) is folded into them at construction.
+
+    A ``PersistentModel``: ``save`` writes the float32 table as
+    ``item_factors.npy`` with the index and the category columns beside
+    it under ``<PIO_FS_BASEDIR>/pmodels/<instance id>-<class name>/``,
+    and ``load`` MAPS the table (``mmap_mode="r"``), so that a 19 GB
+    catalog is never a blob in memory beside the array it holds; the
+    model store keeps the manifest alone."""
+
+    item_factors: np.ndarray  # [n_items, k] float32 (a map after load)
     item_index: BiMap
-    items: Dict[int, Item]  # dense index -> metadata
-    _scorer: Optional[SimilarityScorer] = dataclasses.field(
-        default=None, repr=False, compare=False
-    )
-    _inv_index: Optional[BiMap] = dataclasses.field(
-        default=None, repr=False, compare=False
-    )
-    # deploy-time mesh (BaseAlgorithm.prepare_serving): the candidate
-    # matrix row-shards over it. Device state; never pickled.
-    _serving_mesh: Optional[object] = dataclasses.field(
-        default=None, repr=False, compare=False
-    )
-    # sharded on-device retrieval state (ops/retrieval.py), built by
-    # prepare_serving. Device state; never pickled.
+    items: Optional[Dict[int, Item]] = None
+    category_names: Tuple[str, ...] = ()
+    item_categories: Optional[np.ndarray] = None  # [n_items, C] int32
+    # on-device retrieval state (ops/retrieval.py), built by
+    # prepare_serving. Device state; never persisted.
     _retriever: Optional[ItemRetriever] = dataclasses.field(
         default=None, repr=False, compare=False
     )
-    _normed_host: Optional[np.ndarray] = dataclasses.field(
+    _cat_code: Optional[Dict[str, int]] = dataclasses.field(
         default=None, repr=False, compare=False
     )
-    _cat_items: Optional[Dict[str, np.ndarray]] = dataclasses.field(
+    _item_names: Optional[np.ndarray] = dataclasses.field(
         default=None, repr=False, compare=False
     )
+    _rn: Optional[np.ndarray] = dataclasses.field(
+        default=None, repr=False, compare=False
+    )
+
+    def __post_init__(self):
+        n = self.item_factors.shape[0]
+        if self.items is not None:
+            self.category_names, self.item_categories = (
+                retrieval.category_arrays(self.items, n)
+            )
+            self.items = None
+        elif self.item_categories is None:
+            self.item_categories = np.full((n, 1), -1, np.int32)
 
     def __getstate__(self):
         state = self.__dict__.copy()
-        state["_scorer"] = None
-        state["_inv_index"] = None
-        state["_serving_mesh"] = None
-        state["_retriever"] = None
-        state["_normed_host"] = None
-        state["_cat_items"] = None
+        for name in ("_retriever", "_cat_code", "_item_names", "_rn"):
+            state[name] = None
         return state
 
-    def attach_serving_mesh(self, mesh) -> None:
-        self._serving_mesh = mesh
-        self._scorer = None
+    # --- persistence (controller/persistent_model.py) ---
 
-    @property
-    def normed_host(self) -> np.ndarray:
-        if self._normed_host is None:
-            self._normed_host = normalize_rows(self.item_factors)
-        return self._normed_host
+    @classmethod
+    def model_dir(cls, id: str) -> str:
+        return os.path.join(local_model_dir(), f"{id}-{cls.__name__}")
 
-    def category_items(self, categories) -> np.ndarray:
-        """Dense indices of items carrying one of the given categories
-        (inverted index consumed as an on-device inclusion list)."""
-        if self._cat_items is None:
-            self._cat_items = retrieval.build_category_index(self.items)
-        return retrieval.category_candidates(self._cat_items, categories)
+    @classmethod
+    def factors_path(cls, id: str) -> str:
+        """Where ``save`` puts the float32 table. A writer that fills
+        that file itself (``numpy.lib.format.open_memmap``) and hands
+        the map in as ``item_factors`` is not copied: ``save`` flushes
+        it."""
+        return os.path.join(cls.model_dir(id), "item_factors.npy")
 
-    @property
-    def scorer(self) -> SimilarityScorer:
-        if self._scorer is None:
-            self._scorer = SimilarityScorer(
-                self.item_factors, mesh=self._serving_mesh
+    def save(self, id: str, params: Params, ctx) -> bool:
+        os.makedirs(self.model_dir(id), exist_ok=True)
+        path, table = self.factors_path(id), self.item_factors
+        if (
+            isinstance(table, np.memmap) and os.path.exists(path)
+            and os.path.samefile(table.filename, path)
+        ):
+            table.flush()
+        else:
+            np.save(path, np.asarray(table, np.float32))
+        np.save(
+            os.path.join(self.model_dir(id), "item_categories.npy"),
+            self.item_categories,
+        )
+        with open(os.path.join(self.model_dir(id), "index.pkl"), "wb") as f:
+            pickle.dump(
+                {"item_index": self.item_index,
+                 "category_names": tuple(self.category_names)},
+                f, protocol=pickle.HIGHEST_PROTOCOL,
             )
-        return self._scorer
+        return True
+
+    @classmethod
+    def load(cls, id: str, params: Params, ctx) -> "SPModel":
+        d = cls.model_dir(id)
+        with open(os.path.join(d, "index.pkl"), "rb") as f:
+            index = pickle.load(f)
+        return cls(
+            item_factors=np.load(cls.factors_path(id), mmap_mode="r"),
+            item_index=index["item_index"],
+            category_names=index["category_names"],
+            item_categories=np.load(os.path.join(d, "item_categories.npy")),
+        )
+
+    # --- what a query needs of the model ---
 
     @property
-    def inv_index(self) -> BiMap:
-        if self._inv_index is None:
-            self._inv_index = self.item_index.inverse()
-        return self._inv_index
+    def reciprocal_norms(self) -> np.ndarray:
+        """1/||row|| of the float32 table, [n_items]: the retriever's
+        own where serving is prepared, else computed once in row blocks
+        (4 bytes an item; never a normalized copy of the table)."""
+        if self._retriever is not None:
+            return self._retriever.reciprocal_norms
+        if self._rn is None:
+            n = self.item_factors.shape[0]
+            rn = np.zeros(n, np.float32)
+            for a in range(0, n, _HOST_BLOCK):
+                rn[a:a + _HOST_BLOCK] = retrieval._reciprocal_norms(
+                    self.item_factors[a:a + _HOST_BLOCK]
+                )
+            self._rn = rn
+        return self._rn
 
-    def _retrieval_spec(self, query: Query):
-        """(query vector, exclusion idx, inclusion idx or None) for the
-        on-device retrieval path, or None when no query item has
-        factors. The query vector is the sum of the normalized query-
-        item rows — cosine_sum's math folded to one [k] row; exclusions
-        are the query items themselves plus the blackList; whiteList ∩
-        category index becomes the inclusion list."""
+    def category_codes(self, categories) -> np.ndarray:
+        """Codes of the given category names (names no item carries
+        have none: an empty array means NO candidates)."""
+        if self._cat_code is None:
+            self._cat_code = {
+                c: j for j, c in enumerate(self.category_names)
+            }
+        return retrieval.category_codes(self._cat_code, categories)
+
+    @property
+    def item_names(self) -> np.ndarray:
+        """Item names by dense index (an object array: not a second
+        pair of dicts, and nothing the collector walks)."""
+        if self._item_names is None:
+            self._item_names = retrieval.names_by_index(self.item_index)
+        return self._item_names
+
+    def _spec(self, query: Query):
+        """(query vector, exclusion ids, whitelist ids or None, category
+        codes or None), or None when no query item has factors. The
+        query vector is the sum of the normalized rows of the query
+        items, gathered from the float32 table (at most a handful of
+        rows: upstream's cosine sum folded to one [k] row); exclusions
+        are the query items themselves plus the blackList."""
         query_idx = [
             self.item_index[i] for i in query.items if i in self.item_index
         ]
         if not query_idx:
             return None
-        qvec = self.normed_host[query_idx].sum(axis=0)
+        _m_query_items().observe(len(query_idx))
+        at = np.asarray(query_idx, np.int64)
+        rows = np.asarray(self.item_factors[at], np.float32)
+        qvec = (rows * self.reciprocal_norms[at][:, None]).sum(axis=0)
         excl = set(query_idx)
-        for i in query.black_list or ():
-            if i in self.item_index:
-                excl.add(self.item_index[i])
-        wl = retrieval.include_candidates(
-            self.item_index, query.white_list, query.categories,
-            self.category_items,
+        excl.update(
+            self.item_index[i] for i in query.black_list or ()
+            if i in self.item_index
         )
-        return qvec, np.asarray(sorted(excl), np.int64), wl
+        return (
+            qvec, np.asarray(sorted(excl), np.int64),
+            retrieval.include_candidates(self.item_index, query.white_list),
+            None if query.categories is None
+            else self.category_codes(query.categories),
+        )
 
-    def similar_batch(self, queries) -> List[Tuple[int, PredictedResult]]:
+    def _result(self, ids, scores) -> PredictedResult:
+        names = self.item_names
+        return PredictedResult(
+            item_scores=tuple(
+                ItemScore(item=names[i], score=s)
+                for i, s in zip(ids.tolist(), scores.tolist())
+            )
+        )
+
+    def similar_batch(
+        self, queries, warm_num: int = 16
+    ) -> List[Tuple[int, PredictedResult]]:
         """Batched on-device retrieval: every query of the micro-batch
-        rides ONE fused cosine score+mask+top_k program over the
-        resident sharded factors (requires prepare_serving)."""
+        that fits the warm ladder rides ONE fused cosine
+        score+mask+top_k program over the resident factors (requires
+        prepare_serving); categories travel as codes, tested against
+        the resident per-item codes. A query over the ladder's top is
+        answered on the host and counted."""
+        retriever = self._retriever
         out: List[Tuple[int, PredictedResult]] = []
-        meta, rows, excludes, includes = [], [], [], []
+        meta, rows, excludes, includes, cats = [], [], [], [], []
         for qi, q in queries:
-            spec = self._retrieval_spec(q)
+            spec = self._spec(q)
             if spec is None:
                 logger.info("no item factors for query items %s", q.items)
                 out.append((qi, PredictedResult()))
                 continue
-            qvec, excl, incl = spec
+            qvec, excl, incl, codes = spec
+            if q.num > max(16, warm_num) or not retriever.fits(
+                exclude=len(excl),
+                include=0 if incl is None else len(incl),
+                categories=0 if codes is None else len(codes),
+            ):
+                _m_host_fallbacks().inc()
+                out.append((qi, self._similar_host(q, spec)))
+                continue
             meta.append((qi, q))
             rows.append(qvec)
             excludes.append(excl)
             includes.append(incl)
-        if not meta:
-            return out
-        n_req = retrieval.pow2_topk_width(
-            max(q.num for _, q in meta), self._retriever.n_items
-        )
-        scores, idx = self._retriever.topn(
-            np.stack(rows).astype(np.float32),
-            n_req,
-            exclude=excludes,
-            include=includes,
-            positive_only=True,
-            normalize=True,
-        )
-        inv = self.inv_index
-        trimmed = retrieval.trimmed_results(
-            scores, idx, [q.num for _, q in meta]
-        )
-        out += [
-            (
-                qi,
-                PredictedResult(
-                    item_scores=tuple(
-                        ItemScore(item=inv[int(i)], score=float(s))
-                        for i, s in zip(ids, ss)
-                    )
-                ),
+            cats.append(codes)
+        top = retriever.max_batch  # a batch over the ladder's top is split
+        for s in range(0, len(meta), top):
+            part = meta[s:s + top]
+            n_req = retrieval.pow2_topk_width(
+                max(q.num for _, q in part), retriever.n_items
             )
-            for (qi, _), (ids, ss) in zip(meta, trimmed)
-        ]
+            scores, idx = retriever.topn(
+                np.stack(rows[s:s + top]).astype(np.float32),
+                n_req,
+                exclude=excludes[s:s + top],
+                include=includes[s:s + top],
+                categories=cats[s:s + top],
+                positive_only=True,
+                normalize=True,
+            )
+            with _tracing.stage(_tracing.BUILD):
+                trimmed = retrieval.trimmed_results(
+                    scores, idx, [q.num for _, q in part]
+                )
+                out += [
+                    (qi, self._result(ids, ss))
+                    for (qi, _), (ids, ss) in zip(part, trimmed)
+                ]
         return out
 
     def similar(self, query: Query) -> PredictedResult:
         """Reference ALSAlgorithm.predict: sum-of-cosines scoring with
         candidacy filtering and top-num selection. With a prepared
         serving state the scoring+masking+selection runs fused on
-        device (similar_batch); the host path below is the
-        training-time and parity-oracle implementation."""
+        device (similar_batch); the numpy path below is the
+        training-time implementation and the answer to a query the warm
+        ladder does not hold."""
         if self._retriever is not None:
             [(_, result)] = self.similar_batch([(0, query)])
             return result
-        query_idx = [
-            self.item_index[i] for i in query.items if i in self.item_index
-        ]
-        if not query_idx:
+        spec = self._spec(query)
+        if spec is None:
             logger.info("no item factors for query items %s", query.items)
             return PredictedResult()
-        scores = self.scorer.cosine_sum(self.scorer.normed[query_idx])
+        return self._similar_host(query, spec)
 
-        mask = scores > 0
-        mask[query_idx] = False  # exclude the query items themselves
-        if query.white_list is not None:
-            wl = np.zeros_like(mask)
-            wl[[
-                self.item_index[i]
-                for i in query.white_list
-                if i in self.item_index
-            ]] = True
-            mask &= wl
-        if query.black_list is not None:
-            mask[[
-                self.item_index[i]
-                for i in query.black_list
-                if i in self.item_index
-            ]] = False
-        if query.categories is not None:
-            cats = set(query.categories)
-            for idx in np.nonzero(mask)[0]:
-                item = self.items.get(int(idx))
-                if item is None or not cats.intersection(item.categories):
-                    mask[idx] = False
+    def _similar_host(self, query: Query, spec) -> PredictedResult:
+        """The numpy path: the float32 table times the query vector in
+        row blocks (numpy's float32 product is a float32 product, what
+        ``precision="highest"`` asks of the device), over the whitelist's
+        rows alone where there is one; cosine = dot over the row's norm.
+        Never a normalized copy of the table, never the device."""
+        qvec, excl, incl, codes = spec
+        n = self.item_factors.shape[0]
+        rn = self.reciprocal_norms
+        scores = np.zeros(n, np.float32)
+        if incl is not None:
+            at = np.unique(incl)
+            scores[at] = (
+                np.asarray(self.item_factors[at], np.float32) @ qvec
+            ) * rn[at]
+            mask = np.zeros(n, bool)
+            mask[at] = True
+        else:
+            for a in range(0, n, _HOST_BLOCK):
+                scores[a:a + _HOST_BLOCK] = (
+                    np.asarray(self.item_factors[a:a + _HOST_BLOCK],
+                               np.float32) @ qvec
+                ) * rn[a:a + _HOST_BLOCK]
+            mask = np.ones(n, bool)
+        mask &= scores > 0
+        mask[excl] = False  # the query items themselves + blackList
+        if codes is not None:
+            mask &= np.isin(self.item_categories, codes).any(axis=1)
+        live = np.flatnonzero(mask)
+        # best first, ties to the lowest index (the device's order)
+        top = live[np.lexsort((live, -scores[live]))][: query.num]
+        return self._result(top, scores[top])
 
-        scores = np.where(mask, scores, -np.inf)
-        num = min(query.num, int(mask.sum()))
-        if num <= 0:
-            return PredictedResult()
-        top = np.argpartition(-scores, num - 1)[:num]
-        top = top[np.argsort(-scores[top])]
-        return PredictedResult(
-            item_scores=tuple(
-                ItemScore(item=self.inv_index[int(i)], score=float(scores[i]))
-                for i in top
-            )
-        )
+
+class LikeSPModel(SPModel):
+    """``LikeAlgorithm``'s model: a class of its own so that the multi
+    variant's two models persist to two directories."""
 
 
 class ALSAlgorithm(BaseAlgorithm):
@@ -425,6 +557,7 @@ class ALSAlgorithm(BaseAlgorithm):
 
     params_class = ALSAlgorithmParams
     query_class = Query
+    model_class = SPModel
 
     def _ratings(self, td: TrainingData):
         """(user, item) -> value triples. Overridden by LikeAlgorithm."""
@@ -471,36 +604,45 @@ class ALSAlgorithm(BaseAlgorithm):
             ),
             mesh=ctx.mesh if ctx is not None else None,
         )
-        return SPModel(
+        return self.model_class(
             item_factors=arrays.item_factors,
             item_index=item_index,
             items={item_index[i]: item for i, item in td.items.items()},
-        )
+        )  # folded into category arrays by the model
 
     def predict(self, model: SPModel, query: Query) -> PredictedResult:
-        return model.similar(query)
+        [(_, result)] = self.batch_predict(model, [(0, query)])
+        return result
 
     def batch_predict(self, model: SPModel, queries):
         """With a prepared serving state the whole micro-batch scores as
         ONE fused retrieval program (model.similar_batch); otherwise the
-        default per-query host path."""
+        per-query numpy path."""
         if model._retriever is not None:
-            return model.similar_batch(queries)
-        return [(i, self.predict(model, q)) for i, q in queries]
+            return model.similar_batch(queries, self.params.warm_num)
+        return [(i, model.similar(q)) for i, q in queries]
 
     def prepare_serving(self, ctx, model: SPModel) -> SPModel:
         """Build the prepared serving state: item factors resident on
-        device, row-sharded over the workflow mesh when it has >1
-        device (ops/retrieval.py) — candidacy rules apply as on-device
-        masks instead of a host post-filter."""
+        device in the params' ``precision``, row-sharded over the
+        workflow mesh when it has >1 device (ops/retrieval.py), the
+        items' category codes resident beside them, and the executable
+        space closed by the params' ladders: candidacy rules apply as
+        on-device masks instead of a host post-filter."""
         mesh = ctx.mesh if ctx is not None else None
-        if mesh is not None:
-            model.attach_serving_mesh(mesh)
+        p = self.params
         model._retriever = ItemRetriever(
             model.item_factors, mesh=mesh, component="similarproduct",
-            precision=self.params.precision,
-            shortlist_mult=self.params.shortlist_mult,
+            precision=p.precision,
+            shortlist_mult=p.shortlist_mult,
+            category_codes=model.item_categories,
+            category_width=QUERY_CATEGORIES,
+            exclude_ladder=p.exclude_widths,
+            include_ladder=(1, *p.include_widths),
+            max_batch=p.warm_max_batch,
         )
+        # samples from deploy on: a scrape tells "none" from "no family"
+        _m_host_fallbacks().inc(0)
         return model
 
     def serving_precision(self, model: SPModel) -> Optional[str]:
@@ -511,25 +653,45 @@ class ALSAlgorithm(BaseAlgorithm):
     def release_serving(self, model: SPModel) -> None:
         """Free a displaced model's device-resident serving state
         (promotion drain→release contract, controller/base.py): null
-        the references first — stragglers fall back to the host cosine
-        path — then drop the retriever's resident buffers."""
+        the reference first, so that a straggler is answered by the
+        numpy path over the host's float32 table (seconds a query at
+        catalog scale, and no device: nothing is uploaded again), then
+        drop the retriever's resident buffers."""
         retriever, model._retriever = model._retriever, None
-        model._scorer = None
         if retriever is not None:
+            model._rn = retriever.reciprocal_norms.copy()
             retriever.free()
 
     def warm(self, model: SPModel) -> None:
-        """Compile the serving executables before taking traffic (see
-        BaseAlgorithm.warm): the fused cosine retrieval programs for a
-        prepared state, the cosine-sum path otherwise."""
-        if model._retriever is not None:
-            model._retriever.warm(
-                n=self.params.warm_num,
-                max_batch=self.params.warm_max_batch,
-                flag_combos=((True, True),),
-            )
-        else:
-            model.scorer.warm(max_q=self.params.warm_max_query_items)
+        """Compile the whole closed ladder before the server reports
+        ready (see BaseAlgorithm.warm) and build the name table; the
+        numpy path of an unprepared model compiles nothing."""
+        if model._retriever is None:
+            return
+        p = self.params
+        t0 = time.perf_counter()
+        model._retriever.warm(
+            n=p.warm_num, max_batch=p.warm_max_batch,
+            flag_combos=((True, True),),
+        )
+        model.item_names  # built here, not inside the first batch
+        logger.info(
+            "similarproduct warm ladder: %d executables in %.1fs",
+            model._retriever.ladder_size(
+                tiers=len(model._retriever.warm_tiers(p.warm_num))
+            ),
+            time.perf_counter() - t0,
+        )
+
+    def query_from_json(self, json_obj) -> Query:
+        """Upstream's query spells ``whiteList`` and ``blackList``; the
+        dataclass's own field names are taken too."""
+        obj = dict(json_obj or {})
+        for theirs, ours in (("whiteList", "white_list"),
+                             ("blackList", "black_list")):
+            if theirs in obj:
+                obj[ours] = obj.pop(theirs)
+        return super().query_from_json(obj)
 
     def result_to_json(self, result: PredictedResult):
         return {
@@ -544,6 +706,8 @@ class LikeAlgorithm(ALSAlgorithm):
     """The multi-variant's second algorithm (reference LikeAlgorithm.scala):
     like/dislike events, like=+1 dislike=-1, LATEST event per (user, item)
     wins; same implicit ALS and cosine predict."""
+
+    model_class = LikeSPModel
 
     def _ratings(self, td: TrainingData):
         latest: Dict[Tuple[str, str], Tuple[float, float]] = {}

@@ -45,9 +45,12 @@ Metrics (utils/metrics.py conventions, visible in ``pio top``):
 (every batch off-mesh; SAMPLED on the sharded path — the split needs a
 host sync), ``pio_retrieval_mask_refresh_total{component,outcome}``,
 ``pio_retrieval_mask_age_seconds{component}``,
-``pio_retrieval_resident_bytes{component}``, and
+``pio_retrieval_resident_bytes{component}``,
 ``pio_retrieval_operand_transfers_total{component}`` (host-to-device
-transfers ``topn`` made: one a call).
+transfers ``topn`` made: one a call), and for the quantized tiers' host
+refine ``pio_retrieval_shortlist_rows_total{component}`` (candidate
+rows gathered and rescored) and
+``pio_retrieval_refine_changed_total{component}`` (answers it changed).
 
 Device-observability round: the resident factors/norms and the
 candidacy mask register in the HBM residency ledger
@@ -81,8 +84,10 @@ query BEFORE the (unchanged) cross-shard merge, so the per-shard
 truncation keeps the right candidates. The merge returns the full
 c·n-wide candidate list, and a final host refinement rescores those
 c·n rows per query against the ORIGINAL float32 factors — which stay
-in host RAM, where every engine already keeps them for pickling; HBM
-holds only the quantized rows. B·c·n·k host FLOPs per batch is noise
+on the host, where every engine keeps them (in RAM, or as the mapped
+file of a ``PersistentModel``; the quantized staging copy is made in
+row blocks and dropped once uploaded); HBM holds only the quantized
+rows. B·c·n·k host FLOPs per batch is noise
 next to the device matmul, and it buys id parity with the exact path:
 returned scores are exact over the original matrix, and recall can
 only be lost when a true top-n item misses the entire merged c·n
@@ -96,6 +101,7 @@ byte-for-byte. Capacity shows up in the ledger (component
 
 from __future__ import annotations
 
+import concurrent.futures
 import functools
 import logging
 import time
@@ -166,6 +172,52 @@ def _reciprocal_norms(factors: np.ndarray) -> np.ndarray:
     return np.where(norms > 0, 1.0 / np.where(norms == 0, 1.0, norms), 0.0).astype(
         np.float32
     )
+
+
+# rows a worker quantizes at a time (4 MB of float32 at rank 512), and
+# how many workers: the temporaries of a block stay in cache and their
+# sum stays far under one int8 table
+_QUANT_BLOCK_ROWS = 2048
+_QUANT_THREADS = 4
+
+
+def _quantize_resident(factors: np.ndarray, n_pad: int, precision: str):
+    """The quantized tier's host arrays, made in row blocks straight
+    from the caller's table (which may be a file mapped into memory, 19
+    GB at 9.4 M x 512): ``(rows [n_pad, k] int8 or bfloat16, per-row
+    scales [n_pad] or None, reciprocal norms of the DEQUANTIZED rows
+    [n_pad], reciprocal norms of the original rows [n_pad])``. Padding
+    rows stay zero. Never a float32 copy of the table, never its
+    dequantized form: a block's temporaries are a block's."""
+    n, k = factors.shape
+    rows = np.zeros(
+        (n_pad, k), np.int8 if precision == "int8" else jnp.bfloat16
+    )
+    scale = np.ones(n_pad, np.float32) if precision == "int8" else None
+    rn, rn_exact = np.zeros(n_pad, np.float32), np.zeros(n_pad, np.float32)
+
+    def block(a: int) -> None:
+        b = min(a + _QUANT_BLOCK_ROWS, n)
+        f = np.asarray(factors[a:b], np.float32)
+        rn_exact[a:b] = _reciprocal_norms(f)
+        if precision == "int8":
+            sc = np.maximum(f.max(axis=1), -f.min(axis=1)) / 127.0
+            sc = np.where(sc > 0, sc, 1.0).astype(np.float32)
+            t = f / sc[:, None]
+            np.rint(t, out=t)
+            np.clip(t, -127, 127, out=t)
+            rows[a:b] = t  # the cast of quantize_rows_int8
+            scale[a:b] = sc
+            np.multiply(rows[a:b], sc[:, None], out=t)
+        else:
+            rows[a:b] = f
+            t = rows[a:b].astype(np.float32)
+        rn[a:b] = _reciprocal_norms(t)
+
+    # numpy releases the lock inside each of a block's passes
+    with concurrent.futures.ThreadPoolExecutor(_QUANT_THREADS) as pool:
+        list(pool.map(block, range(0, n, _QUANT_BLOCK_ROWS)))
+    return rows, scale, rn, rn_exact
 
 
 def _batch_sizes(max_batch: int) -> Tuple[int, ...]:
@@ -333,48 +385,53 @@ def trimmed_results(
     return out
 
 
-def build_category_index(items) -> Dict[str, np.ndarray]:
-    """items dict (dense idx -> object with ``.categories``) inverted
-    to category -> sorted dense indices: the host category loop of the
-    templates' candidate masks, precomputed once and consumed as an
-    on-device inclusion list."""
-    by_cat: Dict[str, list] = {}
-    for idx, item in items.items():
-        for c in item.categories:
-            by_cat.setdefault(c, []).append(idx)
-    return {c: np.asarray(sorted(v), np.int64) for c, v in by_cat.items()}
+def category_arrays(
+    items: Dict[int, object], n_items: int
+) -> Tuple[Tuple[str, ...], np.ndarray]:
+    """(category names, per-item category codes [n_items, C] int32, -1
+    where an item has fewer than C) from the ``{dense index: item}``
+    mapping a train produces (an item is anything with
+    ``.categories``): what an engine's model persists and hands
+    ``ItemRetriever`` as its resident ``category_codes``."""
+    names = sorted({c for it in items.values() for c in it.categories})
+    code = {c: j for j, c in enumerate(names)}
+    width = max([len(set(it.categories)) for it in items.values()] + [1])
+    codes = np.full((n_items, width), -1, np.int32)
+    for idx, it in items.items():
+        cs = sorted({code[c] for c in it.categories})
+        codes[idx, : len(cs)] = cs
+    return tuple(names), codes
 
 
-def category_candidates(
-    index: Dict[str, np.ndarray], categories
-) -> np.ndarray:
-    """Union of the index rows for the given categories (empty array =
-    no item carries any of them, i.e. NO candidates)."""
-    arrs = [index[c] for c in categories if c in index]
-    if not arrs:
-        return np.zeros(0, np.int64)
-    return np.unique(np.concatenate(arrs))
+def category_codes(code_of: Dict[str, int], categories) -> np.ndarray:
+    """The sorted codes of a query's category names (``code_of`` maps a
+    model's category names to their codes; a name no item carries has
+    none: an empty array means NO candidates)."""
+    return np.asarray(
+        sorted({code_of[c] for c in categories if c in code_of}), np.int32
+    )
 
 
-def include_candidates(
-    item_index, white_list, categories, category_items
-) -> Optional[np.ndarray]:
-    """The per-query inclusion list both templates share: the
-    ``whiteList`` mapped through the item index, intersected with the
-    category candidates (``category_items`` is the model's cached
-    inverted-index lookup). ``None`` = unrestricted; an EMPTY array =
-    NO candidates — matching the host paths' all-False whitelist
-    mask."""
-    wl: Optional[np.ndarray] = None
-    if white_list is not None:
-        wl = np.asarray(
-            [item_index[i] for i in white_list if i in item_index],
-            np.int64,
-        )
-    if categories is not None:
-        cat = category_items(categories)
-        wl = cat if wl is None else np.intersect1d(wl, cat)
-    return wl
+def names_by_index(item_index) -> np.ndarray:
+    """Item names by dense index, as an object array (not a second pair
+    of dicts, and nothing the collector walks)."""
+    names = np.empty(len(item_index), object)
+    for name, idx in item_index.items():
+        names[idx] = name
+    return names
+
+
+def include_candidates(item_index, white_list) -> Optional[np.ndarray]:
+    """A query's ``whiteList`` mapped through the item index: its
+    inclusion list. ``None`` = unrestricted; an EMPTY array = NO
+    candidates, matching the host paths' all-False whitelist mask.
+    (Categories never travel as lists: they are resident codes,
+    ``topn(categories=...)``.)"""
+    if white_list is None:
+        return None
+    return np.asarray(
+        [item_index[i] for i in white_list if i in item_index], np.int64
+    )
 
 
 def _operand_slices(k: int, widths) -> List[slice]:
@@ -700,6 +757,26 @@ def _m_operand_transfers():
     )
 
 
+def _m_shortlist_rows():
+    return _metrics.get_registry().counter(
+        "pio_retrieval_shortlist_rows_total",
+        "Candidate rows of the device's shortlists that the quantized "
+        "tier's host refine gathered from the original float32 table "
+        "and rescored",
+        labels=("component",),
+    )
+
+
+def _m_refine_changed():
+    return _metrics.get_registry().counter(
+        "pio_retrieval_refine_changed_total",
+        "Answers whose ids or order the host refine changed from the "
+        "device's own best n (over the dequantized rows): whether the "
+        "float32 originals matter at all",
+        labels=("component",),
+    )
+
+
 def _m_mask_age():
     return _metrics.get_registry().gauge(
         "pio_retrieval_mask_age_seconds",
@@ -846,48 +923,44 @@ class ItemRetriever:
         self._n_shards = n_shards
         n_pad = pad_to_multiple(max(self.n_items, 1), n_shards)
         self._n_pad = n_pad
-        # the caller's array, never a second copy of it: at float32
-        # with no padding row to add, the table that is uploaded IS the
-        # caller's (8.5 GB at 4.16 M x 512 would otherwise sit twice in
-        # host RAM for the life of the server)
+        # the caller's array, never a second copy of it: with no
+        # padding row to add, the float32 table that is uploaded (or
+        # that the refine reads) IS the caller's, which may be a file
+        # mapped into memory (8.5 GB at 4.16 M x 512 would otherwise sit
+        # twice in host RAM for the life of the server, 19 GB at 9.4 M)
         self._factors = factors
-        if n_pad == self.n_items:
-            padded = factors
-        else:
-            padded = np.zeros((n_pad, self.rank), np.float32)
-            padded[: self.n_items] = factors
-        # residency tier: the resident row storage + the f32 matrix the
-        # device rescore (and the parity oracle) actually scores
-        # against. Norms fold from the DEQUANTIZED rows, so the cosine
-        # path is self-consistent with stage 2's exact rescore.
         scale_host: Optional[np.ndarray] = None
-        if precision == "int8":
-            y_host, scale_host = quantize_rows_int8(padded)
-            deq = dequantize_rows_int8(y_host, scale_host)
-        elif precision == "bf16":
-            y_host = padded.astype(jnp.bfloat16)
-            deq = y_host.astype(np.float32)
-        else:
-            y_host, deq = padded, padded
-        # float32 keeps no staging copy: dequantized_factors() hands
-        # back the caller's array
-        self._y_host = y_host if precision != "float32" else None
-        self._scale_host = scale_host
-        # the final exact-rescore stage reads the ORIGINAL f32 rows out
-        # of host RAM (every engine keeps item_factors host-resident
-        # for pickling anyway) — only the quantized rows occupy HBM
-        self._y_f32_host: Optional[np.ndarray] = (
-            padded if precision != "float32" else None
-        )
-        rn = np.zeros(n_pad, np.float32)
-        rn[: self.n_items] = _reciprocal_norms(deq[: self.n_items])
-        # the ORIGINAL rows' norms stay on the host (4 bytes an item):
-        # the exact rescore and an engine's host path read them
-        if precision != "float32":
-            rn_exact = np.zeros(n_pad, np.float32)
-            rn_exact[: self.n_items] = _reciprocal_norms(factors)
-        else:
+        if precision == "float32":
+            if n_pad == self.n_items:
+                padded = factors
+            else:
+                padded = np.zeros((n_pad, self.rank), np.float32)
+                padded[: self.n_items] = factors
+            y_host = padded
+            rn = np.zeros(n_pad, np.float32)
+            rn[: self.n_items] = _reciprocal_norms(padded[: self.n_items])
             rn_exact = rn
+            # float32 keeps no second table: dequantized_factors() hands
+            # back the caller's array, and nothing refines
+            self._y_f32_host: Optional[np.ndarray] = None
+        else:
+            # residency tier: the resident row storage, made in row
+            # blocks. Norms fold from the DEQUANTIZED rows, so the
+            # cosine path is self-consistent with stage 2's exact
+            # rescore; the ORIGINAL rows' norms stay on the host (4
+            # bytes an item) for the refine and an engine's host path
+            y_host, scale_host, rn, rn_exact = _quantize_resident(
+                factors, n_pad, precision
+            )
+            # the final exact-rescore stage reads the ORIGINAL f32 rows
+            # on the host, where every engine keeps them (in RAM, or as
+            # the mapped file of a PersistentModel): only the quantized
+            # rows occupy HBM
+            if n_pad == self.n_items:
+                self._y_f32_host = factors
+            else:
+                self._y_f32_host = np.zeros((n_pad, self.rank), np.float32)
+                self._y_f32_host[: self.n_items] = factors
         self._rn_f32_host: Optional[np.ndarray] = rn_exact
         self._valid = np.zeros(n_pad, bool)
         self._valid[: self.n_items] = True
@@ -943,6 +1016,14 @@ class ItemRetriever:
             # per-(n_local, flags, widths, shortlist) jitted shard_map
             # stage-1 executables
             self._stage1_cache: Dict[tuple, object] = {}
+        # the quantized staging copy goes once it is on the device (an
+        # int8 table is 4.8 GB at 9.4 M x 512): dequantized_factors()
+        # reads the device. (What the TPU runtime itself keeps on the
+        # host after an upload, 14.2 GB for these 4.8, does not depend
+        # on the transfer's size: uploading in blocks was tried and
+        # changed nothing, PERF.md PR 33.)
+        jax.block_until_ready(self._y_dev)
+        del y_host
         self._batches = 0
         self._freed = False
         # per-(n_local, flags, shapes) executables this instance already
@@ -1088,15 +1169,17 @@ class ItemRetriever:
     def dequantized_factors(self) -> np.ndarray:
         """Host f32 matrix the device path actually scores against —
         the original factors for float32, the dequantized resident rows
-        otherwise. This is the reference the exact-rescore parity
+        otherwise, READ BACK FROM THE DEVICE (no quantized copy stays on
+        the host). This is the reference the exact-rescore parity
         oracle (tests/bench) feeds to ``naive_topn_reference``."""
-        if self.precision == "int8":
-            deq = dequantize_rows_int8(self._y_host, self._scale_host)
-        elif self.precision == "bf16":
-            deq = self._y_host.astype(np.float32)
-        else:
+        if self.precision == "float32":
             return self._factors
-        return deq[: self.n_items]
+        rows = np.asarray(self._y_dev)[: self.n_items]
+        if self.precision == "int8":
+            return dequantize_rows_int8(
+                rows, np.asarray(self._scale_dev)[: self.n_items]
+            )
+        return rows.astype(np.float32)
 
     # --- the hot path ---
 
@@ -1261,12 +1344,12 @@ class ItemRetriever:
             with _tracing.stage(_tracing.DEVICE_WAIT):
                 host = np.asarray(packed)[:b]
             _m_shard_seconds().observe(time.perf_counter() - t0)
+            if self.precision != "float32":
+                return self._refine_exact(
+                    q, host, n_dev, n, positive_only,
+                    row_norm if normalize == "rows" else normalize,
+                )
             with _tracing.stage(_tracing.BUILD):
-                if self.precision != "float32":
-                    return self._refine_exact(
-                        q, host, n_dev, n, positive_only,
-                        row_norm if normalize == "rows" else normalize,
-                    )
                 return unpack_topn(host, n)
 
         n_local = min(n_dev, self._n_pad // self._n_shards)
@@ -1337,29 +1420,43 @@ class ItemRetriever:
         B·c·n·k host FLOPs per batch, negligible next to the B·N·k the
         device just did; recall@n is then limited only by whole-shortlist
         misses and id parity vs the exact path holds by construction."""
-        s_d, i_d = unpack_topn(packed, n_dev)
-        rows = self._y_f32_host[i_d]  # [B, n_dev, k] gather, host RAM
-        sc = np.einsum(
-            "bk,bnk->bn", q, rows, optimize=True
-        ).astype(np.float32)
-        if isinstance(normalize, np.ndarray):  # per-row cosine flags
-            sc = sc * np.where(
-                normalize[:, None], self._rn_f32_host[i_d], np.float32(1.0)
+        with _tracing.stage(_tracing.REFINE):
+            s_d, i_d = unpack_topn(packed, n_dev)
+            # [B, n_dev, k] gather from host RAM, or from the pages of
+            # a mapped file (the page cache's, or the disk's)
+            rows = self._y_f32_host[i_d]
+            sc = np.einsum(
+                "bk,bnk->bn", q, rows, optimize=True
+            ).astype(np.float32)
+            if isinstance(normalize, np.ndarray):  # per-row cosine flags
+                sc = sc * np.where(
+                    normalize[:, None], self._rn_f32_host[i_d],
+                    np.float32(1.0),
+                )
+            elif normalize:
+                sc = sc * self._rn_f32_host[i_d]
+            if positive_only:
+                sc = np.where(sc > 0, sc, -np.inf)
+            # dead device slots (masked / past live-candidate count)
+            # stay dead regardless of what their placeholder id
+            # rescores to
+            sc = np.where(s_d == -np.inf, -np.inf, sc)
+            # descending exact score, ties broken by LOWEST global id —
+            # the same order naive_topn_reference's stable sort produces
+            order = np.lexsort((i_d, -sc), axis=1)[:, :n]
+            scores = np.take_along_axis(sc, order, axis=1)
+            idx = np.take_along_axis(i_d, order, axis=1)
+            # what the float32 originals bought: the answers whose live
+            # ids or their order differ from the device's own best n
+            live = scores > -np.inf
+            changed = ((idx != i_d[:, :n]) & live).any(axis=1)
+            _m_shortlist_rows().labels(component=self.component).inc(
+                i_d.size
             )
-        elif normalize:
-            sc = sc * self._rn_f32_host[i_d]
-        if positive_only:
-            sc = np.where(sc > 0, sc, -np.inf)
-        # dead device slots (masked / past live-candidate count) stay
-        # dead regardless of what their placeholder id rescores to
-        sc = np.where(s_d == -np.inf, -np.inf, sc)
-        # descending exact score, ties broken by LOWEST global id — the
-        # same order naive_topn_reference's stable sort produces
-        order = np.lexsort((i_d, -sc), axis=1)[:, :n]
-        return (
-            np.take_along_axis(sc, order, axis=1),
-            np.take_along_axis(i_d, order, axis=1),
-        )
+            _m_refine_changed().labels(component=self.component).inc(
+                int(changed.sum())
+            )
+            return scores, idx
 
     def _record_skew(
         self, cand: np.ndarray, host: np.ndarray, n: int, n_local: int

@@ -61,9 +61,22 @@ def normalize_rows(factors: np.ndarray) -> np.ndarray:
 
 @jax.jit
 def _cosine_sum(query_normed, all_normed):
-    # [Q, k] x [k, N] -> sum over Q -> [N]
-    sims = jnp.dot(query_normed, all_normed.T, preferred_element_type=jnp.float32)
+    # [Q, k] x [k, N] -> sum over Q -> [N]. ``highest``: a TPU's default
+    # matmul precision rounds float32 operands to bfloat16, and these
+    # cosines have to order items as a float32 host reference does (the
+    # fault PR 21 found in the recommendation path)
+    sims = jnp.dot(
+        query_normed, all_normed.T, preferred_element_type=jnp.float32,
+        precision="highest",
+    )
     return sims.sum(axis=0)
+
+
+def _device_bytes_limit(device) -> Optional[int]:
+    """What the device says it can hold (None where the backend reports
+    nothing, as the CPU's does)."""
+    stats = device.memory_stats() or {}
+    return stats.get("bytes_limit")
 
 
 class SimilarityScorer:
@@ -80,10 +93,23 @@ class SimilarityScorer:
         mesh: Optional[Mesh] = None,
         axis: str = "data",
     ):
-        self.normed = normalize_rows(factors)
         if mesh is not None and mesh.shape[axis] == 1:
             mesh = None
         self.mesh = mesh
+        devices = (
+            list(mesh.devices.flat) if mesh is not None else jax.devices()[:1]
+        )
+        need = int(np.prod(np.shape(factors))) * 4 // len(devices)
+        limit = _device_bytes_limit(devices[0])
+        if limit is not None and need > limit:
+            raise ValueError(
+                f"SimilarityScorer keeps the normalized table in float32 "
+                f"on the device: {need:,} B a device, which holds "
+                f"{limit:,} B. Serve a table of this size through "
+                "ItemRetriever with precision=\"int8\" or \"bf16\" (the "
+                "engines' `precision` param) instead"
+            )
+        self.normed = normalize_rows(factors)
         if mesh is None:
             self._dev = jax.device_put(jnp.asarray(self.normed))
         else:

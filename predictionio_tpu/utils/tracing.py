@@ -182,7 +182,13 @@ BUILD = "build"
 # inline, and the batch's own stacking and padding
 STORE_READ = "store_read"
 MASK_PREP = "mask_prep"
-BATCH_STAGES = (HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD, STORE_READ, MASK_PREP)
+# the quantized retrieval tier's host refine (ops/retrieval.py
+# ``_refine_exact``): the device's shortlist rescored against the
+# original float32 rows, which may be a file mapped into memory
+REFINE = "refine"
+BATCH_STAGES = (
+    HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD, STORE_READ, MASK_PREP, REFINE,
+)
 
 # the per-batch accumulator of stage() durations, bound by the engine
 # server's executor for the length of one serve_batch

@@ -111,11 +111,11 @@ ENGINES = {"fake": _fake, "reco": _reco, "ecom": _ecom}
 # host_prep (supplement) and build (serving.serve); the recommendation
 # engine's float32 path names its dispatch and its blocking fetch too;
 # the e-commerce engine carves its store read and its list assembly
-# out of host prep
+# out of host prep (its table is float32: nothing is refined)
 ENTERED = {
     "fake": (tr.HOST_PREP, tr.BUILD),
     "reco": (tr.HOST_PREP, tr.DISPATCH, tr.DEVICE_WAIT, tr.BUILD),
-    "ecom": BATCH_STAGES,
+    "ecom": tuple(s for s in BATCH_STAGES if s != tr.REFINE),
 }
 
 

@@ -202,6 +202,33 @@ def test_ecommerce_fused_program_compiles_at_the_taobao_shape(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+@pytest.mark.parametrize("exclude, include", [(16, 1), (64, 256)])
+@pytest.mark.parametrize("batch", [8, 16, 32])
+def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
+        one_chip, batch, exclude, include):
+    """The int8 two-stage program of the Similar Product cell over its
+    warm ladder (every batch size at the narrowest and at the widest
+    pair of lists: 6 of the 12 executables, 15-20 s of compile each;
+    all 12 compiled here when PR 33 was built): 9,400,000 x 512 int8
+    resident (4.8 GB; in float32 it would not fit), the device's
+    candidate list 64 wide and its stage-1 shortlist 256, cosine
+    scores, four category codes, all in the batch's one packed operand.
+    Each has to fit one chip beside the table."""
+    n, k = 9_400_000, 512
+    widths = (exclude, include, 4)
+    compiled = retrieval._fused_topn_single_2s.lower(
+        _shape((batch, k + sum(widths) + 3), jnp.int32, one_chip),
+        _shape((n, k), jnp.int8, one_chip),
+        _shape((n,), jnp.float32, one_chip),  # per-row scales
+        _shape((n,), jnp.float32, one_chip),  # reciprocal norms
+        _shape((n,), jnp.bool_, one_chip),  # candidacy mask
+        _shape((n, 1), jnp.int32, one_chip),  # per-item category codes
+        n=64, shortlist=256, positive_only=True, normalize=True,
+        precision="int8", widths=widths,
+    ).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 def test_int8_stage1_shards_over_four_chips(mesh4):
     """The ``shard_map`` stage-1 program ``ItemRetriever`` builds on a
     mesh: each device holds a quarter of the quantized catalog."""
