@@ -57,18 +57,26 @@ def child_env(work, *, host_only=False):
     env = dict(os.environ)
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("BENCH_RUN", None)  # the driver's own; nothing here reads it
+    # Each repository has a source of its own, as upstream's
+    # conf/pio-env.sh.template lays a deployment out. Set-up children run
+    # side by side (an instance written while a store is bulk-loaded):
+    # sqlite has one write lock a file, and a writer that waits for it
+    # longer than the client's busy timeout fails, so no two of them may
+    # write one file. A source's name holds no underscore.
     env.update(
         TMPDIR=work,  # the profile spool and every temp file stay in here
         PIO_LOG_FORMAT="json",
         PIO_FS_BASEDIR=os.path.join(work, "fs"),
-        PIO_STORAGE_SOURCES_SQLITE_TYPE="sqlite",
-        PIO_STORAGE_SOURCES_SQLITE_PATH=os.path.join(work, "pio.db"),
+        PIO_STORAGE_SOURCES_SQLMETA_TYPE="sqlite",
+        PIO_STORAGE_SOURCES_SQLMETA_PATH=os.path.join(work, "meta.db"),
+        PIO_STORAGE_SOURCES_SQLEVENTS_TYPE="sqlite",
+        PIO_STORAGE_SOURCES_SQLEVENTS_PATH=os.path.join(work, "events.db"),
         PIO_STORAGE_SOURCES_LOCALFS_TYPE="localfs",
         PIO_STORAGE_SOURCES_LOCALFS_PATH=os.path.join(work, "models"),
         PIO_STORAGE_REPOSITORIES_METADATA_NAME="pio_meta",
-        PIO_STORAGE_REPOSITORIES_METADATA_SOURCE="SQLITE",
+        PIO_STORAGE_REPOSITORIES_METADATA_SOURCE="SQLMETA",
         PIO_STORAGE_REPOSITORIES_EVENTDATA_NAME="pio_event",
-        PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE="SQLITE",
+        PIO_STORAGE_REPOSITORIES_EVENTDATA_SOURCE="SQLEVENTS",
         PIO_STORAGE_REPOSITORIES_MODELDATA_NAME="pio_model",
         PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE="LOCALFS",
     )
@@ -105,9 +113,22 @@ def stop_all() -> None:
         stop_child(proc)
 
 
+def last_line(text) -> str:
+    """The last non-empty line of a child's output: of a child that died
+    of an exception the traceback's last, the error and its message; of
+    the program's JSON log, the record's message."""
+    lines = [ln.strip() for ln in text.splitlines() if ln.strip()]
+    if not lines:
+        return "(no output)"
+    for rec in json_lines(lines[-1]):
+        lines += str(rec.get("message", "")).split("\n")
+    return [ln for ln in lines if ln.strip()][-1].strip()[:300]
+
+
 def run_child(name, cmd, env, work, timeout, watch=None):
     """Run one child to its end; returns (seconds, combined output, spawn
-    time). Raises CellFailed on a non-zero exit or a timeout. ``watch``
+    time). Raises CellFailed on a non-zero exit or a timeout, with the
+    child's last line of output as the reason. ``watch``
     maps a name to a piece of text: the harness's own clock is read when
     that text first shows in the child's output, into ``watch[name]``."""
     log_path = os.path.join(work, f"{name}.log")
@@ -139,7 +160,7 @@ def run_child(name, cmd, env, work, timeout, watch=None):
     if rc != 0:
         say(phase=name, failed=True, rc=rc, seconds=round(seconds, 2),
             tail=text[-3000:])
-        raise CellFailed(f"{name}: exit {rc}")
+        raise CellFailed(f"{name}: exit {rc}: {last_line(text)}")
     return seconds, text, t0
 
 
@@ -281,7 +302,8 @@ class Deployed:
             if self.proc.poll() is not None:
                 say(phase=self.name, failed=True, rc=self.proc.returncode,
                     tail=self.log_text()[-3000:])
-                raise CellFailed(f"{self.name}: server exited")
+                raise CellFailed(
+                    f"{self.name}: exited: {last_line(self.log_text())}")
             try:
                 status = http_json(self.url + "/status.json", timeout=5)
                 return time.time() - self.t0, status
@@ -289,7 +311,8 @@ class Deployed:
                 time.sleep(0.25)
         say(phase=self.name, failed=True, rc="never ready",
             tail=self.log_text()[-3000:])
-        raise CellFailed(f"{self.name}: never became ready")
+        raise CellFailed(
+            f"{self.name}: never became ready: {last_line(self.log_text())}")
 
     def metrics(self):
         return http_json(self.url + "/metrics")

@@ -27,8 +27,8 @@ from ..cells import (
 )
 from ..children import (
     BENCH, CHILDREN, UPGRADE_CHECK_LINE, CellFailed, Deployed, child_env,
-    device_of, free_port, http_json, json_lines, metric_samples, pio,
-    run_child, say, stop_child,
+    device_of, free_port, http_json, json_lines, last_line, metric_samples,
+    pio, run_child, say, stop_child,
 )
 from .open_loop_queries import ACCESS_KEY, _capture, _tick
 
@@ -53,17 +53,23 @@ class EventServer:
         )
         CHILDREN.append(self.proc)
 
+    def log_text(self):
+        with open(self.log_path, errors="replace") as f:
+            return f.read()
+
     def wait_ready(self, timeout):
         deadline = time.time() + timeout
         while time.time() < deadline:
             if self.proc.poll() is not None:
-                raise CellFailed("eventserver: exited")
+                raise CellFailed(
+                    f"eventserver: exited: {last_line(self.log_text())}")
             try:
                 http_json(f"http://127.0.0.1:{self.port}/", timeout=5)
                 return
             except Exception:  # a boundary: not listening yet
                 time.sleep(0.25)
-        raise CellFailed("eventserver: never became ready")
+        raise CellFailed(
+            f"eventserver: never became ready: {last_line(self.log_text())}")
 
     def stop(self):
         stop_child(self.proc)

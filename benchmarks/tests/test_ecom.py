@@ -1,13 +1,16 @@
 """The e-commerce cell: its tiny CPU rehearsal end to end, the inputs it
 shares with the program, and the control of its comparison: planted
 faults (a seen item served, a stale constraint, a category ignored, a
-viewed item served again, a query answered on the host, bfloat16 products) each turn ``correct`` false."""
+viewed item served again, a query answered on the host, bfloat16 products) each turn ``correct`` false.
+The set-up's two stages that run side by side share no write lock: the
+instance is written while the event store's file is held."""
 
 from __future__ import annotations
 
 import copy
 import json
 import os
+import sqlite3
 import sys
 
 import numpy as np
@@ -239,3 +242,48 @@ def test_schedule_is_the_same_for_parent_and_generator_and_keeps_the_mix():
         assert a["users"][k] == a["users"][j]
         assert a["due"][j] <= a["due"][k] - traffic["return_after_s"]
     assert all(50 <= len(w) <= 200 or len(w) < 50 for w in a["white"].values())
+
+
+def test_the_instance_is_written_while_the_event_store_is_locked(tmp_path):
+    """The cell's set-up writes the instance beside the bulk load, whose
+    few transactions hold the event store's write lock for seconds each
+    (longer, on a busy disk, than the program's sqlite client waits: 5 s).
+    Here the lock of the EVENTDATA file is held for the whole of the
+    tiny ``write_instance`` stage: with metadata in that same file the
+    stage dies of ``database is locked``; with a file of its own it
+    returns an instance id, and that instance is in the metadata file."""
+    from lib import cells, children
+
+    work = str(tmp_path / "work")
+    os.makedirs(work)
+    bench, manifest = tiny_ecom.make_bench(str(tmp_path / "bench"))
+    run = tiny_ecom.harness.build_run(
+        manifest, tiny_ecom.CELL, 2**31 + 9, 1.0, False, work, bench=bench,
+        require_tpu=False)
+    variant = cells.write_variant(run)
+    config_path = os.path.join(work, "config.json")
+    with open(config_path, "w") as f:
+        json.dump(run.config, f)
+    env = children.child_env(work, host_only=True)
+    children.run_child("app_new", children.pio("app", "new", "bench"),
+                       env, work, 120)
+    holder = sqlite3.connect(
+        tiny_ecom.tiny.sqlite_path(env, "EVENTDATA"), timeout=0.1)
+    holder.execute("BEGIN IMMEDIATE")  # as the bulk import's transaction does
+    try:
+        _, text, _ = children.run_child(
+            "write_instance",
+            ecom_queries.ecom_stage("write_instance", work, variant,
+                                    config_path, run.seed),
+            env, work, 120)
+    finally:
+        holder.rollback()
+        holder.close()
+    written = children.json_lines(text)[-1]
+    assert written["instance_id"] and written["model_bytes"] > 0
+    meta = sqlite3.connect(tiny_ecom.tiny.sqlite_path(env, "METADATA"))
+    (table,) = [r[0] for r in meta.execute(
+        "SELECT name FROM sqlite_master WHERE type='table'")
+        if "engine_instances" in r[0]]
+    assert meta.execute(f"SELECT id FROM {table}").fetchall() == [
+        (written["instance_id"],)]

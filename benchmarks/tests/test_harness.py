@@ -1,11 +1,14 @@
 """The harness end to end at the tiny size, for both kinds of cell; a CPU
 run refused as a measurement; a new configuration, mix, cell and metric
-added as files and entries only; the seeded instance read back."""
+added as files and entries only; the seeded instance read back; the
+children's storage layout (a sqlite file a repository) and the reason a
+failed child leaves on the ``no result:`` line."""
 
 from __future__ import annotations
 
 import json
 import os
+import sqlite3
 import subprocess
 import sys
 
@@ -143,3 +146,124 @@ def test_seeded_instance_reads_back(tmp):
     assert np.array_equal(np.load(os.path.join(d, "item_factors.npy")),
                           data.seeded_factors(30, 8, 77, 1))
     assert np.array_equal(np.load(os.path.join(d, "item_ids.npy")), np.arange(30))
+
+
+def test_children_keep_metadata_and_events_in_files_of_their_own(tmp_path):
+    """Set-up children write side by side, and sqlite has one write lock
+    a file: the two repositories that they write name different files,
+    both inside the run's scratch directory, for every kind of child."""
+    from lib import children
+
+    work = str(tmp_path)
+    for host_only in (True, False):
+        env = children.child_env(work, host_only=host_only)
+        meta = tiny.sqlite_path(env, "METADATA")
+        events = tiny.sqlite_path(env, "EVENTDATA")
+        assert meta != events
+        assert {os.path.dirname(meta), os.path.dirname(events)} == {work}
+        assert env["PIO_STORAGE_REPOSITORIES_MODELDATA_SOURCE"] == "LOCALFS"
+        assert not any("pio.db" in v for k, v in env.items()
+                       if k.startswith("PIO_STORAGE_"))
+
+
+def test_a_metadata_write_does_not_wait_for_the_event_stores_lock(tmp_path):
+    """The storage registry as the layout leans on it: two sources of type
+    sqlite with different PATHs are independent files, and an insert into
+    a METADATA DAO returns while another connection holds the write lock
+    of the EVENTDATA file (longer than the client's busy timeout would
+    have waited: it never waits)."""
+    from lib import children
+    from predictionio_tpu.data.storage import Storage
+    from predictionio_tpu.data.storage.base import App
+
+    env = children.child_env(str(tmp_path), host_only=True)
+    config = {k: v for k, v in env.items() if k.startswith("PIO_STORAGE_")}
+    storage = Storage(config)
+    storage.get_l_events().init(1)
+    meta = tiny.sqlite_path(env, "METADATA")
+    events = tiny.sqlite_path(env, "EVENTDATA")
+    holder = sqlite3.connect(events, timeout=0.1)
+    holder.execute("BEGIN IMMEDIATE")
+    try:
+        apps = Storage(config).get_meta_data_apps()  # a client opened under the lock
+        app_id = apps.insert(App(id=0, name="beside", description=None))
+        assert apps.get_by_name("beside").id == app_id
+        with pytest.raises(sqlite3.OperationalError, match="locked"):
+            other = sqlite3.connect(events, timeout=0.1)
+            other.execute("BEGIN IMMEDIATE")  # the lock is real
+    finally:
+        holder.rollback()
+        holder.close()
+    tables = "SELECT name FROM sqlite_master WHERE type='table'"
+    in_meta = {r[0] for r in sqlite3.connect(meta).execute(tables)}
+    in_events = {r[0] for r in sqlite3.connect(events).execute(tables)}
+    assert any("apps" in t for t in in_meta)
+    assert not any("apps" in t for t in in_events)
+    assert any("pio_event" in t for t in in_events)
+    assert not any("pio_event" in t for t in in_meta)
+
+
+DYING = "raise ValueError('the reason, on one line')"
+
+
+def test_a_child_that_dies_says_why(tmp_path):
+    """``run_child``'s CellFailed ends in the traceback's last line, a
+    timeout's in the last line the child wrote."""
+    from lib import children
+
+    work = str(tmp_path)
+    env = children.child_env(work, host_only=True)
+    with pytest.raises(children.CellFailed) as e:
+        children.run_child("dying", [sys.executable, "-c", DYING], env, work, 60)
+    assert str(e.value) == (
+        "dying: exit 1: ValueError: the reason, on one line")
+    slow = "import time; print('still loading', flush=True); time.sleep(60)"
+    with pytest.raises(children.CellFailed) as e:
+        children.run_child("slow", [sys.executable, "-c", slow], env, work, 1.0)
+    assert str(e.value) == "slow: exit timeout: still loading"
+    assert children.last_line("") == "(no output)"
+    assert children.last_line(
+        'x\n{"level": "ERROR", "message": "first\\nthe cause"}\n\n'
+    ) == "the cause"
+
+
+def test_a_failed_stage_is_on_the_no_result_line(tmp_path, monkeypatch, capsys):
+    """The harness's one line on standard error names the stage, its exit
+    code and its reason; no result line is printed and the code is 2."""
+    harness = tiny.harness
+
+    def dies(run):
+        harness.children.run_child(
+            "write_instance", [sys.executable, "-c", DYING],
+            harness.children.child_env(run.work, host_only=True), run.work, 60)
+
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    monkeypatch.setattr(harness, "run_cell", dies)
+    monkeypatch.setattr(harness, "keep_logs", lambda work, args: None)
+    rc = harness.main(["--workload", tiny.SERVE, "--seed", "3",
+                       "--seconds", "1", "--trace", "0"])
+    out, err = capsys.readouterr()
+    assert rc == 2
+    assert err.splitlines()[-1] == (
+        "no result: write_instance: exit 1: "
+        "ValueError: the reason, on one line")
+    assert not any(ln.startswith('{"correct"') for ln in out.splitlines())
+
+
+def test_a_server_that_exits_says_why(tmp_path):
+    """``Deployed.wait_ready`` gives the last line of the server's log."""
+    from lib import children
+
+    work = str(tmp_path)
+    server = children.Deployed(
+        "deploy", work, os.path.join(work, "no-such-engine.json"),
+        "no-such-instance", children.child_env(work, host_only=True))
+    try:
+        with pytest.raises(children.CellFailed) as e:
+            server.wait_ready(timeout=120)
+    finally:
+        server.stop()
+    text = str(e.value)
+    assert text.startswith("deploy: exited: ") and len(text) > len(
+        "deploy: exited: ") and "\n" not in text
+    assert text.endswith(children.last_line(server.log_text()))

@@ -1,7 +1,8 @@
 """``host_gaps`` over synthetic planes in ``reduce_planes``' tuple form,
-and the new metrics of the tiny CPU serving cell: the ten stage metrics
-in its traced line, none in its untraced one; the two collector metrics
-over a window that holds a full collection."""
+and the stage metrics of the tiny CPU serving cell: the ten stage means
+and the executor's count of batches that met no wait in its traced line,
+none in its untraced one; the two collector metrics over a window that
+holds a full collection."""
 
 from __future__ import annotations
 
@@ -85,6 +86,7 @@ STAGE_METRICS = [
     "serve_slot_wait_mean_ms", "serve_predict_mean_ms", "serve_finish_mean_ms",
     "serve_batch_host_prep_ms", "serve_batch_dispatch_ms",
     "serve_batch_device_wait_ms", "serve_batch_build_ms",
+    "serve_batches_immediate",  # PR 27: batches that found a serve slot free
 ]
 # a full collection comes once in a thousand requests or so: the chip's
 # 16,000-request window holds a dozen, the tiny cell's 80 requests none
@@ -97,9 +99,16 @@ def listed_metrics():
 
 
 def test_the_manifest_lists_the_stage_metrics_for_the_serving_cell():
+    """Every serving cell reports the stage metrics (the steady cell, and
+    since PRs 28 and 33 the e-commerce and similar-product cells): each
+    lists all the cells that report the end-to-end metric it moves."""
     listed = listed_metrics()
+    with open(os.path.join(tiny.ROOT, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    (moved,) = [m for m in manifest["end_to_end"] if m["name"] == "query_p50_ms"]
+    assert tiny.SERVE in moved["workloads"]
     for name in STAGE_METRICS + GC_METRICS:
-        assert listed[name]["workloads"] == [tiny.SERVE]
+        assert listed[name]["workloads"] == moved["workloads"]
         assert listed[name]["source"] == "program_counter"
         assert listed[name]["moves"] == "query_p50_ms"
         assert os.path.isfile(
@@ -144,11 +153,19 @@ def test_serve_cell_traced_line_holds_the_stage_metrics(tmp):
     assert line["correct"] is True
     got = {k: line["metrics"][k]["value"] for k in STAGE_METRICS}
     # what the stages add up to: the four per-request means make the
-    # server's mean less the parse, the batch stages lie inside predict
+    # server's mean less the parse, the batch stages lie inside predict.
+    # The parse is a fixed 0.1-0.2 ms: 3 % of the chip's request, and
+    # since PR 27 took the 2 ms batching window out, 11-12 % of the 2 ms
+    # request of this size on this CPU
     four = sum(got[f"serve_{k}_mean_ms"]
                for k in ("queue_wait", "slot_wait", "predict", "finish"))
     assert four <= got["serve_server_mean_ms"] <= got["serve_http_mean_ms"]
-    assert four == pytest.approx(got["serve_server_mean_ms"], rel=0.1)
+    assert four >= 0.75 * got["serve_server_mean_ms"]
+    batch = sum(got[f"serve_batch_{k}_ms"]
+                for k in ("host_prep", "dispatch", "device_wait", "build"))
+    assert 0.5 * got["serve_predict_mean_ms"] <= batch <= got["serve_predict_mean_ms"]
+    # queries sent a few at a time find the one serve slot free most times
+    assert 0 < got["serve_batches_immediate"] <= line["attempted"]
 
 
 def test_serve_cell_untraced_line_holds_none_of_them(tmp):

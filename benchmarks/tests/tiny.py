@@ -67,6 +67,14 @@ def make_bench(tmp):
     return bench, manifest
 
 
+def sqlite_path(env, repository):
+    """The sqlite file behind one repository (METADATA, EVENTDATA) of a
+    child's environment (``children.child_env``)."""
+    source = env[f"PIO_STORAGE_REPOSITORIES_{repository}_SOURCE"]
+    assert env[f"PIO_STORAGE_SOURCES_{source}_TYPE"] == "sqlite"
+    return env[f"PIO_STORAGE_SOURCES_{source}_PATH"]
+
+
 def tiny_run(tmp, workload, *, seed=5, seconds=2.0, trace=False, bench=None,
              manifest=None):
     """Drive one run at the tiny size on the CPU; returns the result
