@@ -104,6 +104,7 @@ from __future__ import annotations
 import concurrent.futures
 import functools
 import logging
+import math
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -220,6 +221,30 @@ def _quantize_resident(factors: np.ndarray, n_pad: int, precision: str):
     return rows, scale, rn, rn_exact
 
 
+# rows of a float32 table that go up at a time (256 MB at rank 512)
+_UPLOAD_ROWS = 1 << 17
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _write_rows(table, block, at):
+    """``table`` with ``block`` at row ``at``, in ``table``'s own buffer."""
+    return jax.lax.dynamic_update_slice(table, block, (at, 0))
+
+
+def _upload_padded(rows: np.ndarray, n_pad: int, device):
+    """``[n_pad, k]`` on ``device`` (None: the default device): the
+    caller's ``[n, k]`` rows with zero rows after them, written in row
+    blocks into one donated buffer. The padding rows are made on the
+    device: a padded copy of an 8.5 GB table fits neither the host's
+    memory beside the table nor the chip's beside the result. One block
+    is in flight at a time."""
+    table = jnp.zeros((n_pad, rows.shape[1]), rows.dtype, device=device)
+    for a in range(0, len(rows), _UPLOAD_ROWS):
+        block = jax.device_put(rows[a:a + _UPLOAD_ROWS], device)
+        table = jax.block_until_ready(_write_rows(table, block, a))
+    return table
+
+
 def _batch_sizes(max_batch: int) -> Tuple[int, ...]:
     """The padded batch sizes: 8 doubling until ``max_batch`` is held."""
     sizes = [8]
@@ -245,25 +270,39 @@ def _longest(lists) -> int:
 
 # an item id splits into a high and a low digit, id = hi * _LO + lo
 _LO = 2048
+# the width of one block of the block-wise top-k
+_BLOCK = 1024
+# resident rows come in whole blocks of both (``ItemRetriever`` pads its
+# table once, at build), so a score block, its masks and its block
+# maxima are reshapes of one another and no program pads or slices
+_ROW_BLOCK = math.lcm(_LO, _BLOCK)
 # lists wider than this are folded in pieces, so that the one-hots of a
 # [128, 8192] block never stand in memory at once
 _LIST_CHUNK = 1024
 
 
 def _membership(ids, rows: int):
-    """[B, rows] bool: does row j appear in ``ids[b]``? Computed as the
+    """[B, rows] bool: does row j appear in ``ids[b]``? ``rows`` is whole
+    blocks of ``_LO`` (a resident table's always are). Computed as the
     product of two one-hot encodings (the id's high digit, its low
     digit) on the matrix unit: ``grid[b, hi, lo] = sum_w [hi_w == hi] *
-    [lo_w == lo]``. A scatter of ``[B, W]`` ids into a ``[B, rows]``
+    [lo_w == lo]``, handed back as it is made: its ``rows / _LO`` x
+    ``_LO`` cells ARE the rows, nothing is sliced off. A scatter of
+    ``[B, W]`` ids into a ``[B, rows]``
     mask lowers on the TPU to a loop over the batch's rows that rewrites
     each whole row (0.7 ms a row at 4.16 M items: over half of the fused
     program at a batch of 32, PERF.md PR 28); this costs 2·B·W·rows
     operations in one bfloat16 pass, exact because its operands are 0
     and 1. Ids outside ``[0, rows)`` (the sentinel of padded slots, ids
-    owned by another shard) match no high digit or fall into the grid's
-    unread tail, and are dropped."""
-    n_hi = -(-rows // _LO)
+    owned by another shard) match no high digit and are dropped; the id
+    of a padding row marks that row, which the resident mask has
+    already taken out. A width of 1 (what a batch without a list of
+    this kind ships) is one compare an element inside the scoring pass:
+    no grid is made, and none has to be laid out as the scores are."""
     b, w = ids.shape
+    if w == 1:  # "no list", or a list of one: a compare, not a grid
+        return ids == jnp.arange(rows, dtype=jnp.int32)[None, :]
+    n_hi = rows // _LO
     hi_of = jnp.arange(n_hi, dtype=jnp.int32)[None, None, :]
     lo_of = jnp.arange(_LO, dtype=jnp.int32)[None, None, :]
 
@@ -287,11 +326,7 @@ def _membership(ids, rows: int):
             jnp.zeros((b, n_hi, _LO), jnp.float32),
             jnp.moveaxis(pieces, 1, 0),
         )
-    return grid.reshape(b, n_hi * _LO)[:, :rows] > 0
-
-
-# the width of one block of the block-wise top-k
-_BLOCK = 1024
+    return (grid > 0).reshape(b, rows)
 
 
 def _top_k(scores, n: int):
@@ -303,31 +338,40 @@ def _top_k(scores, n: int):
     beats it), the blocks are taken up in index order, so that equal
     scores still go to the lowest index. ``lax.top_k`` over [B, 4.16 M]
     is 8 ms at a batch of 32 and 36 ms at 128 (PERF.md PR 28); the
-    block maxima are one pass over the scores."""
+    block maxima are one pass over the scores. A wide score block is
+    whole blocks (a resident table's rows are), and it is read in the
+    form it was written in: ``[B, rows]`` tiled eight rows by 128
+    columns is ``[B/8, 8, rows/_BLOCK, _BLOCK]`` in the same order, so
+    nothing is padded or copied at any batch size. A narrow one, of
+    any width, goes to ``lax.top_k`` itself. A dead slot (fewer than
+    ``n`` live candidates) holds -inf and the index of any row, a
+    padding row's too: ``ItemRetriever._unpack`` brings those under
+    ``n_items`` on the host."""
     b, rows = scores.shape
-    n_blocks = -(-rows // _BLOCK)
-    if n_blocks <= 2 * n:  # a narrow block: nothing to gain
+    if -(-rows // _BLOCK) <= 2 * n:  # a narrow block: nothing to gain
         return jax.lax.top_k(scores, n)
-    padded = jnp.pad(
-        scores, ((0, 0), (0, n_blocks * _BLOCK - rows)),
-        constant_values=-jnp.inf,
-    ).reshape(b, n_blocks, _BLOCK)
-    _, best = jax.lax.top_k(padded.max(axis=2), n)
+    g = math.gcd(b, 8)
+    blocks = scores.reshape(b // g, g, rows // _BLOCK, _BLOCK)
+    _, best = jax.lax.top_k(blocks.max(axis=3).reshape(b, -1), n)
     best = jnp.sort(best, axis=1)
-    cand = jnp.take_along_axis(padded, best[:, :, None], axis=1)
+    cand = jnp.take_along_axis(
+        blocks, best.reshape(b // g, g, n, 1), axis=2
+    )
     s, j = jax.lax.top_k(cand.reshape(b, n * _BLOCK), n)
-    i = jnp.take_along_axis(best, j // _BLOCK, axis=1) * _BLOCK + j % _BLOCK
-    # a dead slot (fewer than n live candidates) may point into the pad
-    return s, jnp.minimum(i, rows - 1)
+    return s, (
+        jnp.take_along_axis(best, j // _BLOCK, axis=1) * _BLOCK + j % _BLOCK
+    )
 
 
 def _mask_scores(
     scores, allow0, excl, incl, has_incl, positive_only, cat=None
 ):
-    """Shared mask application: ``allow0`` is the resident [rows] mask,
+    """Shared mask application, all of it in the scores' own
+    ``[B, rows]`` form (whole blocks: nothing is padded or sliced):
+    ``allow0`` is the resident [rows] mask, False on the padding rows;
     ``excl``/``incl`` are per-query id lists already mapped into THIS
     score block's index space with out-of-range values pointing past the
-    last row (``mode="drop"`` discards them — sentinel-padded slots and,
+    last row (``_membership`` drops them — sentinel-padded slots and,
     on a shard, ids owned by other shards). ``has_incl`` flags queries
     with a whitelist: only their rows intersect with the scattered
     inclusion mask. ``cat`` = (resident per-item category codes
@@ -851,9 +895,14 @@ class ItemRetriever:
     With a ``mesh`` the factor rows (and the norm/mask vectors) shard
     over ``axis`` and stay resident between queries; without one (or on
     a 1-device mesh) everything lives on ``device`` (default backend
-    device) and retrieval is the fused single-program path. Rows are
-    zero-padded so the row count divides the shard count; padding rows
-    are permanently masked out.
+    device) and retrieval is the fused single-program path. The
+    resident rows are zero-padded ONCE, here, to whole blocks
+    (``_ROW_BLOCK``; on a mesh to whole blocks a shard), so that no
+    program pads, slices or re-tiles a ``[B, rows]`` array on a run.
+    Padding rows exist on the device alone (the caller's table is kept
+    as it is, never copied), are permanently masked out, and no index
+    of one is ever returned
+    (``pio_padding_waste_ratio{site="retrieval_rows"}`` says how many).
 
     ``precision`` selects the residency tier: ``"float32"`` (exact,
     single-stage — the historical path, byte-for-byte), ``"bf16"``, or
@@ -921,24 +970,26 @@ class ItemRetriever:
         self.n_items, self.rank = factors.shape
         n_shards = mesh.shape[axis] if mesh is not None else 1
         self._n_shards = n_shards
-        n_pad = pad_to_multiple(max(self.n_items, 1), n_shards)
+        # whole blocks, and on a mesh whole blocks a shard: the programs
+        # then never pad, slice or re-tile a [B, rows] array (_ROW_BLOCK)
+        n_pad = pad_to_multiple(max(self.n_items, 1), n_shards * _ROW_BLOCK)
         self._n_pad = n_pad
-        # the caller's array, never a second copy of it: with no
-        # padding row to add, the float32 table that is uploaded (or
-        # that the refine reads) IS the caller's, which may be a file
-        # mapped into memory (8.5 GB at 4.16 M x 512 would otherwise sit
-        # twice in host RAM for the life of the server, 19 GB at 9.4 M)
+        _m_padding_waste().labels(site="retrieval_rows").set(
+            (n_pad - self.n_items) / n_pad
+        )
+        # the caller's array, never a second copy of it: the float32
+        # table that is uploaded (or that the refine reads) IS the
+        # caller's, which may be a file mapped into memory (8.5 GB at
+        # 4.16 M x 512 would otherwise sit twice in host RAM for the
+        # life of the server, 19 GB at 9.4 M). The padding rows exist on
+        # the device alone, and no index of one leaves the retriever
+        # (``_unpack``)
         self._factors = factors
         scale_host: Optional[np.ndarray] = None
         if precision == "float32":
-            if n_pad == self.n_items:
-                padded = factors
-            else:
-                padded = np.zeros((n_pad, self.rank), np.float32)
-                padded[: self.n_items] = factors
-            y_host = padded
+            y_host = factors
             rn = np.zeros(n_pad, np.float32)
-            rn[: self.n_items] = _reciprocal_norms(padded[: self.n_items])
+            rn[: self.n_items] = _reciprocal_norms(factors)
             rn_exact = rn
             # float32 keeps no second table: dequantized_factors() hands
             # back the caller's array, and nothing refines
@@ -956,11 +1007,7 @@ class ItemRetriever:
             # on the host, where every engine keeps them (in RAM, or as
             # the mapped file of a PersistentModel): only the quantized
             # rows occupy HBM
-            if n_pad == self.n_items:
-                self._y_f32_host = factors
-            else:
-                self._y_f32_host = np.zeros((n_pad, self.rank), np.float32)
-                self._y_f32_host[: self.n_items] = factors
+            self._y_f32_host = factors
         self._rn_f32_host: Optional[np.ndarray] = rn_exact
         self._valid = np.zeros(n_pad, bool)
         self._valid[: self.n_items] = True
@@ -985,7 +1032,10 @@ class ItemRetriever:
                 jax.device_put(a, device) if device is not None
                 else jax.device_put(a)
             )
-            self._y_dev = put(y_host)
+            self._y_dev = (
+                _upload_padded(y_host, n_pad, device)
+                if precision == "float32" else put(y_host)
+            )
             self._scale_dev = (
                 put(scale_host) if scale_host is not None else None
             )
@@ -997,6 +1047,12 @@ class ItemRetriever:
             self._operand_at = device
         else:
             self._device = None
+            if precision == "float32":
+                # a mesh takes its shards from one host array: a padded
+                # copy that goes once it is up, as the quantized staging
+                # copy does
+                y_host = np.zeros((n_pad, self.rank), np.float32)
+                y_host[: self.n_items] = factors
             self._y_dev = jax.device_put(
                 y_host, NamedSharding(mesh, P(axis, None))
             )
@@ -1350,7 +1406,7 @@ class ItemRetriever:
                     row_norm if normalize == "rows" else normalize,
                 )
             with _tracing.stage(_tracing.BUILD):
-                return unpack_topn(host, n)
+                return self._unpack(host, n)
 
         n_local = min(n_dev, self._n_pad // self._n_shards)
         shortlist = (
@@ -1403,7 +1459,16 @@ class ItemRetriever:
                 q, host, n_dev, n, positive_only,
                 row_norm if normalize == "rows" else normalize,
             )
-        return unpack_topn(host, n)
+        return self._unpack(host, n)
+
+    def _unpack(
+        self, packed: np.ndarray, n: int
+    ) -> Tuple[np.ndarray, np.ndarray]:
+        """``unpack_topn`` with every index under ``n_items``: a dead
+        slot (-inf) may point at a padding row, which the caller's
+        table and an engine's item names do not have."""
+        s, i = unpack_topn(packed, n)
+        return s, np.minimum(i, self.n_items - 1)
 
     def _refine_exact(
         self,
@@ -1421,7 +1486,7 @@ class ItemRetriever:
         device just did; recall@n is then limited only by whole-shortlist
         misses and id parity vs the exact path holds by construction."""
         with _tracing.stage(_tracing.REFINE):
-            s_d, i_d = unpack_topn(packed, n_dev)
+            s_d, i_d = self._unpack(packed, n_dev)
             # [B, n_dev, k] gather from host RAM, or from the pages of
             # a mapped file (the page cache's, or the disk's)
             rows = self._y_f32_host[i_d]

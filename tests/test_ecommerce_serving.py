@@ -634,7 +634,8 @@ def test_a_constraint_written_just_after_a_read_is_served_inside_the_ttl():
 
 
 @pytest.mark.parametrize("rows,n,ties", [
-    (40_000, 16, False), (40_000, 16, True), (33_333, 64, True),
+    # whole blocks of 1,024, as every resident table is since PR 36
+    (40_960, 16, False), (40_960, 16, True), (33_792, 64, True),
     (5_000, 16, True),  # too narrow for blocks: lax.top_k itself
 ])
 def test_block_wise_top_k_is_lax_top_k_ties_and_dead_slots_included(
@@ -657,7 +658,7 @@ def test_block_wise_top_k_is_lax_top_k_ties_and_dead_slots_included(
     assert np.asarray(got_i).max() < rows
 
 
-@pytest.mark.parametrize("rows,width", [(5000, 16), (5000, 1536), (2048, 1)])
+@pytest.mark.parametrize("rows,width", [(6144, 16), (6144, 1536), (2048, 1)])
 def test_membership_by_one_hot_product_is_the_scatter(rows, width):
     import jax.numpy as jnp
 

@@ -810,8 +810,9 @@ DEVICE_RESIDENCY_ALLOWED = {
     # ItemRetriever.__init__ / set_excluded_ids: covered by the
     # _ledger_factors/_ledger_mask handles registered right below them
     # (y_host is the precision-selected storage rows — f32/bf16/int8 —
-    # and _scale_dev the int8 per-row scales, all in the factors handle)
-    ("ops/retrieval.py", "self._y_dev = put(y_host)"),
+    # and _scale_dev the int8 per-row scales, all in the factors handle;
+    # the float32 rows go up through _upload_padded, block by block)
+    ("ops/retrieval.py", "self._y_dev = ("),
     ("ops/retrieval.py", "self._scale_dev = ("),
     ("ops/retrieval.py", "self._rn_dev = put(rn)"),
     ("ops/retrieval.py", "self._allow_dev = put(self._valid)"),

@@ -25,6 +25,7 @@ from predictionio_tpu.ops.retrieval import (
 from predictionio_tpu.parallel import make_mesh
 from predictionio_tpu.utils import metrics as metrics_mod
 from predictionio_tpu.workflow.context import WorkflowContext, workflow_context
+from tests import retrieval_blocks as blocks
 
 
 def _mesh_or_none(shards):
@@ -123,11 +124,102 @@ class TestRetrieverParity:
         r = ItemRetriever(Y, mesh=mesh, component="shardcheck")
         assert not r._y_dev.sharding.is_fully_replicated
         assert len(r._y_dev.sharding.device_set) == 4
-        # padded to 12 rows / 4 shards -> 3 rows per device
+        # padded to whole blocks a shard
         assert {
             s.data.shape[0] for s in r._y_dev.addressable_shards
-        } == {3}
+        } == {retrieval._ROW_BLOCK}
         assert r.resident_bytes > 0
+
+    @pytest.mark.parametrize("mask", blocks.MASKS)
+    @pytest.mark.parametrize("n_items", blocks.ITEM_COUNTS)
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_whole_blocks_change_no_answer(self, shards, n_items, mask):
+        """Float32 over a table padded to whole blocks at build (tests/
+        retrieval_blocks.py says what each case holds), on one device
+        and row-sharded over four."""
+        blocks.check(n_items, "float32", mask, _mesh_or_none(shards))
+
+    @pytest.mark.parametrize("precision", ["float32", "bf16", "int8"])
+    def test_a_mapped_table_is_padded_on_the_device_alone(
+            self, precision, tmp_path):
+        """Rows that are not whole blocks, in a read-only mapped file:
+        the retriever pads what it uploads and keeps the caller's table
+        as it is, for the refine (quantized tiers) and for
+        ``dequantized_factors()`` (float32). Building allocates no
+        second table on the host, and the gauge says how much of the
+        resident rows is padding."""
+        import tracemalloc
+
+        n, k = 50_001, 256
+        path = str(tmp_path / "table.npy")
+        table = np.lib.format.open_memmap(
+            path, mode="w+", dtype=np.float32, shape=(n, k))
+        table[:] = np.random.default_rng(2).standard_normal(
+            (n, k), np.float32)
+        table.flush()
+        del table
+        mapped = np.load(path, mmap_mode="r")
+        tracemalloc.start()
+        try:
+            r = ItemRetriever(
+                mapped, precision=precision, component="mapped-rows")
+            _, peak = tracemalloc.get_traced_memory()
+        finally:
+            tracemalloc.stop()
+        n_pad = r._n_pad
+        assert n_pad == 25 * retrieval._ROW_BLOCK and r._y_dev.shape[0] == n_pad
+        assert _family_value(
+            "pio_padding_waste_ratio", site="retrieval_rows"
+        ) == pytest.approx((n_pad - n) / n_pad)
+        host = (
+            r.dequantized_factors() if precision == "float32"
+            else r._y_f32_host
+        )
+        assert host.shape == (n, k) and not host.flags.writeable
+        assert np.shares_memory(host, mapped)
+        # float32 uploads block views of the map; a quantized tier
+        # stages its own (smaller) rows once
+        staged = {"float32": 0, "bf16": 2, "int8": 1}[precision] * n_pad * k
+        assert peak < staged + mapped.nbytes // 2, (peak, mapped.nbytes)
+        s, i = r.topn(np.asarray(mapped[:3]), 16)
+        ref_s, ref_i = naive_topn_reference(
+            np.asarray(mapped), np.asarray(mapped[:3]), 16)
+        assert np.array_equal(i, ref_i)
+        r.free()
+
+    @pytest.mark.parametrize("precision", ["float32", "int8"])
+    def test_the_fused_programs_neither_pad_nor_slice(self, precision):
+        """The mechanism itself, in the lowered programs: over a table
+        of whole blocks no op pads a ``[B, rows]`` array and none slices
+        one back (``_membership`` returns its grid as it is made,
+        ``_top_k`` reshapes)."""
+        import re
+
+        import jax.numpy as jnp
+
+        b, k, rows, widths = 8, 16, 20 * retrieval._ROW_BLOCK, (64, 16, 1)
+        operand = jnp.zeros((b, k + sum(widths) + 3), jnp.int32)
+        resident = (
+            jnp.zeros((rows,), jnp.float32), jnp.zeros((rows,), bool),
+            jnp.zeros((rows, 1), jnp.int32),
+        )
+        if precision == "float32":
+            lowered = retrieval._fused_topn_single.lower(
+                operand, jnp.zeros((rows, k), jnp.float32), *resident,
+                n=4, positive_only=True, normalize="rows", widths=widths)
+        else:
+            lowered = retrieval._fused_topn_single_2s.lower(
+                operand, jnp.zeros((rows, k), jnp.int8),
+                jnp.zeros((rows,), jnp.float32), *resident,
+                n=4, shortlist=8, positive_only=True, normalize=True,
+                precision="int8", widths=widths)
+        text = lowered.as_text()
+        assert "stablehlo.dot_general" in text
+        for line in text.splitlines():
+            if not re.search(r"stablehlo\.(pad|slice|dynamic_slice)\b", line):
+                continue
+            shape = re.findall(r"tensor<([0-9x]+)x\w+>", line)[-1]
+            assert np.prod([int(d) for d in shape.split("x")]) < rows, line
 
     def test_one_device_mesh_keeps_its_device_pin(self):
         """A `pio deploy --workers` worker pinned to ONE device arrives

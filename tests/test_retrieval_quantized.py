@@ -22,6 +22,7 @@ from predictionio_tpu.ops.retrieval import (
 from predictionio_tpu.parallel import make_mesh
 from predictionio_tpu.utils import device_ledger as dl
 from predictionio_tpu.utils import metrics as metrics_mod
+from tests import retrieval_blocks as blocks
 
 
 def _mesh_or_none(shards):
@@ -153,6 +154,17 @@ class TestQuantizedRecall:
         finally:
             r.free()
 
+    @pytest.mark.parametrize("mask", blocks.MASKS)
+    @pytest.mark.parametrize("n_items", blocks.ITEM_COUNTS)
+    @pytest.mark.parametrize("precision", ["bf16", "int8"])
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_whole_blocks_change_no_answer(
+            self, shards, precision, n_items, mask):
+        """The quantized tiers over a table padded to whole blocks at
+        build: the ids and order of the float32 reference (the host
+        refine reads the caller's rows, which have no padding row)."""
+        blocks.check(n_items, precision, mask, _mesh_or_none(shards))
+
     def test_k_exceeds_live_candidates_quantized(self):
         rng = np.random.default_rng(3)
         Y = rng.standard_normal((10, 4)).astype(np.float32)
@@ -270,7 +282,7 @@ class TestQuantizedLedger:
     def test_ledger_attributes_per_precision(self):
         led = dl.get_ledger()
         rng = np.random.default_rng(6)
-        Y = rng.standard_normal((500, 16)).astype(np.float32)
+        Y = rng.standard_normal((2000, 16)).astype(np.float32)
         r = ItemRetriever(Y, component="qattr", precision="int8")
         try:
             assert led.total_bytes(component="qattr/int8") > 0

@@ -242,9 +242,10 @@ def test_lists_at_the_ladders_top_ride_the_device_and_one_past_the_host(served):
 
 
 def test_warm_compiles_the_whole_ladder_and_traffic_compiles_nothing():
-    """A catalog size no other test uses: every executable this test
-    meets is compiled by its own warm(), for the int8 tier."""
-    model = seeded_model(n_items=2999)
+    """A catalog of more whole blocks (7 of 2,048) than any other test's:
+    every executable this test meets is compiled by its own warm(), for
+    the int8 tier."""
+    model = seeded_model(n_items=12_999)
     size0 = retrieval._fused_topn_single_2s._cache_size()
     dep = deployed(model, "int8")
     retriever = dep.models[0]._retriever

@@ -31,6 +31,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax.sharding import SingleDeviceSharding
 
 from predictionio_tpu.ops import als, retrieval
+from predictionio_tpu.parallel.mesh import pad_to_multiple
 
 N_USERS, N_ITEMS, RANK = 138_493, 26_744, 32
 CHUNKS_U, CHUNKS_I, SC, L = 8, 6, 32_768, 128
@@ -163,15 +164,38 @@ def test_serving_topn_compiles(one_chip):
 WIDTHS = (1, 1, 1)
 
 
-def _stage1_shapes(replicated, rows, rows_k):
+def _resident_rows(n_items, n_shards=1):
+    """The rows ``ItemRetriever`` keeps resident for ``n_items``: whole
+    blocks, and on a mesh whole blocks a shard."""
+    return pad_to_multiple(n_items, n_shards * retrieval._ROW_BLOCK)
+
+
+def _stage1_shapes(replicated, rows, rows_k, n_shards=1):
+    n = _resident_rows(N_ITEMS, n_shards)
     return (
         _shape((BATCH, RANK + sum(WIDTHS) + 3), jnp.int32, replicated),
-        _shape((N_ITEMS, RANK), jnp.int8, rows_k),
-        _shape((N_ITEMS,), jnp.float32, rows),  # per-row scales
-        _shape((N_ITEMS,), jnp.float32, rows),  # reciprocal norms
-        _shape((N_ITEMS,), jnp.bool_, rows),  # candidacy mask
-        _shape((N_ITEMS, 1), jnp.int32, rows_k),  # per-item category codes
+        _shape((n, RANK), jnp.int8, rows_k),
+        _shape((n,), jnp.float32, rows),  # per-row scales
+        _shape((n,), jnp.float32, rows),  # reciprocal norms
+        _shape((n,), jnp.bool_, rows),  # candidacy mask
+        _shape((n, 1), jnp.int32, rows_k),  # per-item category codes
     )
+
+
+def _assert_one_blocked_form(compiled, batch, n_items):
+    """The mechanism of PR 36 in the program the chip would run: no op,
+    inside a fusion or outside, pads, slices or re-lays an array as
+    large as the ``[B, rows]`` scores. What is left is the layout
+    ``copy`` of a membership grid as ``pred`` (a byte an element: the
+    einsum writes ``[B, rows/2048, 2048]`` batch-major, the scores are
+    tiled ``[B, rows]``)."""
+    op = re.compile(
+        r"^\s*(?:ROOT )?%\S+ = (\w+)\[([\d,]+)\]\S* (pad|slice|copy)\("
+    )
+    for line in compiled.as_text().splitlines():
+        m = op.match(line)
+        if m and np.prod([int(d) for d in m[2].split(",")]) >= batch * n_items:
+            assert (m[1], m[3]) == ("pred", "copy"), line
 
 
 def test_int8_stage1_single_device_compiles(one_chip):
@@ -189,7 +213,8 @@ def test_ecommerce_fused_program_compiles_at_the_taobao_shape(one_chip):
     exclusion lists of 8,192, a whitelist of 1,024, four category codes,
     per-row cosine flags, all in the batch's one packed operand. It has
     to fit one chip beside the table."""
-    n, k, b = 4_162_024, 512, 32
+    n_items, k, b = 4_162_024, 512, 32
+    n = _resident_rows(n_items)
     widths = (8192, 1024, 4)
     compiled = retrieval._fused_topn_single.lower(
         _shape((b, k + sum(widths) + 3), jnp.int32, one_chip),
@@ -200,6 +225,7 @@ def test_ecommerce_fused_program_compiles_at_the_taobao_shape(one_chip):
         n=16, positive_only=True, normalize="rows", widths=widths,
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+    _assert_one_blocked_form(compiled, b, n_items)
 
 
 @pytest.mark.parametrize("exclude, include", [(16, 1), (64, 256)])
@@ -214,7 +240,8 @@ def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
     candidate list 64 wide and its stage-1 shortlist 256, cosine
     scores, four category codes, all in the batch's one packed operand.
     Each has to fit one chip beside the table."""
-    n, k = 9_400_000, 512
+    n_items, k = 9_400_000, 512
+    n = _resident_rows(n_items)
     widths = (exclude, include, 4)
     compiled = retrieval._fused_topn_single_2s.lower(
         _shape((batch, k + sum(widths) + 3), jnp.int32, one_chip),
@@ -227,6 +254,7 @@ def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
         precision="int8", widths=widths,
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+    _assert_one_blocked_form(compiled, batch, n_items)
 
 
 def test_int8_stage1_shards_over_four_chips(mesh4):
@@ -248,13 +276,13 @@ def test_int8_stage1_shards_over_four_chips(mesh4):
             check_vma=False,
         )
     )
-    assert N_ITEMS % 4 == 0
     compiled = fn.lower(
         *_stage1_shapes(
             NamedSharding(mesh4, P()),
             NamedSharding(mesh4, P("data")),
             NamedSharding(mesh4, P("data", None)),
+            n_shards=4,
         )
     ).compile()
-    catalog_bytes = N_ITEMS * (RANK + 4 + 4 + 1)  # rows + scale + rn + mask
+    catalog_bytes = _resident_rows(N_ITEMS, 4) * (RANK + 4 + 4 + 1)  # rows + scale + rn + mask
     assert compiled.memory_analysis().argument_size_in_bytes < catalog_bytes / 2
