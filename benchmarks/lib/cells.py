@@ -40,8 +40,9 @@ class Run:
     def peaks(self, device):
         """The peaks of the chips the cell runs on: the table's, which is
         per chip, times the cell's chips. A device not in the table is an
-        error, and anything but a TPU, or another number of chips than
-        the cell asks for, is refused as a measurement."""
+        error, and anything but a TPU is refused as a measurement.
+        Another number of devices than the cell's chips is refused in a
+        rehearsal too: its children are given the cell's count."""
         if device is None:
             raise CellFailed("the child's log names no device")
         if self.require_tpu and device["platform"] != "tpu":
@@ -49,7 +50,7 @@ class Run:
                 f"JAX found platform {device['platform']!r}, not a TPU: "
                 "refusing to time a CPU"
             )
-        if self.require_tpu and device["count"] != self.chips:
+        if device["count"] != self.chips:
             raise CellFailed(
                 f"the cell asks for {self.chips} chip(s), JAX found {device}"
             )
@@ -127,6 +128,18 @@ def reduce_trace(run, trace_dir):
     )
     with open(out_path) as f:
         return json.load(f)
+
+
+def batches_seen(reduced):
+    """Batches counted in the trace itself: each pattern that a cell's
+    metric files match is a program that runs once a batch (on one chip
+    the fused program; on a mesh the sharded one, and the merge after
+    it), so the batches are the most runs any of them made. The scrapes
+    around a capture span its start-up and its archiving too, so they
+    cannot count what the trace saw."""
+    device = reduced.get("device") or {}
+    runs = [m["events"] for m in (device.get("matching") or {}).values()]
+    return max(runs, default=0)
 
 
 def breakdown(reduced, t_trace_start, label_at):

@@ -50,10 +50,13 @@ def cache_dir() -> str:
     )
 
 
-def child_env(work, *, host_only=False):
+def child_env(work, *, host_only=False, chips=1):
     """Environment of a child. ``host_only`` children (bulk insert,
     export, trace reduction) are held to the CPU so they can never take
-    the chip."""
+    the chip. A child that holds the chip sees every chip of the
+    machine; in a rehearsal on the CPU it sees ``chips`` virtual
+    devices, the cell's own count, so that a cell over several chips
+    runs its sharded path there too."""
     env = dict(os.environ)
     env["PYTHONPATH"] = ROOT + os.pathsep + env.get("PYTHONPATH", "")
     env.pop("BENCH_RUN", None)  # the driver's own; nothing here reads it
@@ -87,7 +90,7 @@ def child_env(work, *, host_only=False):
     # cache hit and miss by program name
     env["JAX_DEBUG_LOG_MODULES"] = "jax._src.compiler"
     if os.environ.get("JAX_PLATFORMS") == "cpu":  # a rehearsal
-        env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=1"
+        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={chips}"
     return env
 
 
@@ -334,4 +337,59 @@ def metric_samples(text, family):
         if line.startswith(family + "{") or line.startswith(family + " "):
             head, _, value = line.rpartition(" ")
             out[head[len(family):]] = float(value)
+    return out
+
+
+LABEL_RE = re.compile(r'(\w+)="((?:[^"\\]|\\.)*)"')
+
+
+def labels_of(label_string):
+    """``{name: value}`` of one sample's label string (values as they
+    are rendered, escapes and all)."""
+    return dict(LABEL_RE.findall(label_string))
+
+
+def device_memory(scrape):
+    """The device's memory at a scrape, for the result line's ``device``.
+
+    Each device's own ``bytes_in_use`` (``pio_device_memory_bytes``, the
+    server's ``memory_stats()`` at every scrape) in device order is
+    ``memory_by_device``, and the fullest device's is
+    ``memory_peak_bytes``: a table sharded over four chips is a quarter
+    of it on each. ``ledger_bytes`` is the residency ledger's total over
+    every device. The ledger cannot stand in for a device: a sharded
+    buffer is one sample under its span's label, holding the whole.
+
+    Where the backend gives no device stats (the CPU), the peak is the
+    ledger + drift reading, ``memory_source`` says so, and nothing is
+    read by device. That reading is given beside the other in every
+    case (``ledger_plus_drift_bytes``): on one chip it is the device's
+    ``bytes_in_use`` plus the ledger's entries on the host, which
+    ``ledger_host_bytes`` names by component."""
+    ledger = metric_samples(scrape, "pio_device_ledger_bytes")
+    total = sum(ledger.values())
+    drifts = metric_samples(scrape, "pio_device_ledger_drift_bytes").values()
+    host = {}
+    for label_string, value in ledger.items():
+        labels = labels_of(label_string)
+        if labels.get("device") == "host" and value:
+            name = labels["component"]
+            host[name] = host.get(name, 0) + int(value)
+    out = {"ledger_bytes": int(total),
+           "ledger_plus_drift_bytes": int(max([total] + [
+               total + d for d in drifts])),
+           "ledger_host_bytes": host}
+    in_use = {}
+    for label_string, value in metric_samples(
+            scrape, "pio_device_memory_bytes").items():
+        labels = labels_of(label_string)
+        if labels.get("stat") == "bytes_in_use":
+            in_use[int(labels["device"])] = int(value)
+    if not in_use:
+        out.update(memory_peak_bytes=out["ledger_plus_drift_bytes"],
+                   memory_source="ledger+drift: no device stats")
+        return out
+    by_device = [in_use[d] for d in sorted(in_use)]
+    out.update(memory_peak_bytes=max(by_device), memory_by_device=by_device,
+               memory_source="bytes_in_use")
     return out

@@ -23,16 +23,15 @@ import numpy as np
 
 from .. import compare, data, ecom, layers, loadgen, reference_ecom
 from ..cells import (
-    Run, breakdown, reduce_trace, result_line, settle_disk, write_variant,
+    Run, batches_seen, breakdown, reduce_trace, result_line, settle_disk,
+    write_variant,
 )
 from ..children import (
     BENCH, CHILDREN, UPGRADE_CHECK_LINE, CellFailed, Deployed, child_env,
-    device_of, free_port, http_json, json_lines, last_line, metric_samples,
+    device_memory, device_of, free_port, http_json, json_lines, last_line,
     pio, run_child, say, stop_child,
 )
 from .open_loop_queries import ACCESS_KEY, _capture, _tick
-
-PROGRAM = r"jit__fused_topn_single\b"  # the fused retrieval program's name
 
 
 def ecom_stage(name, *args):
@@ -108,7 +107,8 @@ def start_servers(run: Run):
     events = EventServer(run.work, host)
     server = Deployed(
         "deploy", run.work, variant, written["instance_id"],
-        child_env(run.work), extra=("--accesskey", ACCESS_KEY),
+        child_env(run.work, chips=run.chips),
+        extra=("--accesskey", ACCESS_KEY),
     )
     try:
         ready_s, status = server.wait_ready(timeout=t["deploy_s"])
@@ -233,11 +233,7 @@ def run_cell(run: Run) -> dict:
         got = offer(run, server, ctx, run.traffic, run.seconds, box)
         setup_s = got["t_ready"] - t_setup
         scrape_before, scrape_after = got["scrapes"]
-        ledger = sum(metric_samples(scrape_after, "pio_device_ledger_bytes").values())
-        in_use = [
-            ledger + drift for drift in
-            metric_samples(scrape_after, "pio_device_ledger_drift_bytes").values()
-        ]
+        memory = device_memory(scrape_after)
         server_rss = rss_of(server.proc.pid)
     finally:
         server.stop()  # the chip is free and the server's state gone
@@ -250,10 +246,7 @@ def run_cell(run: Run) -> dict:
         "setup_s": {"value": setup_s, "unit": "s"},
         "query_p50_ms": {"value": loadgen.percentile(latency_ms, 50), "unit": "ms"},
     }
-    device_out = dict(
-        device or {}, memory_peak_bytes=int(max(in_use + [ledger])),
-        ledger_bytes=int(ledger), server_rss_bytes=server_rss,
-    )
+    device_out = dict(device or {}, **memory, server_rss_bytes=server_rss)
     late_ms = (sent - due) * 1e3
     lag = np.asarray(got["lag"]["worst_ms_by_second"])
     both = {"prom": (scrape_before, scrape_after)}
@@ -288,16 +281,14 @@ def run_cell(run: Run) -> dict:
             zipfile.ZipFile(io.BytesIO(box["archive"])).extractall(trace_dir)
             reduced = reduce_trace(run, trace_dir)
             dev = reduced.get("device")
-            # one run of the fused retrieval program a batch (known and
-            # recent-view users ride one run), counted in the trace itself
-            runs = ((dev or {}).get("matching") or {}).get(PROGRAM, {})
-            batches = runs.get("events", 0.0)
+            batches = batches_seen(reduced)
             fill = layers.read(tctx, "prom:pio_serving_batch_fill:mean")
             tctx.update(
                 trace=reduced, trace_window_s=box["seconds"],
                 seen={"batches": batches, "queries": batches * (fill or 0.0)})
             if dev:
-                device_out.update(busy_s=dev["busy_s"], window_s=box["seconds"])
+                device_out.update(busy_s=dev["busy_s"], window_s=box["seconds"],
+                                  busy_by_plane=dev["busy_by_plane"])
 
                 def in_flight(at):
                     n = int(np.sum((got["t_open"] + sent <= at)

@@ -16,11 +16,12 @@ import numpy as np
 
 from .. import compare, data, layers, loadgen
 from ..cells import (
-    Run, breakdown, reduce_trace, result_line, settle_disk, write_variant,
+    Run, batches_seen, breakdown, reduce_trace, result_line, settle_disk,
+    write_variant,
 )
 from ..children import (
-    UPGRADE_CHECK_LINE, CellFailed, Deployed, child_env, device_of,
-    http_json, json_lines, metric_samples, pio, run_child, say, stage,
+    UPGRADE_CHECK_LINE, CellFailed, Deployed, child_env, device_memory,
+    device_of, http_json, json_lines, pio, run_child, say, stage,
 )
 
 ACCESS_KEY = "bench"  # gates POST /debug/profile on the deployed server
@@ -75,7 +76,8 @@ def start_server(run: Run):
     say(phase="write_instance", seconds=seconds, **written)
     server = Deployed(
         "deploy", run.work, variant, written["instance_id"],
-        child_env(run.work), extra=("--accesskey", ACCESS_KEY),
+        child_env(run.work, chips=run.chips),
+        extra=("--accesskey", ACCESS_KEY),
     )
     try:
         # the same factors, for the reference, while the server loads: the
@@ -179,11 +181,7 @@ def run_cell(run: Run) -> dict:
         got = offer(run, server, run.traffic, run.seconds, box)
         setup_s = got["t_ready"] - t_setup
         scrape_before, scrape_after = got["scrapes"]
-        ledger = sum(metric_samples(scrape_after, "pio_device_ledger_bytes").values())
-        in_use = [
-            ledger + drift for drift in
-            metric_samples(scrape_after, "pio_device_ledger_drift_bytes").values()
-        ]
+        memory = device_memory(scrape_after)
     finally:
         server.stop()  # the chip is free and the server's state gone
     due, users, nums, out, t_open, sent, answered = (
@@ -196,10 +194,7 @@ def run_cell(run: Run) -> dict:
         "setup_s": {"value": setup_s, "unit": "s"},
         "query_p50_ms": {"value": loadgen.percentile(latency_ms, 50), "unit": "ms"},
     }
-    device_out = dict(
-        device or {}, memory_peak_bytes=int(max(in_use + [ledger])),
-        ledger_bytes=int(ledger),
-    )
+    device_out = dict(device or {}, **memory)
 
     # where in the window the worst waits fell, and who saw them: the
     # generator's own loop, the parent's ticker, the server's histogram.
@@ -248,13 +243,7 @@ def run_cell(run: Run) -> dict:
             trace_dir = os.path.join(run.work, "capture")
             zipfile.ZipFile(io.BytesIO(box["archive"])).extractall(trace_dir)
             reduced = reduce_trace(run, trace_dir)
-            # batches are counted in the trace itself (one program run a
-            # batch); the scrapes around the capture span its start-up and
-            # its archiving too, so they cannot count what the trace saw
-            batches = sum(
-                m["events"] for m in
-                ((reduced.get("device") or {}).get("matching") or {}).values()
-            )
+            batches = batches_seen(reduced)
             fill = layers.read(ctx, "prom:pio_serving_batch_fill:mean")
             ctx.update(
                 trace=reduced, trace_window_s=box["seconds"],
@@ -262,7 +251,8 @@ def run_cell(run: Run) -> dict:
             )
             dev = reduced.get("device")
             if dev:
-                device_out.update(busy_s=dev["busy_s"], window_s=box["seconds"])
+                device_out.update(busy_s=dev["busy_s"], window_s=box["seconds"],
+                                  busy_by_plane=dev["busy_by_plane"])
 
                 def in_flight(at):
                     n = int(np.sum((t_open + sent <= at) & (at < t_open + answered)))

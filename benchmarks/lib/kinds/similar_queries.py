@@ -25,15 +25,14 @@ import numpy as np
 from .. import compare, counts, counts_quantized, layers, loadgen
 from .. import reference_similar, similar
 from ..cells import (
-    Run, breakdown, reduce_trace, result_line, settle_disk, write_variant,
+    Run, batches_seen, breakdown, reduce_trace, result_line, settle_disk,
+    write_variant,
 )
 from ..children import (
-    BENCH, UPGRADE_CHECK_LINE, CellFailed, Deployed, child_env, device_of,
-    http_json, json_lines, metric_samples, pio, run_child, say,
+    BENCH, UPGRADE_CHECK_LINE, CellFailed, Deployed, child_env,
+    device_memory, device_of, http_json, json_lines, pio, run_child, say,
 )
 from .open_loop_queries import ACCESS_KEY, _capture, _tick
-
-PROGRAM = r"jit__fused_topn_single_2s\b"  # the two-stage program's name
 
 # the count of the two-stage program's work, kept with the benchmark and
 # made known to ``layers.read`` here (``counts.py`` is not this PR's)
@@ -84,7 +83,8 @@ def start_server(run: Run):
     settle_disk()  # the table's pages, before the server maps them
     server = Deployed(
         "deploy", run.work, variant, written["instance_id"],
-        child_env(run.work), extra=("--accesskey", ACCESS_KEY),
+        child_env(run.work, chips=run.chips),
+        extra=("--accesskey", ACCESS_KEY),
     )
     try:
         ready_s, status = server.wait_ready(timeout=t["deploy_s"])
@@ -179,11 +179,7 @@ def run_cell(run: Run) -> dict:
         got = offer(run, server, ctx, run.traffic, run.seconds, box)
         setup_s = got["t_ready"] - t_setup
         scrape_before, scrape_after = got["scrapes"]
-        ledger = sum(metric_samples(scrape_after, "pio_device_ledger_bytes").values())
-        in_use = [
-            ledger + drift for drift in
-            metric_samples(scrape_after, "pio_device_ledger_drift_bytes").values()
-        ]
+        device_mem = device_memory(scrape_after)
         memory = memory_of(server.proc.pid) or {}
     finally:
         server.stop()  # the chip is free and the server's state gone
@@ -196,8 +192,7 @@ def run_cell(run: Run) -> dict:
         "query_p50_ms": {"value": loadgen.percentile(latency_ms, 50), "unit": "ms"},
     }
     device_out = dict(
-        device or {}, memory_peak_bytes=int(max(in_use + [ledger])),
-        ledger_bytes=int(ledger), server_rss_bytes=memory.get("VmRSS"),
+        device or {}, **device_mem, server_rss_bytes=memory.get("VmRSS"),
         server_rss_anon_bytes=memory.get("RssAnon"),
         server_rss_file_bytes=memory.get("RssFile"),
     )
@@ -235,16 +230,14 @@ def run_cell(run: Run) -> dict:
             zipfile.ZipFile(io.BytesIO(box["archive"])).extractall(trace_dir)
             reduced = reduce_trace(run, trace_dir)
             dev = reduced.get("device")
-            # one run of the two-stage program a batch, counted in the
-            # trace itself
-            runs = ((dev or {}).get("matching") or {}).get(PROGRAM, {})
-            batches = runs.get("events", 0.0)
+            batches = batches_seen(reduced)
             fill = layers.read(tctx, "prom:pio_serving_batch_fill:mean")
             tctx.update(
                 trace=reduced, trace_window_s=box["seconds"],
                 seen={"batches": batches, "queries": batches * (fill or 0.0)})
             if dev:
-                device_out.update(busy_s=dev["busy_s"], window_s=box["seconds"])
+                device_out.update(busy_s=dev["busy_s"], window_s=box["seconds"],
+                                  busy_by_plane=dev["busy_by_plane"])
 
                 def in_flight(at):
                     n = int(np.sum((got["t_open"] + sent <= at)

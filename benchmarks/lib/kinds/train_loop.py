@@ -57,7 +57,7 @@ def timed_train(run, name, variant, env, extra=(), timeout=300):
 def run_cell(run: Run) -> dict:
     shape, t_setup = run.config["shape"], time.time()
     host = child_env(run.work, host_only=True)
-    chip = child_env(run.work)
+    chip = child_env(run.work, chips=run.chips)
     u, i, r = data.synth_ratings(
         shape["n_users"], shape["n_items"], shape["n_events"],
         run.config["data"]["structure_seed"], run.seed,
@@ -146,7 +146,8 @@ def run_cell(run: Run) -> dict:
         metrics.update(layers.evaluate(ctx, run.layer_defs))
         dev = reduced.get("device")
         if dev:
-            device_out.update(busy_s=dev["busy_s"], window_s=window)
+            device_out.update(busy_s=dev["busy_s"], window_s=window,
+                              busy_by_plane=dev["busy_by_plane"])
             said = sorted(
                 (record_time(rec), rec.get("message", "").split("\n")[0])
                 for rec in json_lines(first["text"]) if rec.get("ts")

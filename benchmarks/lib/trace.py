@@ -83,7 +83,8 @@ def reduce_planes(planes, patterns=()):
     """``planes``: [(plane name, [(line name, [(event name, start ns,
     end ns)])])]. Returns the reduction as plain JSON-able data; device
     numbers are averaged over the device planes found, and absent (None)
-    where there is none: a CPU trace has no device plane."""
+    where there is none: a CPU trace has no device plane. Each plane's
+    own busy time is kept beside the average (``busy_by_plane``)."""
     layout = [
         {"plane": p, "lines": [{"line": ln, "events": len(ev)} for ln, ev in lines]}
         for p, lines in planes
@@ -97,17 +98,18 @@ def reduce_planes(planes, patterns=()):
         if events is None:  # an unknown layout: take every line but steps
             events = [e for ln, ev in lines if ln != "Steps" for e in ev]
         if events:
-            devices.append((events, [e for _, ev in lines for e in ev]))
+            devices.append((p, (events, [e for _, ev in lines for e in ev])))
     out = {"layout": layout, "device_planes": len(devices), "device": None}
     if not devices:
         return out
     n = len(devices)
-    busy = 0.0
+    busy, busy_by_plane = 0.0, {}
     matching = {p: {"seconds": 0.0, "events": 0.0} for p in patterns}
     merged, spans, first, last = {}, [], None, None
-    for events, every_line in devices:
+    for plane, (events, every_line) in devices:
         spans_d = [(s, e) for _, s, e in events]
-        busy += union_seconds(spans_d) / 1e9
+        busy_by_plane[plane] = union_seconds(spans_d) / 1e9
+        busy += busy_by_plane[plane]
         for p in patterns:
             rx = re.compile(p)
             hit = [(s, e) for nm, s, e in every_line if rx.search(nm)]
@@ -124,6 +126,7 @@ def reduce_planes(planes, patterns=()):
     ops = sorted(merged.items(), key=lambda kv: -kv[1][0])
     out["device"] = {
         "busy_s": busy / n,
+        "busy_by_plane": busy_by_plane,  # an idle or straggling chip shows
         "matching": matching,
         "first_ns": first, "last_ns": last,
         "absolute_clock": bool(first > UNIX_NS),

@@ -166,6 +166,25 @@ def test_children_keep_metadata_and_events_in_files_of_their_own(tmp_path):
                        if k.startswith("PIO_STORAGE_"))
 
 
+@pytest.mark.parametrize("chips", [1, 4])
+def test_a_rehearsals_children_see_the_cells_chips(tmp_path, monkeypatch,
+                                                     chips):
+    """Under ``JAX_PLATFORMS=cpu`` a child that would hold the chip gets
+    the cell's count of virtual devices; on the chip, and in a host-only
+    child, no flag is set: every chip stays visible."""
+    from lib import children
+
+    monkeypatch.delenv("XLA_FLAGS", raising=False)
+    work = str(tmp_path)
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert children.child_env(work, chips=chips)["XLA_FLAGS"] == (
+        f"--xla_force_host_platform_device_count={chips}")
+    assert "XLA_FLAGS" not in children.child_env(
+        work, host_only=True, chips=chips)
+    monkeypatch.delenv("JAX_PLATFORMS")
+    assert "XLA_FLAGS" not in children.child_env(work, chips=chips)
+
+
 def test_a_metadata_write_does_not_wait_for_the_event_stores_lock(tmp_path):
     """The storage registry as the layout leans on it: two sources of type
     sqlite with different PATHs are independent files, and an insert into

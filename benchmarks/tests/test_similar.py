@@ -7,6 +7,7 @@ as they are, a bfloat16 refine) each turn ``correct`` false."""
 from __future__ import annotations
 
 import copy
+import glob
 import json
 import os
 import sys
@@ -74,6 +75,37 @@ def test_tiny_rehearsal_is_correct_and_reports_the_cells_metrics(rehearsal):
         "simprod_refine_ms", "serve_batch_build_ms"))
     assert 0.8 * m["serve_predict_mean_ms"]["value"] <= stages
     assert stages <= m["serve_predict_mean_ms"]["value"]
+
+
+def test_a_four_chip_cell_rehearses_its_row_sharded_path(tmp_path):
+    """A cell that asks for four chips is rehearsed on four virtual
+    devices: the cell at float32 in a manifest of the test's own, its
+    table row-sharded over them, every answer held to the same
+    comparison. The CPU has no device stats: memory is the ledger's."""
+    bench, manifest = tiny_similar.make_bench(str(tmp_path))
+    path = os.path.join(bench, "configs", "simprod-amazon-d512.json")
+    with open(path) as f:
+        config = json.load(f)
+    config["engine"]["algorithms"][0]["params"]["precision"] = "float32"
+    with open(path, "w") as f:
+        json.dump(config, f)
+    for cell in manifest["workloads"]:
+        if cell["name"] == tiny_similar.CELL:
+            cell["chips"] = 4
+    path = os.path.join(bench, "four-chips.json")
+    with open(path, "w") as f:
+        json.dump(manifest, f)
+    line = tiny_similar.tiny_run(
+        str(tmp_path), seed=2**31 + 38, bench=bench,
+        manifest=tiny_similar.harness.load_json(path))
+    assert line["correct"], line["compared"]
+    assert line["device"]["count"] == 4
+    assert line["device"]["memory_source"].startswith("ledger+drift")
+    [log] = glob.glob(os.path.join(str(tmp_path), "work_*", "deploy.log"))
+    with open(log) as f:
+        assert (f"ItemRetriever[similarproduct]: {tiny_similar.SHAPE['n_items']}"
+                " items (rank 32, float32) resident row-sharded over 4 devices"
+                ) in f.read()
 
 
 def verdict(seen, got, host_fallbacks=0.0):

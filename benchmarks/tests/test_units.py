@@ -139,6 +139,20 @@ def test_trace_reduction_on_made_up_planes():
     assert own["fusion.1"] == pytest.approx(40e-6)
     assert device["gaps"][0] == [100 * us, pytest.approx(100e-6)]
     assert trace.reduce_planes(planes[:1])["device"] is None  # a CPU trace
+    # a batch is one run of each program the cell's files name (a sharded
+    # program and the merge after it): not one a program
+    from lib import cells
+    assert cells.batches_seen(got) == 1
+    assert cells.batches_seen(
+        trace.reduce_planes(planes, ["jit_loop", "jit_other"])) == 1
+    assert cells.batches_seen(trace.reduce_planes(planes[:1])) == 0
+    # a second chip that did less: the average, and each plane's own
+    two = trace.reduce_planes(
+        planes + [("/device:TPU:1", [("XLA Ops", [("fusion.1", 0, 30 * us)])])])
+    assert two["device"]["busy_by_plane"] == {
+        "/device:TPU:0": pytest.approx(150e-6),
+        "/device:TPU:1": pytest.approx(30e-6)}
+    assert two["device"]["busy_s"] == pytest.approx(90e-6)
 
 
 def test_recorded_trace_reduces():
@@ -253,6 +267,108 @@ def test_chips_are_the_cells_own(tmp_path):
         a_run(tmp_path, "k").peaks(dict(one, platform="cpu"))
     with pytest.raises(children.CellFailed, match="no peaks"):
         a_run(tmp_path, "k").peaks(dict(one, kind="TPU v9"))
+    # a rehearsal: the platform is not checked, the count is
+    cpu = {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert a_run(tmp_path, "k", chips=4, require_tpu=False).peaks(
+        dict(cpu, count=4)) is None
+    with pytest.raises(children.CellFailed, match="asks for 4 chip"):
+        a_run(tmp_path, "k", chips=4, require_tpu=False).peaks(cpu)
+
+
+def scrape_of(ledger=(), drift=(), in_use=()):
+    """A /metrics scrape with the families the memory reading takes:
+    ``ledger`` (device label, component, bytes), ``drift`` (device
+    label, bytes), ``in_use`` (device id, bytes)."""
+    lines = [
+        f'pio_device_ledger_bytes{{device="{d}",component="{c}",owner="i"}} {b}'
+        for d, c, b in ledger]
+    lines += [f'pio_device_ledger_drift_bytes{{device="{d}"}} {b}'
+              for d, b in drift]
+    for d, b in in_use:
+        lines += [
+            f'pio_device_memory_bytes{{device="{d}",stat="bytes_in_use"}} {b}',
+            f'pio_device_memory_bytes{{device="{d}",stat="peak_bytes_in_use"}}'
+            f' {2 * b}']
+    return "\n".join(lines) + "\n"
+
+
+@pytest.mark.parametrize("host_bytes", [0, 65_536])
+def test_memory_of_one_device_is_the_ledger_and_drift_reading(host_bytes):
+    """On one chip the device's ``bytes_in_use`` is what ledger + drift
+    read, less the ledger's entries on the host, which are named."""
+    from lib import children
+
+    device = "TPU_0(process=0,(0,0,0,0))"
+    ledger = [(device, "serving-factors", 5_000_000_000),
+              (device, "serving-factors-norms", 21_000_000)]
+    if host_bytes:
+        ledger.append(("host", "pack-cache", host_bytes))
+    got = children.device_memory(scrape_of(
+        ledger=ledger, drift=[(device, 207_552)],
+        in_use=[(0, 5_021_207_552)]))
+    assert got["memory_peak_bytes"] == 5_021_207_552
+    assert got["memory_by_device"] == [5_021_207_552]
+    assert got["memory_peak_bytes"] + host_bytes == got["ledger_plus_drift_bytes"]
+    assert got["ledger_host_bytes"] == (
+        {"pack-cache": host_bytes} if host_bytes else {})
+
+
+def test_memory_reads_what_the_program_renders(monkeypatch):
+    """The families and labels as the server renders them at a scrape
+    (the ledger reconciled against each device, then each device's
+    ``memory_stats()`` recorded), for a table sharded over four chips."""
+    import jax
+    from lib import children
+    from predictionio_tpu.utils import device_ledger, health, metrics
+
+    class Chip:
+        def __init__(self, n, in_use):
+            self.id, self.in_use = n, in_use
+
+        def __str__(self):
+            return f"TPU_{self.id}(process=0,({self.id},0,0,0))"
+
+        def memory_stats(self):
+            return {"bytes_in_use": self.in_use,
+                    "peak_bytes_in_use": 2 * self.in_use}
+
+    in_use = [4_900_000_000, 4_950_000_000, 4_800_000_000, 4_810_000_000]
+    # the runtime's order need not be the ids': the reading is by id
+    chips = [Chip(n, in_use[n]) for n in (2, 0, 3, 1)]
+    monkeypatch.setattr(jax, "local_devices", lambda: chips)
+    share = 4_824_250_000
+    ledger = device_ledger.DeviceLedger()
+    ledger.register("similarproduct", 4 * share, device=f"{chips[1]}x4",
+                    members={str(c): share for c in chips})
+    ledger.register("pack-cache", 1_000)  # host memory, on no device
+    try:
+        ledger.reconcile()
+        health.record_memory_gauges()
+        got = children.device_memory(metrics.get_registry().render())
+    finally:
+        metrics.get_registry().reset()
+    assert got["memory_by_device"] == in_use
+    assert got["memory_peak_bytes"] == 4_950_000_000
+    assert got["memory_source"] == "bytes_in_use"
+    assert got["ledger_bytes"] == 4 * share + 1_000
+    assert got["ledger_host_bytes"] == {"pack-cache": 1_000}
+    # the reading it replaces: all four chips' residency and one's drift
+    assert got["ledger_plus_drift_bytes"] == (
+        4 * share + 1_000 + 4_950_000_000 - share)
+
+
+def test_memory_without_device_stats_falls_back_to_the_ledger():
+    """The CPU gives no ``memory_stats()``: no drift, no bytes in use;
+    the ledger's total stands in and the line says so."""
+    from lib import children
+
+    got = children.device_memory(scrape_of(
+        ledger=[("TFRT_CPU_0x4", "similarproduct", 2_560_000),
+                ("TFRT_CPU_0x4", "similarproduct-mask", 640_000)]))
+    assert got["memory_peak_bytes"] == got["ledger_bytes"] == 3_200_000
+    assert got["memory_source"].startswith("ledger+drift")
+    assert "memory_by_device" not in got
+    assert children.device_memory("")["memory_peak_bytes"] == 0
 
 
 def test_the_generators_lag_is_seen():
