@@ -43,7 +43,10 @@ pass through a float.
 Metrics (utils/metrics.py conventions, visible in ``pio top``):
 ``pio_retrieval_shard_topk_seconds`` / ``pio_retrieval_merge_seconds``
 (every batch off-mesh; SAMPLED on the sharded path — the split needs a
-host sync), ``pio_retrieval_mask_refresh_total{component,outcome}``,
+host sync), ``pio_retrieval_merge_rows_total{component}`` (candidate
+rows the merge takes across the sharded→replicated hop, every batch;
+the merge's dispatch is the batch stage ``merge``),
+``pio_retrieval_mask_refresh_total{component,outcome}``,
 ``pio_retrieval_mask_age_seconds{component}``,
 ``pio_retrieval_resident_bytes{component}``,
 ``pio_retrieval_operand_transfers_total{component}`` (host-to-device
@@ -243,6 +246,25 @@ def _upload_padded(rows: np.ndarray, n_pad: int, device):
         block = jax.device_put(rows[a:a + _UPLOAD_ROWS], device)
         table = jax.block_until_ready(_write_rows(table, block, a))
     return table
+
+
+def _upload_row_sharded(rows: np.ndarray, n_pad: int, sharding):
+    """``[n_pad, k]`` laid out by ``sharding`` (rows split over a mesh
+    axis): each device's shard is ``_upload_padded`` from a view of the
+    caller's rows, in the rows that device owns, and the shards are
+    assembled as one array. Only a shard that runs past the caller's
+    rows gets padding rows, and those are made on its device: no host
+    copy of the table, padded or not (19 GB at 9.4 M x 512)."""
+    shape = (n_pad, rows.shape[1])
+    shards = []
+    for device, (at, _) in sharding.addressable_devices_indices_map(
+        shape
+    ).items():
+        start, stop, _ = at.indices(n_pad)
+        shards.append(_upload_padded(
+            rows[start:min(stop, len(rows))], stop - start, device
+        ))
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)
 
 
 def _batch_sizes(max_batch: int) -> Tuple[int, ...]:
@@ -782,6 +804,16 @@ def _m_merge_seconds():
     )
 
 
+def _m_merge_rows():
+    return _metrics.get_registry().counter(
+        "pio_retrieval_merge_rows_total",
+        "Candidate rows a row-sharded retriever's merge took across the "
+        "sharded->replicated hop (padded batch rows x shards x each "
+        "shard's top-n, every batch)",
+        labels=("component",),
+    )
+
+
 def _m_mask_refresh():
     return _metrics.get_registry().counter(
         "pio_retrieval_mask_refresh_total",
@@ -1047,14 +1079,10 @@ class ItemRetriever:
             self._operand_at = device
         else:
             self._device = None
-            if precision == "float32":
-                # a mesh takes its shards from one host array: a padded
-                # copy that goes once it is up, as the quantized staging
-                # copy does
-                y_host = np.zeros((n_pad, self.rank), np.float32)
-                y_host[: self.n_items] = factors
-            self._y_dev = jax.device_put(
-                y_host, NamedSharding(mesh, P(axis, None))
+            rows_at = NamedSharding(mesh, P(axis, None))
+            self._y_dev = (
+                _upload_row_sharded(y_host, n_pad, rows_at)
+                if precision == "float32" else jax.device_put(y_host, rows_at)
             )
             self._scale_dev = (
                 jax.device_put(scale_host, NamedSharding(mesh, P(axis)))
@@ -1445,7 +1473,11 @@ class ItemRetriever:
             jax.block_until_ready(cand)
             t1 = time.perf_counter()
             _m_shard_seconds().observe(t1 - t0)
-        packed = _merge_candidates(cand, n_dev, n_local, self._rep_out)
+        with _tracing.stage(_tracing.MERGE):
+            packed = _merge_candidates(cand, n_dev, n_local, self._rep_out)
+        _m_merge_rows().labels(component=self.component).inc(
+            b_pad * self._n_shards * n_local
+        )
         with _tracing.stage(_tracing.DEVICE_WAIT):
             host = np.asarray(packed)[:b]
         if split:

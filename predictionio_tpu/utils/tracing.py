@@ -186,8 +186,13 @@ MASK_PREP = "mask_prep"
 # ``_refine_exact``): the device's shortlist rescored against the
 # original float32 rows, which may be a file mapped into memory
 REFINE = "refine"
+# a row-sharded retriever's cross-shard merge (ops/retrieval.py
+# ``_merge_candidates``): the dispatch of the program that takes every
+# shard's candidates across the sharded -> replicated hop to one top-n
+MERGE = "merge"
 BATCH_STAGES = (
     HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD, STORE_READ, MASK_PREP, REFINE,
+    MERGE,
 )
 
 # the per-batch accumulator of stage() durations, bound by the engine

@@ -811,12 +811,12 @@ DEVICE_RESIDENCY_ALLOWED = {
     # _ledger_factors/_ledger_mask handles registered right below them
     # (y_host is the precision-selected storage rows — f32/bf16/int8 —
     # and _scale_dev the int8 per-row scales, all in the factors handle;
-    # the float32 rows go up through _upload_padded, block by block)
+    # the float32 rows go up through _upload_padded, block by block,
+    # on a mesh a shard a device through _upload_row_sharded)
     ("ops/retrieval.py", "self._y_dev = ("),
     ("ops/retrieval.py", "self._scale_dev = ("),
     ("ops/retrieval.py", "self._rn_dev = put(rn)"),
     ("ops/retrieval.py", "self._allow_dev = put(self._valid)"),
-    ("ops/retrieval.py", "self._y_dev = jax.device_put("),
     ("ops/retrieval.py", "self._rn_dev = jax.device_put(rn, NamedSharding(mesh, P(axis)))"),
     ("ops/retrieval.py", "self._allow_dev = jax.device_put("),
     ("ops/retrieval.py", "self._allow_dev = ("),

@@ -111,7 +111,9 @@ ENGINES = {"fake": _fake, "reco": _reco, "ecom": _ecom}
 # host_prep (supplement) and build (serving.serve); the recommendation
 # engine's float32 path names its dispatch and its blocking fetch too;
 # the e-commerce engine carves its store read and its list assembly
-# out of host prep (its table is float32: nothing is refined)
+# out of host prep (its table is float32: nothing is refined), and its
+# workflow's mesh holds the suite's eight devices, so its retriever is
+# row-sharded and each batch merges the shards' candidates
 ENTERED = {
     "fake": (tr.HOST_PREP, tr.BUILD),
     "reco": (tr.HOST_PREP, tr.DISPATCH, tr.DEVICE_WAIT, tr.BUILD),

@@ -286,3 +286,55 @@ def test_int8_stage1_shards_over_four_chips(mesh4):
     ).compile()
     catalog_bytes = _resident_rows(N_ITEMS, 4) * (RANK + 4 + 4 + 1)  # rows + scale + rn + mask
     assert compiled.memory_analysis().argument_size_in_bytes < catalog_bytes / 2
+
+
+@pytest.mark.parametrize("batch, exclude, include", [(8, 16, 1), (32, 64, 256)])
+def test_similar_product_float32_shards_over_four_chips_at_the_amazon_shape(
+        mesh4, batch, exclude, include):
+    """The float32 deployment of the Similar Product catalog on one
+    four-chip host: 9,400,000 x 512 row-sharded, the ``shard_map``
+    program ``ItemRetriever`` builds on the mesh and the merge after it,
+    at the narrowest and the widest corner of the warm ladder. Each chip
+    holds a quarter of the table (4.8 GB; the whole, 19.25 GB, fits no
+    chip) and the program fits beside it; the merge reads the shards'
+    candidates alone."""
+    n_items, k, n_local = 9_400_000, 512, 16
+    n = _resident_rows(n_items, 4)
+    widths = (exclude, include, 4)
+    rows = NamedSharding(mesh4, P("data"))
+    rows_k = NamedSharding(mesh4, P("data", None))
+    rep = NamedSharding(mesh4, P())
+    kernel = functools.partial(
+        retrieval._shard_topk_kernel, axis="data", n_local=n_local,
+        positive_only=True, normalize=True, widths=widths,
+    )
+    fn = jax.jit(
+        jax.shard_map(
+            kernel, mesh=mesh4,
+            in_specs=(
+                P(None, None), P("data", None), P("data"), P("data"),
+                P("data", None),
+            ),
+            out_specs=P(None, "data"),
+            check_vma=False,
+        )
+    )
+    compiled = fn.lower(
+        _shape((batch, k + sum(widths) + 3), jnp.int32, rep),
+        _shape((n, k), jnp.float32, rows_k),
+        _shape((n,), jnp.float32, rows),  # reciprocal norms
+        _shape((n,), jnp.bool_, rows),  # candidacy mask
+        _shape((n, 1), jnp.int32, rows_k),  # one category code an item
+    ).compile()
+    # a device's arguments: its quarter of the rows, and the per-item
+    # vectors of those rows (9 bytes an item)
+    shard = n // 4 * k * 4
+    args = compiled.memory_analysis().argument_size_in_bytes
+    assert shard < args < 1.01 * shard, (args, shard)
+    assert _device_bytes(compiled) < HBM_BYTES
+    merge = retrieval._merge_candidates.lower(
+        _shape((batch, 4 * 2 * n_local), jnp.int32,
+               NamedSharding(mesh4, P(None, "data"))),
+        n=16, n_local=n_local, rep_s=rep,
+    ).compile()
+    assert _device_bytes(merge) < 2**20
