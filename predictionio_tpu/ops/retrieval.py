@@ -50,7 +50,9 @@ the merge's dispatch is the batch stage ``merge``),
 ``pio_retrieval_mask_age_seconds{component}``,
 ``pio_retrieval_resident_bytes{component}``,
 ``pio_retrieval_operand_transfers_total{component}`` (host-to-device
-transfers ``topn`` made: one a call), and for the quantized tiers' host
+transfers ``topn`` made: one a call),
+``pio_retrieval_topk_two_level_total{component}`` (runs whose top-k took
+its second level, ``_two_level``), and for the quantized tiers' host
 refine ``pio_retrieval_shortlist_rows_total{component}`` (candidate
 rows gathered and rescored) and
 ``pio_retrieval_refine_changed_total{component}`` (answers it changed).
@@ -294,6 +296,15 @@ def _longest(lists) -> int:
 _LO = 2048
 # the width of one block of the block-wise top-k
 _BLOCK = 1024
+# the width of one sub-block of its second level: one lane row
+_SUB = 128
+# the second level runs from this many winners up. The last sort takes
+# n·_BLOCK scores a query: at the quantized tiers' shortlists (64, 256)
+# that is 65,536 / 262,144, and a top-256 of 262,144 costs 1.5 ms of a
+# 10 ms program on a v5e at a batch of 8 (PERF.md §5), where n·_SUB =
+# 32,768 is an eighth of it. At n <= 32 (the float32 programs' top-16,
+# each shard's) the sort is small and the program stays as it is
+_SUB_FROM = 64
 # resident rows come in whole blocks of both (``ItemRetriever`` pads its
 # table once, at build), so a score block, its masks and its block
 # maxima are reshapes of one another and no program pads or slices
@@ -368,7 +379,12 @@ def _top_k(scores, n: int):
     any width, goes to ``lax.top_k`` itself. A dead slot (fewer than
     ``n`` live candidates) holds -inf and the index of any row, a
     padding row's too: ``ItemRetriever._unpack`` brings those under
-    ``n_items`` on the host."""
+    ``n_items`` on the host.
+
+    From ``_SUB_FROM`` winners up (``_two_level``) the same argument
+    runs once more inside the ``n`` blocks: the maxima of their
+    sub-blocks of ``_SUB`` in index order, the ``n`` best of those, and
+    the last sort over ``n·_SUB`` scores instead of ``n·_BLOCK``."""
     b, rows = scores.shape
     if -(-rows // _BLOCK) <= 2 * n:  # a narrow block: nothing to gain
         return jax.lax.top_k(scores, n)
@@ -379,10 +395,40 @@ def _top_k(scores, n: int):
     cand = jnp.take_along_axis(
         blocks, best.reshape(b // g, g, n, 1), axis=2
     )
-    s, j = jax.lax.top_k(cand.reshape(b, n * _BLOCK), n)
-    return s, (
-        jnp.take_along_axis(best, j // _BLOCK, axis=1) * _BLOCK + j % _BLOCK
+    if not _two_level(rows, n):
+        s, j = jax.lax.top_k(cand.reshape(b, n * _BLOCK), n)
+        return s, (
+            jnp.take_along_axis(best, j // _BLOCK, axis=1) * _BLOCK
+            + j % _BLOCK
+        )
+    per = _BLOCK // _SUB
+    subs = cand.reshape(b // g, g, n * per, _SUB)
+    _, sub = jax.lax.top_k(subs.max(axis=3).reshape(b, n * per), n)
+    sub = jnp.sort(sub, axis=1)
+    cand = jnp.take_along_axis(
+        subs, sub.reshape(b // g, g, n, 1), axis=2
+    ).reshape(b, n * _SUB)
+    # a stable sort, not lax.top_k: a TPU splits a top-256 of 32,768
+    # into sorts by value alone, which leave equal scores in any order
+    # (PERF.md §6); sorted stably, ties go to the lowest index
+    s, j = jax.lax.sort(
+        (-cand, jax.lax.broadcasted_iota(jnp.int32, cand.shape, 1)),
+        dimension=1, is_stable=True,
     )
+    s, j = -s[:, :n], j[:, :n]
+    # winner -> its sub-block among the candidates -> its block's row
+    sub = jnp.take_along_axis(sub, j // _SUB, axis=1)
+    return s, (
+        jnp.take_along_axis(best, sub // per, axis=1) * _BLOCK
+        + sub % per * _SUB + j % _SUB
+    )
+
+
+def _two_level(rows: int, n: int) -> bool:
+    """Does ``_top_k`` over ``rows`` scores a query take its second
+    level for the top ``n``? Static on the shapes, so one executable
+    always does or never does."""
+    return n >= _SUB_FROM and -(-rows // _BLOCK) > 2 * n
 
 
 def _mask_scores(
@@ -829,6 +875,16 @@ def _m_operand_transfers():
         "Host-to-device transfers ItemRetriever.topn made for its "
         "batches' operands (query rows, id lists, category codes and "
         "flags travel as one packed buffer: one a call)",
+        labels=("component",),
+    )
+
+
+def _m_topk_two_level():
+    return _metrics.get_registry().counter(
+        "pio_retrieval_topk_two_level_total",
+        "Runs of a retrieval program whose block-wise top-k took its "
+        "second level (the best sub-blocks of 128 inside the best blocks "
+        "of 1,024, for a top-k of 64 or more: the quantized shortlists)",
         labels=("component",),
     )
 
@@ -1291,6 +1347,12 @@ class ItemRetriever:
             f"{what} of {width} is over the ladder's top {ladder[-1]}"
         )
 
+    def _count_top_k(self, rows: int, n: int) -> None:
+        """A run of a program whose ``_top_k`` over ``rows`` scores a
+        query takes its second level, counted (static per executable)."""
+        if _two_level(rows, n):
+            _m_topk_two_level().labels(component=self.component).inc()
+
     def _upload(self, operand: np.ndarray):
         """A batch's packed operand onto the device(s): the one
         host-to-device transfer of a ``topn`` call, counted."""
@@ -1399,6 +1461,7 @@ class ItemRetriever:
                     self._codes_dev.shape[1],
                     n, positive_only, normalize,
                 )
+                self._count_top_k(self._n_pad, n)
                 with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
@@ -1415,6 +1478,7 @@ class ItemRetriever:
                     n_dev, shortlist, positive_only, normalize,
                     self.precision,
                 )
+                self._count_top_k(self._n_pad, shortlist)
                 with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
@@ -1460,6 +1524,10 @@ class ItemRetriever:
         resident = (
             (self._y_dev, self._rn_dev) if shortlist is None
             else (self._y_dev, self._scale_operand, self._rn_dev)
+        )
+        self._count_top_k(
+            self._n_pad // self._n_shards,
+            n_local if shortlist is None else shortlist,
         )
         t0 = time.perf_counter()
         with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(

@@ -7,8 +7,17 @@ must not change: item counts on both sides of a block edge, every kind
 of mask, the same ids in the same order as ``naive_topn_reference``,
 ties to the lowest index, dead slots that stay under ``n_items``, an id
 in the last real block that bites and one in the pad's range that is
-dropped."""
+dropped.
 
+Below them the cases of ``_top_k`` itself against ``lax.top_k`` over
+the whole row, at the widths where it takes its second level (the
+quantized shortlists, 64 and 256), and ``one_level_top_k``: the top-k
+as it was before that level, the yardstick of "the same answer"."""
+
+import math
+
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from predictionio_tpu.ops import retrieval
@@ -98,3 +107,58 @@ def _check(r, Y, q, codes, mask, mesh):
     if mask == "whitelist":
         assert list(i[0, :3]) == list(ref_i[0, :3]) and i[0, 0] == last
         assert (s[0, 3:] == -np.inf).all() and (s[2] == -np.inf).all()
+
+
+# _top_k's second level: n from _SUB_FROM up, the batches of the ladder,
+# and rows of whole blocks, more than 2·n of them for the widest n
+TOP_K_WIDTHS = (64, 256)
+TOP_K_BATCHES = (8, 16, 32)
+TOP_K_ROWS = 600 * 1024
+TOP_K_KINDS = ("ties", "dead", "one_block")
+
+
+def top_k_scores(n, batch, kind):
+    """[batch, TOP_K_ROWS] float32 scores of one kind of case.
+
+    ``ties``: few distinct values, so every rank is a tie; row 0 holds
+    one value throughout, and row 1 a run of 1.5·n equal best scores
+    that starts inside a sub-block just before a block boundary and
+    crosses it and several sub-block boundaries: the lowest indices win.
+    ``dead``: row 0 has 7 live scores (fewer than ``n``), row 1 none,
+    row 2 exactly ``n``. ``one_block``: row 0's best 1,024 lie in one
+    block, row 1's best ``n`` in one sub-block where ``n`` fits one."""
+    rng = np.random.default_rng(n * 1000 + batch)
+    s = rng.standard_normal((batch, TOP_K_ROWS)).astype(np.float32)
+    if kind == "ties":
+        s = np.round(s * 2).astype(np.float32)
+        s[0] = 1.0
+        start = 3 * 1024 - n // 2 - 5
+        s[1, start:start + n + n // 2] = 100.0
+    elif kind == "dead":
+        s[0, : TOP_K_ROWS - 7] = -np.inf
+        s[1] = -np.inf
+        s[2, rng.permutation(TOP_K_ROWS)[: TOP_K_ROWS - n]] = -np.inf
+    else:
+        s[0, 7 * 1024: 8 * 1024] += 10.0
+        s[1, 9 * 1024 + 256: 9 * 1024 + 384] += 10.0
+    return s
+
+
+def one_level_top_k(scores, n: int):
+    """``ops/retrieval.py::_top_k`` with one level of blocks alone:
+    the top ``n`` of the ``n`` best blocks' ``n·_BLOCK`` scores."""
+    b, rows = scores.shape
+    block = retrieval._BLOCK
+    if -(-rows // block) <= 2 * n:
+        return jax.lax.top_k(scores, n)
+    g = math.gcd(b, 8)
+    blocks = scores.reshape(b // g, g, rows // block, block)
+    _, best = jax.lax.top_k(blocks.max(axis=3).reshape(b, -1), n)
+    best = jnp.sort(best, axis=1)
+    cand = jnp.take_along_axis(
+        blocks, best.reshape(b // g, g, n, 1), axis=2
+    )
+    s, j = jax.lax.top_k(cand.reshape(b, n * block), n)
+    return s, (
+        jnp.take_along_axis(best, j // block, axis=1) * block + j % block
+    )

@@ -139,6 +139,129 @@ class TestRetrieverParity:
         and row-sharded over four."""
         blocks.check(n_items, "float32", mask, _mesh_or_none(shards))
 
+    @pytest.mark.parametrize("kind", blocks.TOP_K_KINDS)
+    @pytest.mark.parametrize("batch", blocks.TOP_K_BATCHES)
+    @pytest.mark.parametrize("n", blocks.TOP_K_WIDTHS)
+    def test_two_level_top_k_is_lax_top_k_over_the_row(self, n, batch, kind):
+        """``_top_k`` where it takes its second level (the best
+        sub-blocks inside the best blocks) against ``lax.top_k`` over
+        the whole row: the same scores and the same indices, ties to the
+        lowest index and the dead slots' indices included
+        (tests/retrieval_blocks.py says what each kind holds)."""
+        import jax.numpy as jnp
+
+        scores = jnp.asarray(blocks.top_k_scores(n, batch, kind))
+        assert retrieval._two_level(scores.shape[1], n)
+        want_s, want_i = jax.lax.top_k(scores, n)
+        got_s, got_i = jax.jit(retrieval._top_k, static_argnums=1)(scores, n)
+        np.testing.assert_array_equal(np.asarray(got_s), np.asarray(want_s))
+        np.testing.assert_array_equal(np.asarray(got_i), np.asarray(want_i))
+        got_s, got_i = np.asarray(got_s), np.asarray(got_i)
+        if kind == "ties":
+            np.testing.assert_array_equal(got_i[0], np.arange(n))
+            start = 3 * 1024 - n // 2 - 5
+            np.testing.assert_array_equal(got_i[1], np.arange(start, start + n))
+        elif kind == "dead":
+            assert (got_s[0] > -np.inf).sum() == 7
+            assert (got_s[1] == -np.inf).all() and (got_s[2] > -np.inf).all()
+        else:
+            assert (got_i[0] // 1024 == 7).all()
+            assert (got_i[1, : min(n, 128)] // 128 == 9 * 8 + 2).all()
+
+    @pytest.mark.parametrize("n, shortlist", [(16, 64), (64, 256)])
+    def test_the_int8_program_serves_what_one_level_served(
+            self, n, shortlist, monkeypatch):
+        """``_fused_topn_single_2s`` over a seeded int8 table of 614,400
+        rows returns the packed buffer, ids and order, bit for bit, that
+        the same program returns with the top-k of one level of blocks:
+        the device hands the refine the same candidates."""
+        import jax.numpy as jnp
+
+        rows, k, b, widths = blocks.TOP_K_ROWS, 16, 8, (16, 1, 4)
+        rng = np.random.default_rng(shortlist)
+        yq = jnp.asarray(rng.integers(-127, 128, (rows, k), dtype=np.int8))
+        scale = jnp.asarray(rng.uniform(0.005, 0.02, rows), jnp.float32)
+        rn = jnp.asarray(rng.uniform(0.5, 2.0, rows), jnp.float32)
+        allow = jnp.asarray(np.arange(rows) < rows - 300)
+        codes = jnp.asarray(rng.integers(0, 24, (rows, 1)), jnp.int32)
+        q = rng.standard_normal((b, k)).astype(np.float32)
+        excl = [rng.integers(0, rows, 1 + i) for i in range(b)]
+        cats = [np.array([i], np.int32) if i % 3 == 0 else None
+                for i in range(b)]
+        operand = jnp.asarray(retrieval._pack_operand(
+            q, b, widths, rows, excl, (), cats, ()))
+        args = (operand, yq, scale, rn, allow, codes)
+        kw = dict(n=n, shortlist=shortlist, positive_only=True,
+                  normalize=True, precision="int8", widths=widths)
+        assert retrieval._two_level(rows, shortlist)
+        got = np.asarray(retrieval._fused_topn_single_2s(*args, **kw))
+        monkeypatch.setattr(retrieval, "_top_k", blocks.one_level_top_k)
+        one_level = jax.jit(
+            retrieval._fused_topn_single_2s.__wrapped__,
+            static_argnames=tuple(kw),
+        )
+        want = np.asarray(one_level(*args, **kw))
+        np.testing.assert_array_equal(got, want)
+        assert (want[:, :n].view(np.float32) > 0).all()  # all live
+
+    @pytest.mark.parametrize("n", [4, 16, 32, 64])
+    def test_a_narrow_top_k_lowers_as_it_did(self, n):
+        """Below ``_SUB_FROM`` winners (the float32 programs' top-16,
+        each shard's) ``_top_k`` over a block wide enough to leave the
+        narrow shortcut traces to the very program of one level of
+        blocks, op for op; from there up it adds the sub-blocks'
+        reduction."""
+        import functools
+
+        import jax.numpy as jnp
+
+        scores = jax.ShapeDtypeStruct((8, 200 * 1024), jnp.float32)
+        assert 200 > 2 * n  # past the narrow shortcut
+
+        def ops(top_k):
+            jaxpr = jax.make_jaxpr(functools.partial(top_k, n=n))(scores)
+            return str(jaxpr), sum(
+                e.primitive.name == "reduce_max" for e in jaxpr.eqns)
+
+        got, reductions = ops(retrieval._top_k)
+        want, one = ops(blocks.one_level_top_k)
+        if n < retrieval._SUB_FROM:
+            assert got == want and reductions == one == 1
+        else:
+            assert got != want and reductions == 2
+
+    @pytest.mark.parametrize("shards", [1, 4])
+    def test_the_two_level_counter(self, shards):
+        """``pio_retrieval_topk_two_level_total`` counts a run of a
+        program whose top-k took its second level: an int8 ``topn`` at
+        a shortlist of 256 on one device (of 64 a shard over four, whose
+        answer is the float32 reference's), not a float32 one."""
+        mesh = _mesh_or_none(shards)
+        rng = np.random.default_rng(40)
+        Y = rng.standard_normal((blocks.TOP_K_ROWS - 5, 8)).astype(np.float32)
+        q = Y[:3]
+        n, shortlist = (16, 256) if shards == 1 else (4, 64)
+        family = "pio_retrieval_topk_two_level_total"
+        int8, f32 = f"two-level-8x{shards}", f"two-level-32x{shards}"
+        quantized = ItemRetriever(
+            Y, mesh=mesh, precision="int8", component=int8)
+        exact = ItemRetriever(Y, mesh=mesh, component=f32)
+        try:
+            rows = quantized._n_pad // shards
+            n_dev = min(quantized._shortlist_width(n, quantized.n_items), rows)
+            assert quantized._shortlist_width(n_dev, rows) == shortlist
+            before = _family_value(family, component=int8)
+            s, i = quantized.topn(q, n)
+            quantized.topn(q, n)
+            assert _family_value(family, component=int8) == before + 2
+            ref_s, ref_i = naive_topn_reference(Y, q, n)
+            np.testing.assert_array_equal(i, ref_i)
+            exact.topn(q, n)
+            assert _family_value(family, component=f32) == 0
+        finally:
+            quantized.free()
+            exact.free()
+
     @pytest.mark.parametrize("precision", ["float32", "bf16", "int8"])
     def test_a_mapped_table_is_padded_on_the_device_alone(
             self, precision, tmp_path):

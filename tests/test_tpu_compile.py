@@ -198,6 +198,35 @@ def _assert_one_blocked_form(compiled, batch, n_items):
             assert (m[1], m[3]) == ("pred", "copy"), line
 
 
+def _sorts(compiled):
+    """(width along the sorted dimension, stable?) of every ``sort`` and
+    every ``TopK`` custom call in a compiled program (an operand's shape
+    is read from the line that defines it). A ``TopK`` keeps ties in
+    index order on the chip; a ``sort`` does only where it is stable."""
+    text = compiled.as_text()
+    shapes = {
+        m[1]: [int(d) for d in m[2].split(",") if d]
+        for m in re.finditer(
+            r"(?m)^\s*(?:ROOT )?%(\S+) = \w+\[([\d,]*)\]", text)
+    }
+    found = []
+    for line in text.splitlines():
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = .*? sort\(%([^,)]+).*dimensions=\{(\d+)\}",
+            line,
+        )
+        if m:
+            found.append(
+                (shapes[m[1]][int(m[2])], "is_stable=true" in line))
+        m = re.match(
+            r"\s*(?:ROOT )?%\S+ = .*? custom-call\(%([^,)]+)\)"
+            r'.*custom_call_target="TopK"', line,
+        )
+        if m:
+            found.append((shapes[m[1]][-1], True))
+    return found
+
+
 def test_int8_stage1_single_device_compiles(one_chip):
     compiled = retrieval._fused_topn_single_2s.lower(
         *_stage1_shapes(one_chip, one_chip, one_chip),
@@ -239,7 +268,11 @@ def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
     resident (4.8 GB; in float32 it would not fit), the device's
     candidate list 64 wide and its stage-1 shortlist 256, cosine
     scores, four category codes, all in the batch's one packed operand.
-    Each has to fit one chip beside the table."""
+    Each has to fit one chip beside the table, and no sort or top-k in
+    it takes more than 256 sub-blocks of 128 scores a query: the
+    shortlist's top-k takes its second level (a top-256 of 262,144 was
+    1.5 ms of a 10 ms run). Every sort in it is stable, so equal scores
+    keep their index order (the chip's top-256 of 32,768 did not)."""
     n_items, k = 9_400_000, 512
     n = _resident_rows(n_items)
     widths = (exclude, include, 4)
@@ -255,6 +288,9 @@ def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
     _assert_one_blocked_form(compiled, batch, n_items)
+    sorts = _sorts(compiled)
+    assert sorts and max(w for w, _ in sorts) <= 256 * retrieval._SUB, sorts
+    assert all(stable for _, stable in sorts), sorts
 
 
 def test_int8_stage1_shards_over_four_chips(mesh4):
