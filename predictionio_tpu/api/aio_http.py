@@ -56,6 +56,7 @@ from predictionio_tpu.api.http import (
     request_trace_id,
 )
 from predictionio_tpu.utils import metrics as _metrics
+from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
@@ -114,13 +115,29 @@ class AsyncJsonHTTPServer:
         # is the event loop's: the wake-up after the handler's future
         # resolves, json.dumps, the write.
         self._timed_routes = frozenset(timed_routes)
-        self._m_request = _metrics.get_registry().histogram(
-            "pio_http_request_seconds",
-            "Request parsed until its response was written, for the "
-            "routes the server names as timed",
-            labels=("server",),
-            buckets=_metrics.LATENCY_BUCKETS_S,
-        ).labels(server=name) if self._timed_routes else None
+        # and two parts of it: the hand-off from the thread that
+        # resolved the handler's future to this loop, and the render
+        # and write after it
+        self._m_request, self._m_handoff, self._m_respond = (
+            _metrics.get_registry().histogram(
+                family, help_, labels=("server",),
+                buckets=_metrics.LATENCY_BUCKETS_S,
+            ).labels(server=name) if self._timed_routes else None
+            for family, help_ in (
+                ("pio_http_request_seconds",
+                 "Request parsed until its response was written, for "
+                 "the routes the server names as timed"),
+                ("pio_http_handoff_seconds",
+                 "A timed route's future resolved (its resolved_at "
+                 "stamp) until the connection's writer resumed with "
+                 "it: the event loop's wake-up, and any wait behind an "
+                 "earlier response on the same connection"),
+                ("pio_http_respond_seconds",
+                 "A timed route's writer resumed with its result until "
+                 "the response was handed to the socket: the render "
+                 "and the write"),
+            )
+        )
         self._pass_headers = accepts_headers(handle_fn)
         # bind synchronously so construction fails loudly (port conflict,
         # missing SO_REUSEPORT) and .port is known before the loop spins
@@ -447,11 +464,14 @@ class AsyncJsonHTTPServer:
                     # the batching executor is dropped from its batch
                     result.cancel()
                 continue
+            resolved_at = None
             try:
                 if isinstance(result, concurrent.futures.Future):
                     # the future-based handoff: the in-flight request is
                     # this queue entry, not a parked OS thread
-                    result = await asyncio.wrap_future(result)
+                    handed = result
+                    result = await asyncio.wrap_future(handed)
+                    resolved_at = getattr(handed, "resolved_at", None)
                 elif inspect.isawaitable(result):
                     result = await result
             except asyncio.CancelledError:
@@ -462,30 +482,41 @@ class AsyncJsonHTTPServer:
                     extra={"traceId": trace_id} if trace_id else None,
                 )
                 result = (500, {"message": str(e)})
-            status = None
-            try:
-                # rendering is inside the invariant too: a payload
-                # json.dumps can't encode (or a malformed handler tuple)
-                # must produce a 500, not kill the writer and wedge the
-                # reader on the bounded queue
-                head, data = self._render(result, keep_alive)
-                status = result[0]
-            except Exception as e:
-                logger.exception("unrenderable handler result %r", result)
-                head, data = self._render(
-                    (500, {"message": str(e)}), keep_alive
-                )
-                status = 500
-            record_http_error(self.name, route, status, trace_id)
-            try:
-                writer.write(head + data)
+            if parsed_at is not None:
+                resumed = time.perf_counter()
+                if resolved_at is not None:
+                    self._m_handoff.observe(resumed - resolved_at)
+            with _tracing.annotation("respond"):
+                head, data, status = self._render_or_500(result, keep_alive)
+                record_http_error(self.name, route, status, trace_id)
+                try:
+                    writer.write(head + data)
+                except (ConnectionError, OSError):
+                    discarding = True  # peer went away; drain to _CLOSE
+            if not discarding:
                 if parsed_at is not None:
-                    self._m_request.observe(time.perf_counter() - parsed_at)
-                await writer.drain()
-            except (ConnectionError, OSError):
-                discarding = True  # peer went away; drain to _CLOSE
+                    written = time.perf_counter()
+                    self._m_respond.observe(written - resumed)
+                    self._m_request.observe(written - parsed_at)
+                try:
+                    await writer.drain()
+                except (ConnectionError, OSError):
+                    discarding = True
             if not keep_alive:
                 discarding = True  # discard pipelined leftovers
+
+    def _render_or_500(self, result, keep_alive: bool):
+        """(head, body, status) of a handler's result. Rendering is
+        inside the writer's invariant too: a payload json.dumps can't
+        encode (or a malformed handler tuple) must produce a 500, not
+        kill the writer and wedge the reader on the bounded queue."""
+        try:
+            head, data = self._render(result, keep_alive)
+            return head, data, result[0]
+        except Exception as e:
+            logger.exception("unrenderable handler result %r", result)
+            head, data = self._render((500, {"message": str(e)}), keep_alive)
+            return head, data, 500
 
     @staticmethod
     def _render(result, keep_alive: bool) -> Tuple[bytes, bytes]:

@@ -758,6 +758,10 @@ class _BatchingExecutor:
                    f"Seconds of one served micro-batch spent in its "
                    f"{stage} stage")
                   for stage in _tracing.BATCH_STAGES),
+                ("batch_unstaged",
+                 "Seconds of one served micro-batch's predict that no "
+                 "stage covers: predict less its stages entered at "
+                 "depth 0 (a nested stage is not counted twice)"),
             )
         }
         self._m_stages["immediate"] = _metrics.get_registry().counter(
@@ -973,7 +977,7 @@ class _BatchingExecutor:
             (it[3].trace for it in items if it[3].trace is not None), None
         )
         compile_events: List[dict] = []
-        stage_s: Dict[str, float] = {}
+        stage_s = _tracing.StageTotals()
         served = started
         try:
             with self._hb.busy(), _cc.compile_site("serving"), \
@@ -1004,6 +1008,9 @@ class _BatchingExecutor:
             observe["predict"].observe(served - started, n)
             for name, seconds in stage_s.items():
                 observe["batch_" + name].observe(seconds)
+            observe["batch_unstaged"].observe(
+                served - started - stage_s.staged
+            )
             predict_attrs: Optional[Dict[str, Any]] = None
             if batch_trace is not None:
                 # what a traced request's predict span says of the
@@ -1820,6 +1827,9 @@ class QueryAPI:
                 result = (500, {"message": str(e)}, "application/json")
                 # a failed request still shows where its time went
                 times.record_spans(time.perf_counter())
+            # when the answer left this thread: the transport's
+            # pio_http_handoff_seconds runs from here to its writer
+            out.resolved_at = time.perf_counter()
             try:
                 out.set_result(result)
             except concurrent.futures.InvalidStateError:

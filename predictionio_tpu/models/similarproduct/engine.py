@@ -451,26 +451,33 @@ class SPModel(PersistentModel):
         retriever = self._retriever
         out: List[Tuple[int, PredictedResult]] = []
         meta, rows, excludes, includes, cats = [], [], [], [], []
-        for qi, q in queries:
-            spec = self._spec(q)
-            if spec is None:
-                logger.info("no item factors for query items %s", q.items)
-                out.append((qi, PredictedResult()))
-                continue
-            qvec, excl, incl, codes = spec
-            if q.num > max(16, warm_num) or not retriever.fits(
-                exclude=len(excl),
-                include=0 if incl is None else len(incl),
-                categories=0 if codes is None else len(codes),
-            ):
-                _m_host_fallbacks().inc()
-                out.append((qi, self._similar_host(q, spec)))
-                continue
-            meta.append((qi, q))
-            rows.append(qvec)
-            excludes.append(excl)
-            includes.append(incl)
-            cats.append(codes)
+        on_host = []
+        # the name lookups and the query rows' gather from the table
+        with _tracing.stage(_tracing.HOST_PREP):
+            for qi, q in queries:
+                spec = self._spec(q)
+                if spec is None:
+                    logger.info(
+                        "no item factors for query items %s", q.items
+                    )
+                    out.append((qi, PredictedResult()))
+                    continue
+                qvec, excl, incl, codes = spec
+                if q.num > max(16, warm_num) or not retriever.fits(
+                    exclude=len(excl),
+                    include=0 if incl is None else len(incl),
+                    categories=0 if codes is None else len(codes),
+                ):
+                    on_host.append((qi, q, spec))
+                    continue
+                meta.append((qi, q))
+                rows.append(qvec)
+                excludes.append(excl)
+                includes.append(incl)
+                cats.append(codes)
+        for qi, q, spec in on_host:
+            _m_host_fallbacks().inc()
+            out.append((qi, self._similar_host(q, spec)))
         top = retriever.max_batch  # a batch over the ladder's top is split
         for s in range(0, len(meta), top):
             part = meta[s:s + top]

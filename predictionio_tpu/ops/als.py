@@ -2489,18 +2489,21 @@ class ServingFactors:
         exec_key = (
             q.shape, self._if_dev.shape, n, self.mesh is None,
         )
-        # dispatch: the query rows' upload and the program's call
-        # returning (asynchronous: the device may still be running)
+        # dispatch: the query rows' upload (its own stage inside) and
+        # the program's call returning (asynchronous: the device may
+        # still be running)
         with _tracing.stage(_tracing.DISPATCH):
             if self.mesh is None:
-                q_dev = jax.device_put(q)
+                with _tracing.stage(_tracing.UPLOAD):
+                    q_dev = jax.device_put(q)
                 with _cc.track_compile("serving-topk", _TOPK_SEEN, exec_key):
                     return _topn_packed(q_dev, self._if_dev, n)
             # shard_batch further pads so the batch divides the mesh axis
             # (a no-op for power-of-two axes), then places row-sharded
             from predictionio_tpu.parallel.mesh import shard_batch
 
-            q_dev, _ = shard_batch(self.mesh, q, self._axis)
+            with _tracing.stage(_tracing.UPLOAD):
+                q_dev, _ = shard_batch(self.mesh, q, self._axis)
             with _cc.track_compile("serving-topk", _TOPK_SEEN, exec_key):
                 return _topn_packed_sharded(
                     q_dev, self._if_dev, n,

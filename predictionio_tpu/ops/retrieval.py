@@ -41,9 +41,7 @@ lesson of ``_topn_packed_sharded``): one fetch per batch, ids never
 pass through a float.
 
 Metrics (utils/metrics.py conventions, visible in ``pio top``):
-``pio_retrieval_shard_topk_seconds`` / ``pio_retrieval_merge_seconds``
-(every batch off-mesh; SAMPLED on the sharded path — the split needs a
-host sync), ``pio_retrieval_merge_rows_total{component}`` (candidate
+``pio_retrieval_merge_rows_total{component}`` (candidate
 rows the merge takes across the sharded→replicated hop, every batch;
 the merge's dispatch is the batch stage ``merge``),
 ``pio_retrieval_mask_refresh_total{component,outcome}``,
@@ -66,12 +64,15 @@ utils/device_ledger.py) — component ``<component>`` for factors+norms,
 report through utils/compilation_cache.py's executable-cache
 accounting, so one compiling inside a live serving batch is counted in
 ``pio_cold_compiles_total{site="serving"}`` and annotated on the
-serving trace. Sampled batches also record padding waste
-(``pio_padding_waste_ratio{site}``) and cross-shard skew
+serving trace. Batches record padding waste
+(``pio_padding_waste_ratio{site}``), and on a mesh one batch in
+``_SKEW_SAMPLE_EVERY`` records cross-shard skew
 (``pio_retrieval_shard_skew{kind}`` — candidate-count and final-result
 imbalance over the mesh, the stage-1 load-imbalance proxy: per-shard
 scoring work is shape-uniform, so imbalance shows up in candidate
-survival, not FLOPs).
+survival, not FLOPs), from the shards' candidates fetched after the
+answer, with no barrier between the two programs. How long the shards'
+program and the merge ran on the device is the profiler trace's to say.
 
 Quantized residency (the approximate-computing MF / ALX recipe for
 10M+-item catalogs, arXiv:1808.03843 + arXiv:2112.02194): with
@@ -128,9 +129,9 @@ from predictionio_tpu.utils import tracing as _tracing
 
 logger = logging.getLogger(__name__)
 
-# how often the sharded path takes the host sync that splits shard-topk
-# vs merge timing (see ItemRetriever.topn)
-_SPLIT_SAMPLE_EVERY = 16
+# one batch in this many on a mesh also fetches the shards' candidates,
+# after its answer, for pio_retrieval_shard_skew (ItemRetriever.topn)
+_SKEW_SAMPLE_EVERY = 16
 
 # executable keys this process already compiled on the SHARED
 # single-device fused-program jit cache (executable-cache accounting:
@@ -829,27 +830,6 @@ def _merge_candidates(packed, n, n_local, rep_s):
 # granularity, following the utils/metrics conventions) ---
 
 
-def _m_shard_seconds():
-    return _metrics.get_registry().histogram(
-        "pio_retrieval_shard_topk_seconds",
-        "Device time of the fused per-shard score+mask+top_k stage "
-        "(single-device: the whole fused retrieval program, every "
-        "batch; sharded: sampled batches only — the split needs a "
-        "host sync)",
-        buckets=_metrics.LATENCY_BUCKETS_S,
-    )
-
-
-def _m_merge_seconds():
-    return _metrics.get_registry().histogram(
-        "pio_retrieval_merge_seconds",
-        "Time of the cross-shard candidate merge (the "
-        "sharded->replicated hop + final top_k + result fetch; "
-        "sampled batches only)",
-        buckets=_metrics.LATENCY_BUCKETS_S,
-    )
-
-
 def _m_merge_rows():
     return _metrics.get_registry().counter(
         "pio_retrieval_merge_rows_total",
@@ -1357,7 +1337,8 @@ class ItemRetriever:
         """A batch's packed operand onto the device(s): the one
         host-to-device transfer of a ``topn`` call, counted."""
         _m_operand_transfers().labels(component=self.component).inc()
-        return jax.device_put(operand, self._operand_at)
+        with _tracing.stage(_tracing.UPLOAD):
+            return jax.device_put(operand, self._operand_at)
 
     def topn(
         self,
@@ -1451,7 +1432,6 @@ class ItemRetriever:
             (b_pad - b) / b_pad
         )
         if self.mesh is None:
-            t0 = time.perf_counter()
             # executable-cache accounting: the fused program's jit cache
             # is keyed by shapes + statics; a NEW key here is a compile
             # (cold if it happens under a serving compile_site)
@@ -1491,7 +1471,6 @@ class ItemRetriever:
                     )
             with _tracing.stage(_tracing.DEVICE_WAIT):
                 host = np.asarray(packed)[:b]
-            _m_shard_seconds().observe(time.perf_counter() - t0)
             if self.precision != "float32":
                 return self._refine_exact(
                     q, host, n_dev, n, positive_only,
@@ -1510,13 +1489,7 @@ class ItemRetriever:
         stage1 = self._stage1(
             n_local, positive_only, normalize, widths, shortlist
         )
-        # the shard-vs-merge timing split needs a host sync between the
-        # two programs, which would serialize an otherwise back-to-back
-        # dispatch on EVERY batch — so the split is SAMPLED (first
-        # batch, then every _SPLIT_SAMPLE_EVERY-th); unsampled batches
-        # run barrier-free and record nothing in these families
         self._batches += 1
-        split = self._batches % _SPLIT_SAMPLE_EVERY == 1
         exec_key = (
             n_local, positive_only, normalize, b_pad, *widths, shortlist,
             self.precision,
@@ -1529,7 +1502,6 @@ class ItemRetriever:
             self._n_pad // self._n_shards,
             n_local if shortlist is None else shortlist,
         )
-        t0 = time.perf_counter()
         with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
             "retrieval-stage1", self._exec_seen, exec_key
         ):
@@ -1537,10 +1509,6 @@ class ItemRetriever:
                 self._upload(operand), *resident, self._allow_dev,
                 self._codes_dev,
             )
-        if split:
-            jax.block_until_ready(cand)
-            t1 = time.perf_counter()
-            _m_shard_seconds().observe(t1 - t0)
         with _tracing.stage(_tracing.MERGE):
             packed = _merge_candidates(cand, n_dev, n_local, self._rep_out)
         _m_merge_rows().labels(component=self.component).inc(
@@ -1548,11 +1516,9 @@ class ItemRetriever:
         )
         with _tracing.stage(_tracing.DEVICE_WAIT):
             host = np.asarray(packed)[:b]
-        if split:
-            _m_merge_seconds().observe(time.perf_counter() - t1)
-            # sampled skew: the candidate buffer is already synced (the
-            # split's block_until_ready), so the extra fetch costs one
-            # host copy on 1/_SPLIT_SAMPLE_EVERY batches only
+        if self._batches % _SKEW_SAMPLE_EVERY == 1:
+            # the merge has read the candidates by now, so this fetch
+            # waits on nothing: one host copy, on sampled batches only
             self._record_skew(np.asarray(cand)[:b], host, n_dev, n_local)
         if self.precision != "float32":
             return self._refine_exact(

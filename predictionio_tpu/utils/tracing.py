@@ -10,6 +10,7 @@ the storage-gateway RPC client — so one request's path is a chain of
 spans. Training runs mint their own trace per ``PhaseTimer``:
 
     serving:  http → batch → {queue_wait, slot_wait, predict, finish}
+              → handoff → respond (the transport's histograms)
     ingest:   http → insert → group-commit-flush
     remote:   http → rpc:<dao>.<method> (gateway process) → flush
 
@@ -26,7 +27,12 @@ on the profiler's clock: while a capture of ``utils/profiling`` runs, a
 ``jax.profiler.TraceAnnotation`` named ``pio:<name>`` lands on the
 capture's host planes, which share the device planes' clock, so a
 device-idle gap can be put down to the host phase that was open during
-it (``benchmarks/host_gaps.py``).
+it (``benchmarks/host_gaps.py``). The batch stages are ``BATCH_STAGES``
+(``upload``, the operand's transfer, nests inside ``dispatch``); the
+event loop's ``pio:respond`` is an annotation of its own. Stages nest: a
+stage counts toward the batch's staged total only at depth 0, so what
+``predict`` spends outside every stage is one number
+(``pio_serving_batch_unstaged_seconds``).
 
 Like utils/metrics.py, this module is a sanctioned home for
 module-level observability state (tests/test_lint.py polices the rest
@@ -56,6 +62,7 @@ __all__ = [
     "annotation",
     "stage",
     "stage_totals",
+    "StageTotals",
     "BATCH_STAGES",
     "record_span",
     "span",
@@ -182,6 +189,10 @@ BUILD = "build"
 # inline, and the batch's own stacking and padding
 STORE_READ = "store_read"
 MASK_PREP = "mask_prep"
+# the host-to-device transfer of a batch's query operand
+# (``jax.device_put``), nested inside ``dispatch`` so that dispatch keeps
+# the upload and the program's launch together and this splits them
+UPLOAD = "upload"
 # the quantized retrieval tier's host refine (ops/retrieval.py
 # ``_refine_exact``): the device's shortlist rescored against the
 # original float32 rows, which may be a file mapped into memory
@@ -192,25 +203,39 @@ REFINE = "refine"
 MERGE = "merge"
 BATCH_STAGES = (
     HOST_PREP, DISPATCH, DEVICE_WAIT, BUILD, STORE_READ, MASK_PREP, REFINE,
-    MERGE,
+    MERGE, UPLOAD,
 )
 
 # the per-batch accumulator of stage() durations, bound by the engine
 # server's executor for the length of one serve_batch
-_STAGES: "contextvars.ContextVar[Optional[Dict[str, float]]]" = (
+_STAGES: "contextvars.ContextVar[Optional[StageTotals]]" = (
     contextvars.ContextVar("pio_stages", default=None)
 )
 
 
+class StageTotals(dict):
+    """``{stage name: seconds}`` of one batch, with ``staged``: the
+    seconds of the stages entered at depth 0 (``depth``: stages open now
+    on the thread that bound it), so a nested stage adds under its own
+    name and not twice to what the batch spent inside stages."""
+
+    __slots__ = ("staged", "depth")
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.staged = 0.0
+        self.depth = 0
+
+
 class stage_totals:
-    """Bind a fresh ``{stage name: seconds}`` accumulator for the block
-    (``with stage_totals() as totals``); every :func:`stage` entered on
-    this thread inside it adds its duration there."""
+    """Bind a fresh :class:`StageTotals` for the block (``with
+    stage_totals() as totals``); every :func:`stage` entered on this
+    thread inside it adds its duration there."""
 
     __slots__ = ("_token",)
 
-    def __enter__(self) -> Dict[str, float]:
-        totals: Dict[str, float] = {}
+    def __enter__(self) -> StageTotals:
+        totals = StageTotals()
         self._token = _STAGES.set(totals)
         return totals
 
@@ -222,11 +247,12 @@ class stage:
     """One named host phase of a serving batch (one of
     ``BATCH_STAGES``): a ``pio:<name>`` annotation while a profiler
     capture runs, and, inside :func:`stage_totals`, its duration added
-    under its name (a stage entered twice in a batch adds up). Outside
-    a serving batch (warm-up, eval, a single ``recommend``) it is the
-    annotation alone."""
+    under its name (a stage entered twice in a batch adds up) and, at
+    depth 0 only, to the batch's ``staged`` seconds. Outside a serving
+    batch (warm-up, eval, a single ``recommend``) it is the annotation
+    alone."""
 
-    __slots__ = ("name", "_annotation", "_t0")
+    __slots__ = ("name", "_annotation", "_t0", "_totals")
 
     def __init__(self, name: str):
         assert name in BATCH_STAGES, name
@@ -239,6 +265,9 @@ class stage:
         if _CAPTURING[0]:
             self._annotation = annotation(self.name)
             self._annotation.__enter__()
+        totals = self._totals = _STAGES.get()
+        if totals is not None:
+            totals.depth += 1
         self._t0 = time.perf_counter()
         return self
 
@@ -246,9 +275,12 @@ class stage:
         elapsed = time.perf_counter() - self._t0
         if self._annotation is not None:
             self._annotation.__exit__(*exc)
-        totals = _STAGES.get()
+        totals = self._totals
         if totals is not None:
             totals[self.name] = totals.get(self.name, 0.0) + elapsed
+            totals.depth -= 1
+            if totals.depth == 0:
+                totals.staged += elapsed
 
 
 _SPANS: "collections.deque" = collections.deque(maxlen=MAX_SPANS)

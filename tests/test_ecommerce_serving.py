@@ -260,7 +260,8 @@ def test_a_batch_of_mixed_shapes_is_one_store_read_a_query_and_one_run(
             values = [world.algo.prepare_query(world.model, q)
                       for q in queries]
     events_prepared = _events_read() - events0
-    runs0 = retrieval._m_shard_seconds().labels().count
+    runs = retrieval._m_operand_transfers().labels(component="ecommerce")
+    runs0 = runs.value
     with _CountedReads(world) as in_the_batch:
         world.check(queries, values)
     assert before_the_batch + in_the_batch == [1] * 16
@@ -270,7 +271,7 @@ def test_a_batch_of_mixed_shapes_is_one_store_read_a_query_and_one_run(
         world.n_events.get(q.user, 0) for q in queries)
     assert (events_prepared > 0) == prepared
     # known users and recent-view users rode ONE fused program run
-    assert retrieval._m_shard_seconds().labels().count - runs0 == 1
+    assert runs.value - runs0 == 1
 
 
 CELL_SHAPES = {  # the query shapes of `ecom-taobao-d512.query-filtered`

@@ -402,23 +402,65 @@ class TestRetrieverParity:
         _, i = r.topn(q, 19)
         assert 5 not in i[0][: int((_[0] > -np.inf).sum())]
 
-    def test_timing_families_recorded(self):
+    def test_mesh_topn_takes_no_barrier_and_still_samples_skew(
+        self, monkeypatch
+    ):
+        """The sharded program and the merge run back to back: nothing
+        waits on the shards' candidates between them, and the skew gauge
+        is still set, from candidates fetched after the answer."""
         rng = np.random.default_rng(6)
         Y = rng.standard_normal((16, 4)).astype(np.float32)
         r = ItemRetriever(Y, mesh=_mesh_or_none(2), component="timing")
-        before_shard = _family_value(
-            "pio_retrieval_shard_topk_seconds_count"
+        skew = retrieval._m_shard_skew().labels(kind="candidates")
+        skew.set(-1.0)
+        barriers = []
+        real = jax.block_until_ready
+        monkeypatch.setattr(
+            jax, "block_until_ready",
+            lambda x: barriers.append(x) or real(x),
         )
-        before_merge = _family_value("pio_retrieval_merge_seconds_count")
         r.topn(rng.standard_normal((2, 4)).astype(np.float32), 4)
-        assert (
-            _family_value("pio_retrieval_shard_topk_seconds_count")
-            > before_shard
-        )
-        assert (
-            _family_value("pio_retrieval_merge_seconds_count")
-            > before_merge
-        )
+        assert barriers == []
+        assert skew.value >= 1.0  # the first batch is sampled
+        names = {f.name for f in metrics_mod.get_registry().families()}
+        assert not names & {"pio_retrieval_shard_topk_seconds",
+                            "pio_retrieval_merge_seconds"}
+
+    @pytest.mark.parametrize("path", ["single", "mesh", "serving_factors"])
+    def test_upload_is_recorded_inside_dispatch(self, path, monkeypatch):
+        from predictionio_tpu.ops.als import ServingFactors
+        from predictionio_tpu.utils import tracing
+
+        opened, seen = [], []
+
+        class Recording(tracing.stage):
+            __slots__ = ()
+
+            def __enter__(self):
+                seen.append((self.name, tuple(opened)))
+                opened.append(self.name)
+                return super().__enter__()
+
+            def __exit__(self, *exc):
+                opened.pop()
+                return super().__exit__(*exc)
+
+        rng = np.random.default_rng(7)
+        Y = rng.standard_normal((32, 4)).astype(np.float32)
+        q = rng.standard_normal((3, 4)).astype(np.float32)
+        if path == "serving_factors":
+            run = ServingFactors(q, Y).topn_by_rows
+        else:
+            run = ItemRetriever(
+                Y, mesh=_mesh_or_none(2 if path == "mesh" else 1),
+                component="upload",
+            ).topn
+        monkeypatch.setattr(tracing, "stage", Recording)
+        with tracing.stage_totals() as totals:
+            run(q, 4)
+        assert (tracing.UPLOAD, (tracing.DISPATCH,)) in seen
+        assert [name for name, _ in seen].count(tracing.UPLOAD) == 1
+        assert totals[tracing.UPLOAD] <= totals[tracing.DISPATCH]
 
 
 class _CountedPuts:

@@ -109,14 +109,15 @@ def _ecom(storage):
 ENGINES = {"fake": _fake, "reco": _reco, "ecom": _ecom}
 # the stages each engine brackets: every engine's serve_batch enters
 # host_prep (supplement) and build (serving.serve); the recommendation
-# engine's float32 path names its dispatch and its blocking fetch too;
+# engine's float32 path names its dispatch (the upload inside it) and
+# its blocking fetch too;
 # the e-commerce engine carves its store read and its list assembly
 # out of host prep (its table is float32: nothing is refined), and its
 # workflow's mesh holds the suite's eight devices, so its retriever is
 # row-sharded and each batch merges the shards' candidates
 ENTERED = {
     "fake": (tr.HOST_PREP, tr.BUILD),
-    "reco": (tr.HOST_PREP, tr.DISPATCH, tr.DEVICE_WAIT, tr.BUILD),
+    "reco": (tr.HOST_PREP, tr.DISPATCH, tr.UPLOAD, tr.DEVICE_WAIT, tr.BUILD),
     "ecom": tuple(s for s in BATCH_STAGES if s != tr.REFINE),
 }
 
@@ -218,6 +219,7 @@ def test_batch_stages_count_one_a_batch_inside_predict(served):
     before = {s: total(n) for s, n in fams.items()}
     batches0 = total("pio_serving_batch_fill")[1]
     predict0 = total("pio_serving_predict_seconds")[0]
+    unstaged0 = total("pio_serving_batch_unstaged_seconds")
     for _ in range(6):  # one at a time: a batch of one each
         assert post(server.port, body)[0] == 200
     batches = total("pio_serving_batch_fill")[1] - batches0
@@ -226,9 +228,15 @@ def test_batch_stages_count_one_a_batch_inside_predict(served):
     for stage in BATCH_STAGES:
         seconds, count = (a - b for a, b in zip(total(fams[stage]), before[stage]))
         assert count == (batches if stage in ENTERED[name] else 0)
-        spent += seconds
+        if stage != tr.UPLOAD:  # inside dispatch: its seconds are there
+            spent += seconds
     predict = total("pio_serving_predict_seconds")[0] - predict0
     assert 0.0 < spent <= predict
+    # what no stage covers is the rest of predict, one sample a batch
+    unstaged, count = (a - b for a, b in zip(
+        total("pio_serving_batch_unstaged_seconds"), unstaged0))
+    assert count == batches
+    assert spent + unstaged == pytest.approx(predict, abs=1e-9)
 
 
 def test_traced_request_chains_the_stages_under_batch(served):
@@ -254,8 +262,9 @@ def test_traced_request_chains_the_stages_under_batch(served):
     attrs = spans["predict"]["attrs"]
     assert attrs["batch_size"] == 1
     assert set(attrs["stages_ms"]) == set(ENTERED[name])
-    assert sum(attrs["stages_ms"].values()) <= (
-        spans["predict"]["durationMs"] + 0.01)
+    # upload lies inside dispatch: its milliseconds are there already
+    assert sum(ms for stage, ms in attrs["stages_ms"].items()
+               if stage != tr.UPLOAD) <= spans["predict"]["durationMs"] + 0.01
     assert "attrs" not in spans["queue_wait"]
 
 
@@ -279,6 +288,51 @@ def test_http_family_times_the_query_route_alone(served):
     # the transport's span encloses the handler's
     assert (total("pio_http_request_seconds")[0] - http0
             > total("pio_serving_latency_seconds")[0] - latency0)
+
+
+def test_handoff_and_respond_are_observed_once_a_timed_request(served):
+    _, server, body = served
+    names = ("pio_http_request_seconds", "pio_http_handoff_seconds",
+             "pio_http_respond_seconds")
+    before = [total(n) for n in names]
+    for _ in range(3):
+        assert post(server.port, body)[0] == 200
+        assert get(server.port, "/metrics")[0] == 200
+        assert get(server.port, "/status.json")[0] == 200
+    (http, n_http), (handoff, n_handoff), (respond, n_respond) = (
+        (a - b for a, b in zip(total(n), was))
+        for n, was in zip(names, before)
+    )
+    assert n_http == n_handoff == n_respond == 3
+    # both lie inside the transport's span, after the handler's own
+    assert 0.0 < handoff and 0.0 < respond
+    assert handoff + respond < http
+
+
+def test_an_untimed_route_observes_neither_handoff_nor_respond():
+    """A future that a handler returns on a route the server does not
+    time is awaited and answered like any other, and counted nowhere."""
+    import concurrent.futures
+
+    from predictionio_tpu.api.aio_http import AsyncJsonHTTPServer
+
+    def handle(method, path, query, body, form):
+        fut = concurrent.futures.Future()
+        fut.resolved_at = 0.0
+        fut.set_result((200, {"path": path}))
+        return fut
+
+    server = AsyncJsonHTTPServer(
+        handle, "127.0.0.1", 0, "untimed-test",
+        timed_routes=(("POST", "/timed"),),
+    ).start()
+    names = ("pio_http_handoff_seconds", "pio_http_respond_seconds")
+    try:
+        before = [total(n)[1] for n in names]
+        assert get(server.port, "/other") == (200, '{"path": "/other"}')
+        assert [total(n)[1] for n in names] == before
+    finally:
+        server.shutdown()
 
 
 def gc_child(generation):
@@ -355,6 +409,64 @@ def test_an_unknown_stage_name_is_refused():
 def batch_counts():
     return {s: total(f"pio_serving_batch_{s}_seconds")[1]
             for s in BATCH_STAGES}
+
+
+def test_unstaged_is_predict_less_the_top_level_stages():
+    """One batch whose stages nest: the executor's unstaged sample is
+    its predict less the stages entered at depth 0, so the upload inside
+    dispatch is not taken off twice, and the sleep between stages is
+    what is left."""
+    import time
+    import types
+
+    from predictionio_tpu.api.engine_server import _BatchingExecutor
+
+    class Nested:
+        engine_instance = types.SimpleNamespace(id="unstaged-test")
+
+        def serve_batch(self, queries):
+            with tr.stage(tr.HOST_PREP):
+                time.sleep(0.005)
+            time.sleep(0.02)  # in no stage
+            with tr.stage(tr.DISPATCH):
+                with tr.stage(tr.UPLOAD):
+                    time.sleep(0.01)
+                time.sleep(0.002)
+            return list(queries)
+
+    executor = _BatchingExecutor(8, 1)
+    try:
+        assert executor.submit(Nested(), "q") == "q"
+    finally:
+        executor.close()
+
+    def child(stage):
+        fam = family(f"pio_serving_{stage}_seconds")
+        return dict(fam.children())[("unstaged-test",)]
+
+    predict, unstaged = child("predict"), child("batch_unstaged")
+    staged = [child(f"batch_{s}") for s in (tr.HOST_PREP, tr.DISPATCH)]
+    upload = child("batch_upload")
+    assert predict.count == unstaged.count == upload.count == 1
+    assert upload.sum >= 0.01
+    assert unstaged.sum == pytest.approx(
+        predict.sum - sum(c.sum for c in staged), abs=1e-9)
+    assert 0.02 <= unstaged.sum < 0.02 + upload.sum
+
+
+def test_nested_stages_add_under_their_names_and_once_to_staged():
+    with tr.stage_totals() as totals:
+        with tr.stage(tr.DISPATCH):
+            with tr.stage(tr.UPLOAD):
+                pass
+            with tr.stage(tr.UPLOAD):
+                pass
+        with tr.stage(tr.BUILD):
+            pass
+    assert set(totals) == {tr.DISPATCH, tr.UPLOAD, tr.BUILD}
+    assert totals.depth == 0
+    assert totals.staged == pytest.approx(
+        totals[tr.DISPATCH] + totals[tr.BUILD], abs=1e-12)
 
 
 def test_stage_outside_a_batch_observes_no_batch_family():

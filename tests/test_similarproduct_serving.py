@@ -8,6 +8,7 @@ and the host memory the quantized retriever takes over that map."""
 from __future__ import annotations
 
 import os
+import time
 import tracemalloc
 import types
 
@@ -282,7 +283,7 @@ def test_a_quantized_batch_names_its_refine_and_counts_it():
     with tr.stage_totals() as totals:
         dep.serve_batch(queries)
     assert set(totals) == {tr.HOST_PREP, tr.MASK_PREP, tr.DISPATCH,
-                           tr.DEVICE_WAIT, tr.REFINE, tr.BUILD}
+                           tr.UPLOAD, tr.DEVICE_WAIT, tr.REFINE, tr.BUILD}
     assert all(v > 0 for v in totals.values())
     # the device hands back pow2(4 x 16) = 64 candidates a query
     assert rows.value - rows0 == 3 * 64
@@ -290,6 +291,27 @@ def test_a_quantized_batch_names_its_refine_and_counts_it():
     after = asked.snapshot()
     assert after.count - asked0.count == 3
     assert after.sum - asked0.sum == 2 + 1 + 1  # the unknown item is none
+
+
+def test_the_query_rows_gather_is_host_prep(served, monkeypatch):
+    """``_spec`` (the name lookups and the gather of the query rows from
+    the table) runs inside the batch's host_prep stage, once a query."""
+    _, dep = served
+    model = dep.models[0]
+    calls = []
+    real = SPModel._spec
+
+    def slow_spec(self, query):
+        calls.append(query)
+        time.sleep(0.005)
+        return real(self, query)
+
+    monkeypatch.setattr(SPModel, "_spec", slow_spec)
+    queries = [Query(items=("i9", "i10"), num=4), Query(items=("i11",), num=10)]
+    with tr.stage_totals() as totals:
+        model.similar_batch(list(enumerate(queries)))
+    assert len(calls) == 2
+    assert totals[tr.HOST_PREP] >= 2 * 0.005
 
 
 def test_release_leaves_the_numpy_path_and_no_device(served):
