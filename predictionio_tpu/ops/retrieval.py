@@ -29,7 +29,10 @@ never materializes the full [B, N] score matrix anywhere:
    A RESIDENT global mask (refreshed out-of-band on constraint-entity
    change, see data/constraints.py) plus small per-query
    inclusion/exclusion id lists travel as indices and scatter into the
-   mask on device; masked scores become ``-inf`` before ``top_k``.
+   mask on device; masked scores become ``-inf`` before ``top_k``. An
+   exclusion list no wider than the top-k it feeds is applied to the
+   candidates of a top-k over-fetched by its width instead
+   (``_masked_top_k``): no ``[B, N]`` mask is built for it.
 
 The single-device fallback is the SAME kernel fused into one jit
 (score + mask + top_k, one dispatch) — 1-device serving no longer
@@ -50,7 +53,10 @@ the merge's dispatch is the batch stage ``merge``),
 ``pio_retrieval_operand_transfers_total{component}`` (host-to-device
 transfers ``topn`` made: one a call),
 ``pio_retrieval_topk_two_level_total{component}`` (runs whose top-k took
-its second level, ``_two_level``), and for the quantized tiers' host
+its second level, ``_two_level``),
+``pio_retrieval_exclusion_after_topk_total{component}`` (runs whose
+exclusion lists were applied after the top-k, ``_excl_after_topk``),
+and for the quantized tiers' host
 refine ``pio_retrieval_shortlist_rows_total{component}`` (candidate
 rows gathered and rescored) and
 ``pio_retrieval_refine_changed_total{component}`` (answers it changed).
@@ -111,6 +117,7 @@ import concurrent.futures
 import functools
 import logging
 import math
+import operator
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -441,28 +448,76 @@ def _mask_scores(
     ``excl``/``incl`` are per-query id lists already mapped into THIS
     score block's index space with out-of-range values pointing past the
     last row (``_membership`` drops them — sentinel-padded slots and,
-    on a shard, ids owned by other shards). ``has_incl`` flags queries
+    on a shard, ids owned by other shards); ``excl`` None leaves the
+    exclusion lists to ``_masked_top_k``. ``has_incl`` flags queries
     with a whitelist: only their rows intersect with the scattered
     inclusion mask. ``cat`` = (resident per-item category codes
     [rows, C], the queries' category codes [B, Wc], which queries
     carry any): membership is a compare in this program, so a category
     filter ships its few codes and never a list as wide as the
-    category. Item slots without a category hold -1, query slots -2:
-    padding never matches."""
+    category. Its C x Wc compares are unrolled element-wise ``==`` and
+    ``|`` (both counts static and small), which the scoring pass
+    absorbs: an ``any`` over a ``[B, rows, Wc]`` compare is a fusion of
+    its own that writes a ``[B, rows]`` predicate for the scoring pass
+    to read back. Item slots without a category hold -1, query slots
+    -2: padding never matches."""
     rows = scores.shape[1]
-    allow = allow0[None, :] & ~_membership(excl, rows)
+    allow = allow0[None, :]
+    if excl is not None:
+        allow = allow & ~_membership(excl, rows)
     allow = allow & (_membership(incl, rows) | ~has_incl[:, None])
     if cat is not None:
         codes, cats, has_cat = cat
-        member = jnp.zeros(scores.shape, bool)
-        for c in range(codes.shape[1]):  # static and small: 1 for Taobao
-            member = member | jnp.any(
-                codes[None, :, c, None] == cats[:, None, :], axis=2
-            )
+        member = functools.reduce(operator.or_, (
+            codes[None, :, c] == cats[:, j, None]
+            for c in range(codes.shape[1]) for j in range(cats.shape[1])
+        ))
         allow = allow & (member | ~has_cat[:, None])
     if positive_only:
         allow = allow & (scores > 0)
     return jnp.where(allow, scores, -jnp.inf)
+
+
+def _excl_after_topk(w: int, n: int, rows: int) -> bool:
+    """Are exclusion lists ``w`` wide applied to a top-``(n + w)`` of
+    ``rows`` scores a query rather than as a ``[B, rows]`` membership
+    grid? Where a list is no wider than the top-k it feeds (and is a
+    list: a width of 1 is already one compare inside the scoring pass).
+    Static on the shapes, so one executable always does or never
+    does."""
+    return 1 < w <= n and n + w <= rows
+
+
+def _masked_top_k(
+    scores, n: int, allow0, excl, incl, has_incl, positive_only, cat
+):
+    """``_top_k(_mask_scores(...), n)``, the exclusion lists applied
+    after the top-k where ``_excl_after_topk`` says so: the top
+    ``n + W`` of the scores under every other mask, the candidates
+    whose index is in the query's list marked -inf, the first ``n``
+    kept by a stable sort on that mark. Exact, ties included: at most
+    W listed rows rank above any of the true top ``n``, so each of them
+    is in the top ``n + W``, and the stable sort keeps the survivors in
+    their (score desc, index asc) order. What a ``[B, W]`` list costs
+    is then a ``[B, n + W, W]`` compare, where the grid was a product
+    over every row and a layout copy of its ``pred`` form."""
+    w = excl.shape[1]
+    after = _excl_after_topk(w, n, scores.shape[1])
+    s, i = _top_k(
+        _mask_scores(
+            scores, allow0, None if after else excl, incl, has_incl,
+            positive_only, cat,
+        ),
+        n + w if after else n,
+    )
+    if not after:
+        return s, i
+    hit = jnp.any(i[:, :, None] == excl[:, None, :], axis=2)
+    _, s, i = jax.lax.sort(
+        (hit.astype(jnp.int32), jnp.where(hit, -jnp.inf, s), i),
+        dimension=1, is_stable=True, num_keys=1,
+    )
+    return s[:, :n], i[:, :n]
 
 
 def pow2_topk_width(
@@ -630,11 +685,10 @@ def _fused_topn_single(
         packed, Y.shape[1], widths
     )
     scores = _scale_cosine(_exact_scores(q, Y), rn, row_norm, normalize)
-    scores = _mask_scores(
-        scores, allow0, excl, incl, has_incl, positive_only,
+    s, i = _masked_top_k(
+        scores, n, allow0, excl, incl, has_incl, positive_only,
         (codes, cats, has_cat),
     )
-    s, i = _top_k(scores, n)
     return _pack_topn(s, i)
 
 
@@ -726,11 +780,10 @@ def _fused_topn_single_2s(
     approx = _scale_cosine(
         _approx_scores(q, Yq, scale, precision), rn, row_norm, normalize
     )
-    approx = _mask_scores(
-        approx, allow0, excl, incl, has_incl, positive_only,
+    s1, i1 = _masked_top_k(
+        approx, shortlist, allow0, excl, incl, has_incl, positive_only,
         (codes, cats, has_cat),
     )
-    s1, i1 = _top_k(approx, shortlist)
     rescored = _rescore_exact(
         q, Yq, scale, s1, i1, rn, positive_only, normalize, precision,
         row_norm,
@@ -762,11 +815,10 @@ def _shard_topk_kernel_2s(
     approx = _scale_cosine(
         _approx_scores(q, Yq, scale, precision), rn, row_norm, normalize
     )
-    approx = _mask_scores(
-        approx, allow0, localize(excl), localize(incl), has_incl,
-        positive_only, (codes, cats, has_cat),
+    s1, i1 = _masked_top_k(
+        approx, shortlist, allow0, localize(excl), localize(incl),
+        has_incl, positive_only, (codes, cats, has_cat),
     )
-    s1, i1 = _top_k(approx, shortlist)
     rescored = _rescore_exact(
         q, Yq, scale, s1, i1, rn, positive_only, normalize, precision,
         row_norm,
@@ -796,11 +848,10 @@ def _shard_topk_kernel(
         return jnp.where((g >= off) & (g < off + rows_l), g - off, rows_l)
 
     scores = _scale_cosine(_exact_scores(q, Y), rn, row_norm, normalize)
-    scores = _mask_scores(
-        scores, allow0, localize(excl), localize(incl), has_incl,
+    s, i = _masked_top_k(
+        scores, n_local, allow0, localize(excl), localize(incl), has_incl,
         positive_only, (codes, cats, has_cat),
     )
-    s, i = _top_k(scores, n_local)
     return _pack_topn(s, i + off)
 
 
@@ -865,6 +916,16 @@ def _m_topk_two_level():
         "Runs of a retrieval program whose block-wise top-k took its "
         "second level (the best sub-blocks of 128 inside the best blocks "
         "of 1,024, for a top-k of 64 or more: the quantized shortlists)",
+        labels=("component",),
+    )
+
+
+def _m_excl_after_topk():
+    return _metrics.get_registry().counter(
+        "pio_retrieval_exclusion_after_topk_total",
+        "Runs of a retrieval program that applied its exclusion lists to "
+        "an over-fetched top-k (lists no wider than the top-k they feed) "
+        "instead of a [B, rows] membership grid",
         labels=("component",),
     )
 
@@ -1327,9 +1388,14 @@ class ItemRetriever:
             f"{what} of {width} is over the ladder's top {ladder[-1]}"
         )
 
-    def _count_top_k(self, rows: int, n: int) -> None:
-        """A run of a program whose ``_top_k`` over ``rows`` scores a
-        query takes its second level, counted (static per executable)."""
+    def _count_top_k(self, rows: int, n: int, w: int) -> None:
+        """A run of a program whose top-``n`` over ``rows`` scores a
+        query, with exclusion lists ``w`` wide, applies the lists after
+        the top-k (``_excl_after_topk``) or takes the top-k's second
+        level, counted (both static per executable)."""
+        if _excl_after_topk(w, n, rows):
+            _m_excl_after_topk().labels(component=self.component).inc()
+            n += w
         if _two_level(rows, n):
             _m_topk_two_level().labels(component=self.component).inc()
 
@@ -1441,7 +1507,7 @@ class ItemRetriever:
                     self._codes_dev.shape[1],
                     n, positive_only, normalize,
                 )
-                self._count_top_k(self._n_pad, n)
+                self._count_top_k(self._n_pad, n, widths[0])
                 with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
@@ -1458,7 +1524,7 @@ class ItemRetriever:
                     n_dev, shortlist, positive_only, normalize,
                     self.precision,
                 )
-                self._count_top_k(self._n_pad, shortlist)
+                self._count_top_k(self._n_pad, shortlist, widths[0])
                 with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
                     "retrieval-fused", _FUSED_SEEN, exec_key
                 ):
@@ -1501,6 +1567,7 @@ class ItemRetriever:
         self._count_top_k(
             self._n_pad // self._n_shards,
             n_local if shortlist is None else shortlist,
+            widths[0],
         )
         with _tracing.stage(_tracing.DISPATCH), _cc.track_compile(
             "retrieval-stage1", self._exec_seen, exec_key

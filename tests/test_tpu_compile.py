@@ -198,6 +198,17 @@ def _assert_one_blocked_form(compiled, batch, n_items):
             assert (m[1], m[3]) == ("pred", "copy"), line
 
 
+def _fusions_of(compiled, shape):
+    """How many fusions of a compiled program write a ``pred`` array of
+    ``shape``: a membership grid ``[1?, B, rows/2048, 2048]``, or a
+    ``[B, rows]`` predicate that the scoring pass reads back."""
+    dims = ",".join(str(d) for d in shape)
+    return len(re.findall(
+        rf"(?m)^\s*(?:ROOT )?%\S+ = pred\[(?:1,)?{dims}\]\S* fusion\(",
+        compiled.as_text(),
+    ))
+
+
 def _sorts(compiled):
     """(width along the sorted dimension, stable?) of every ``sort`` and
     every ``TopK`` custom call in a compiled program (an operand's shape
@@ -255,6 +266,8 @@ def test_ecommerce_fused_program_compiles_at_the_taobao_shape(one_chip):
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
     _assert_one_blocked_form(compiled, b, n_items)
+    # lists of 8,192 and 1,024 against a top-16 keep their grids
+    assert _fusions_of(compiled, (b, n // retrieval._LO, retrieval._LO)) == 2
 
 
 @pytest.mark.parametrize("exclude, include", [(16, 1), (64, 256)])
@@ -269,10 +282,14 @@ def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
     candidate list 64 wide and its stage-1 shortlist 256, cosine
     scores, four category codes, all in the batch's one packed operand.
     Each has to fit one chip beside the table, and no sort or top-k in
-    it takes more than 256 sub-blocks of 128 scores a query: the
-    shortlist's top-k takes its second level (a top-256 of 262,144 was
-    1.5 ms of a 10 ms run). Every sort in it is stable, so equal scores
-    keep their index order (the chip's top-256 of 32,768 did not)."""
+    it takes more than 256 + ``exclude`` sub-blocks of 128 scores a
+    query: the shortlist's top-k takes its second level (a top-256 of
+    262,144 was 1.5 ms of a 10 ms run), over-fetched by the exclusion
+    list's width. Every sort in it is stable, so equal scores keep
+    their index order (the chip's top-256 of 32,768 did not). The
+    exclusion lists go after that top-k: no executable builds their
+    membership grid (the whitelist's is the one left), and the category
+    compare writes no ``[B, rows]`` predicate of its own."""
     n_items, k = 9_400_000, 512
     n = _resident_rows(n_items)
     widths = (exclude, include, 4)
@@ -289,8 +306,12 @@ def test_similar_product_two_stage_ladder_compiles_at_the_amazon_shape(
     assert _device_bytes(compiled) < HBM_BYTES
     _assert_one_blocked_form(compiled, batch, n_items)
     sorts = _sorts(compiled)
-    assert sorts and max(w for w, _ in sorts) <= 256 * retrieval._SUB, sorts
+    widest = (256 + exclude) * retrieval._SUB
+    assert sorts and max(w for w, _ in sorts) <= widest, sorts
     assert all(stable for _, stable in sorts), sorts
+    grid = (batch, n // retrieval._LO, retrieval._LO)
+    assert _fusions_of(compiled, grid) == (include > 1)
+    assert _fusions_of(compiled, (batch, n)) == 0
 
 
 def test_int8_stage1_shards_over_four_chips(mesh4):
@@ -368,6 +389,9 @@ def test_similar_product_float32_shards_over_four_chips_at_the_amazon_shape(
     args = compiled.memory_analysis().argument_size_in_bytes
     assert shard < args < 1.01 * shard, (args, shard)
     assert _device_bytes(compiled) < HBM_BYTES
+    # a list no wider than the shard's top-16 goes after its top-k
+    grid = (batch, n // 4 // retrieval._LO, retrieval._LO)
+    assert _fusions_of(compiled, grid) == (exclude > n_local) + (include > 1)
     merge = retrieval._merge_candidates.lower(
         _shape((batch, 4 * 2 * n_local), jnp.int32,
                NamedSharding(mesh4, P(None, "data"))),
